@@ -90,7 +90,7 @@ def run():
         ref_fn = jax.jit(hlog_qmatmul_ref)
         us_ref = time_call(ref_fn, xq, wq)
         err = float(jnp.max(jnp.abs(
-            hlog_qmatmul(xq, wq, interpret=True) - ref_fn(xq, wq))))
+            hlog_qmatmul(xq, wq) - ref_fn(xq, wq))))
         rows.append((f"quant/hlog_qmatmul_serving/chunk{CS}x{D}", us_ref,
                      {"max_err_vs_fused": err,
                       "timing": "jnp-oracle (CPU); fused kernel "

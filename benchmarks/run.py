@@ -59,6 +59,8 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     names = args.only or list(MODULES)
 
+    from repro.launch.compile_cache import configure_compile_cache
+    configure_compile_cache()
     print("name,us_per_call,derived")
     failures = 0
     for name in names:

@@ -113,10 +113,12 @@ def main():
     done = eng.run_until_drained(max_ticks=2000)
     dt = time.perf_counter() - t0
     total_tokens = sum(len(r.output) for r in reqs)
+    dev = jax.devices()[0]
     print(f"requests={len(reqs)} slots={args.slots} paged={args.paged} "
           f"spls={args.spls} retired={len(done)}")
     print(f"decoded {total_tokens} tokens in {dt:.2f}s "
-          f"({total_tokens/dt:.1f} tok/s on CPU)")
+          f"({total_tokens/dt:.1f} tok/s on {dev.platform}:"
+          f"{dev.device_kind}, compile included)")
     if args.paged:
         print(f"pool: peak_pages={eng.stats['peak_pages']} "
               f"preemptions={eng.stats['preemptions']} "

@@ -1,5 +1,5 @@
 """qwen3-0.6b [dense]: 28L d_model=1024 16H (GQA kv=8) d_ff=3072
-vocab=151936 -- qk-norm, GQA.  [hf:Qwen/Qwen3-8B; hf]
+vocab=151936 -- qk-norm, GQA.  [hf:Qwen/Qwen3-0.6B config.json]
 
 long_500k: skipped -- pure full attention (see DESIGN.md).
 """

@@ -54,6 +54,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .interpret import resolve_interpret
+
 __all__ = ["flash_attention"]
 
 _NEG = -1e30
@@ -91,7 +93,7 @@ def _make_kernel(*, scale, causal, window, softcap, bq, bk, nk,
         q_start = iq * bq
         k_start = ik * bk
         if has_qpos:
-            qpos = qpos_ref[0]                       # (bq,) original row ids
+            qpos = qpos_ref[0]                       # (bq, 1) original row ids
             q_lo, q_hi = jnp.min(qpos), jnp.max(qpos)
         else:
             q_lo, q_hi = q_start, q_start + bq - 1
@@ -105,7 +107,7 @@ def _make_kernel(*, scale, causal, window, softcap, bq, bk, nk,
             if not causal:  # symmetric window: future side masks too
                 live = jnp.logical_and(live, k_start < q_hi + window)
         if keep_ref is not None:
-            live = jnp.logical_and(live, jnp.any(keep_ref[0] > 0))
+            live = jnp.logical_and(live, jnp.max(keep_ref[0]) > 0)
 
         @pl.when(live)
         def _compute():
@@ -116,7 +118,7 @@ def _make_kernel(*, scale, causal, window, softcap, bq, bk, nk,
             if softcap is not None:
                 s = jnp.tanh(s / softcap) * softcap
             if has_qpos:
-                qi = jnp.broadcast_to(qpos[:, None], (bq, bk))
+                qi = jnp.broadcast_to(qpos, (bq, bk))
             else:
                 qi = q_start + jax.lax.broadcasted_iota(
                     jnp.int32, (bq, bk), 0)
@@ -129,7 +131,7 @@ def _make_kernel(*, scale, causal, window, softcap, bq, bk, nk,
                 if not causal:
                     mask &= kj - qi < window
             if keep_ref is not None:
-                mask &= (keep_ref[0] > 0)[None, :]
+                mask &= keep_ref[0] > 0                 # (1, bk) row
             s = jnp.where(mask, s, _NEG)
 
             m_prev = m_scr[...]
@@ -159,14 +161,19 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     kv_keep: Optional[jax.Array] = None,
                     q_pos: Optional[jax.Array] = None,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: Optional[bool] = None) -> jax.Array:
     """q: (B, H, Lq, Dh); k, v: (B, H, Lk, Dh) or GQA-grouped
     (B, KV, Lk, Dh) with H % KV == 0 -- grouped K/V is read through the
     BlockSpec index map (head h -> group h // G), never materialized
     H-wide.  kv_keep: optional (B, H, Lk) bool (per *query* head -- SPLS
     prunes per head).  q_pos: optional (B, H, Lq) int32 original position
     of each query row (for SPLS-packed rows); defaults to arange semantics
-    when omitted.  Ragged Lq/Lk are padded internally."""
+    when omitted.  Ragged Lq/Lk are padded internally.  ``interpret=None``
+    interprets on CPU only (:func:`repro.kernels.interpret.resolve_interpret`).
+
+    q_pos rides in as a ``(B*H, Lq, 1)`` column and kv_keep as a
+    ``(B*H, 1, Lk)`` row, so each block's last two dims are either a
+    multiple of the TPU's (8, 128) tile or the whole array dim."""
     B, H, Lq, Dh = q.shape
     KVh, Lk = k.shape[1], k.shape[2]
     assert H % KVh == 0, (H, KVh)
@@ -204,11 +211,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         pl.BlockSpec((1, bk, Dh), lambda b, i, j: (b // G, j, 0)),
     ]
     if q_pos is not None:
-        args.append(q_pos.reshape(B * H, Lqp).astype(jnp.int32))
-        in_specs.append(pl.BlockSpec((1, bq), lambda b, i, j: (b, i)))
+        args.append(q_pos.reshape(B * H, Lqp, 1).astype(jnp.int32))
+        in_specs.append(pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)))
     if kv_keep is not None:
-        args.append(kv_keep.reshape(B * H, Lkp).astype(jnp.int32))
-        in_specs.append(pl.BlockSpec((1, bk), lambda b, i, j: (b, j)))
+        args.append(kv_keep.reshape(B * H, 1, Lkp).astype(jnp.int32))
+        in_specs.append(pl.BlockSpec((1, 1, bk), lambda b, i, j: (b, 0, j)))
 
     kernel = _make_kernel(scale=scale, causal=causal, window=window,
                           softcap=softcap, bq=bq, bk=bk, nk=nk,
@@ -225,6 +232,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, Dh), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*args)
     return out.reshape(B, H, Lqp, Dh)[:, :, :Lq]

@@ -27,6 +27,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .interpret import resolve_interpret
+
 __all__ = ["flash_decode"]
 
 _NEG = -1e30
@@ -42,7 +44,7 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    pos = pos_ref[0]
+    pos = pos_ref[pl.program_id(0)]
     k_start = ik * bk
     live = k_start <= pos
     if window is not None:
@@ -82,9 +84,10 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array, pos: jax.Array,
                  softcap: Optional[float] = None,
                  window: Optional[int] = None, block_k: int = 512,
-                 interpret: bool = True) -> jax.Array:
+                 interpret: Optional[bool] = None) -> jax.Array:
     """q: (B, KV, G, Dh) one token per row; k/v: (B, KV, S, Dh) caches;
-    pos: (B,) current write index (inclusive).  Returns (B, KV, G, Dh)."""
+    pos: (B,) current write index (inclusive), a scalar-prefetch operand.
+    Returns (B, KV, G, Dh).  ``interpret=None`` interprets on CPU only."""
     B, KV, G, Dh = q.shape
     S = k.shape[2]
     bk = min(block_k, S)
@@ -95,25 +98,28 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array, pos: jax.Array,
     qf = q.reshape(B * KV, G, Dh)
     kf = k.reshape(B * KV, S, Dh)
     vf = v.reshape(B * KV, S, Dh)
-    posf = jnp.repeat(pos, KV)
+    posf = jnp.repeat(pos.astype(jnp.int32), KV)
 
-    out = pl.pallas_call(
-        functools.partial(_kernel, scale=scale, softcap=softcap,
-                          window=window, bk=bk, nk=nk),
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(B * KV, nk),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, j: (b,)),
-            pl.BlockSpec((1, G, Dh), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, bk, Dh), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, Dh), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, G, Dh), lambda b, j, p: (b, 0, 0)),
+            pl.BlockSpec((1, bk, Dh), lambda b, j, p: (b, j, 0)),
+            pl.BlockSpec((1, bk, Dh), lambda b, j, p: (b, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, G, Dh), lambda b, j: (b, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * KV, G, Dh), q.dtype),
+        out_specs=pl.BlockSpec((1, G, Dh), lambda b, j, p: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((G,), jnp.float32),
             pltpu.VMEM((G,), jnp.float32),
             pltpu.VMEM((G, Dh), jnp.float32),
         ],
-        interpret=interpret,
+    )
+    out = pl.pallas_call(
+        functools.partial(_kernel, scale=scale, softcap=softcap,
+                          window=window, bk=bk, nk=nk),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B * KV, G, Dh), q.dtype),
+        interpret=resolve_interpret(interpret),
     )(posf, qf, kf, vf)
     return out.reshape(B, KV, G, Dh)

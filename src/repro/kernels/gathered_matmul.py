@@ -14,7 +14,10 @@ makes for the block table:
   copies** resolved against ``perm`` (the gather happens in the DMA
   schedule; no ``(C, D)`` gathered copy ever lands in HBM), pipelined
   two-deep over a pair of DMA semaphores so row ``r + 1``'s copy is in
-  flight while row ``r``'s is awaited;
+  flight while row ``r``'s is awaited.  Source and panel are ``(rows, 1,
+  D)`` views: a one-row slice of a 2-D ref breaks the TPU's 8-row sublane
+  tiling, while a leading-dim index of a 3-D ref copies one whole
+  ``(1, D)`` tile;
 * the MXU consumes the panel directly (K-slices of the VMEM panel), and
   the output tile accumulates across K steps exactly like
   ``hlog_qmatmul``.
@@ -26,11 +29,13 @@ output row's source slot in the index map, so the scatter is also pure
 DMA scheduling.  :func:`gathered_matmul` chains both when ``src_slot``
 is given -- gather -> matmul -> leader-scatter in one call.
 
-Numerics: with ``bk=None`` (the default) the whole contraction runs in
-one MXU dot per tile, which keeps the result **bitwise identical** to
-the XLA ``x[perm] @ w`` oracle (row/column subsets of an XLA dot are
-bitwise stable; K-blocked accumulation is not -- callers that set ``bk``
-trade that equality for a smaller VMEM footprint).
+Numerics: the panel is float32 and, with ``bk=None`` (the default),
+the whole contraction runs in one dot per tile.  The result equals the
+XLA ``x[perm] @ w`` oracle up to float32 summation order: a tile's dot
+need not sum in the order of the full dot (XLA's CPU dot does not
+either), so equality is exact only where the arithmetic is (e.g.
+small-integer data).  ``bk`` blocks the contraction for a smaller VMEM
+footprint.
 """
 
 from __future__ import annotations
@@ -42,6 +47,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .interpret import resolve_interpret
 
 __all__ = ["gathered_matmul", "gather_rows_kernel"]
 
@@ -67,6 +74,7 @@ def _gmm_kernel(perm_ref, x_hbm, w_ref, o_ref, xs, sem, *, bm, bk,
         # and the panel is fully awaited before the MXU reads it.
         def dma(r, slot):
             src = perm_ref[i * bm + r]
+            # (1, D) tile of the (L, 1, D) source into panel row r
             return pltpu.make_async_copy(x_hbm.at[src], xs.at[r],
                                          sem.at[slot])
 
@@ -99,7 +107,7 @@ def _gmm_kernel(perm_ref, x_hbm, w_ref, o_ref, xs, sem, *, bm, bk,
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    xt = xs[:, pl.ds(k * bk, bk)]
+    xt = xs[:, 0, pl.ds(pl.multiple_of(k * bk, bk), bk)]
     o_ref[...] += jnp.dot(xt, w_ref[...], preferred_element_type=jnp.float32)
 
 
@@ -111,18 +119,18 @@ def _gathered_matmul_padded(x: jax.Array, w: jax.Array, perm: jax.Array,
                             interpret: bool,
                             double_buffer: bool = True) -> jax.Array:
     C = perm.shape[0]
-    _, D = x.shape
+    L, D = x.shape
     _, F = w.shape
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(C // bm, F // bn, D // bk),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),          # x stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),    # x (L, 1, D) in HBM
             pl.BlockSpec((bk, bn), lambda i, j, k, perm: (k, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k, perm: (i, j)),
         scratch_shapes=[
-            pltpu.VMEM((bm, D), jnp.float32),              # gathered panel
+            pltpu.VMEM((bm, 1, D), jnp.float32),     # gathered panel
             pltpu.SemaphoreType.DMA((2,)),      # double-buffered row copies
         ],
     )
@@ -132,13 +140,13 @@ def _gathered_matmul_padded(x: jax.Array, w: jax.Array, perm: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((C, F), jnp.float32),
         interpret=interpret,
-    )(perm, x, w)
+    )(perm, x.reshape(L, 1, D), w)
 
 
 def gathered_matmul(x: jax.Array, w: jax.Array, perm: jax.Array,
                     src_slot: Optional[jax.Array] = None,
                     bm: int = 128, bn: int = 128, bk: Optional[int] = None,
-                    interpret: bool = True,
+                    interpret: Optional[bool] = None,
                     double_buffer: bool = True) -> jax.Array:
     """``x[perm] @ w`` with the gather fused into the matmul DMA schedule.
 
@@ -150,16 +158,17 @@ def gathered_matmul(x: jax.Array, w: jax.Array, perm: jax.Array,
 
     Ragged C / F are padded internally (padded perm slots gather row 0,
     computed wastefully and sliced off -- the same discipline as the
-    capacity pack).  ``bk=None`` runs the whole contraction per tile:
-    bitwise equal to the XLA oracle; see module docstring.
+    capacity pack).  ``bk=None`` runs the whole contraction per tile;
+    see the module docstring for numerics.
 
     ``double_buffer=False`` serializes the row gather (start+wait per
     row, no overlap) -- bitwise identical, kept as the timing baseline
     that isolates what the two-semaphore pipeline buys
     (``benchmarks/bench_kernels.py`` times both; the dispatch carries a
     ``jax.profiler.TraceAnnotation`` so on-TPU profiles name the
-    variant).
+    variant).  ``interpret=None`` interprets on CPU only.
     """
+    interpret = resolve_interpret(interpret)
     L, D = x.shape
     D2, F = w.shape
     assert D == D2, (x.shape, w.shape)
@@ -196,7 +205,7 @@ def _gather_kernel(idx_ref, src_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def gather_rows_kernel(src: jax.Array, idx: jax.Array,
-                       interpret: bool = True) -> jax.Array:
+                       interpret: Optional[bool] = None) -> jax.Array:
     """``out[i] = src[idx[i]]`` -- the leader-scatter as pure DMA.
 
     src: (C, F); idx: (M,) int32 source row per output row.  The index
@@ -204,19 +213,22 @@ def gather_rows_kernel(src: jax.Array, idx: jax.Array,
     resolved by the input BlockSpec index map, so the whole scatter is
     realised in the DMA schedule (no gathered intermediate, no XLA
     gather op) -- the row-granular version of ``paged_decode``'s
-    block-table lookup.
+    block-table lookup.  Rows move as ``(1, 1, F)`` blocks of ``(rows,
+    1, F)`` views, whose last two dims are whole-array (the TPU block
+    rule).  ``interpret=None`` interprets on CPU only.
     """
     C, F = src.shape
     M = idx.shape[0]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(M,),
-        in_specs=[pl.BlockSpec((1, F), lambda i, idx: (idx[i], 0))],
-        out_specs=pl.BlockSpec((1, F), lambda i, idx: (i, 0)),
+        in_specs=[pl.BlockSpec((1, 1, F), lambda i, idx: (idx[i], 0, 0))],
+        out_specs=pl.BlockSpec((1, 1, F), lambda i, idx: (i, 0, 0)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _gather_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((M, F), src.dtype),
-        interpret=interpret,
-    )(idx.astype(jnp.int32), src)
+        out_shape=jax.ShapeDtypeStruct((M, 1, F), src.dtype),
+        interpret=resolve_interpret(interpret),
+    )(idx.astype(jnp.int32), src.reshape(C, 1, F))
+    return out.reshape(M, F)

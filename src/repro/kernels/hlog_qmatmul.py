@@ -18,10 +18,13 @@ via BlockSpec; bm/bn/bk default to MXU-aligned 128 multiples.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from .interpret import resolve_interpret
 
 __all__ = ["hlog_qmatmul"]
 
@@ -55,8 +58,10 @@ def _kernel(x_ref, w_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
 def hlog_qmatmul(xq: jax.Array, wq: jax.Array, bm: int = 128, bn: int = 128,
-                 bk: int = 128, interpret: bool = True) -> jax.Array:
+                 bk: int = 128,
+                 interpret: Optional[bool] = None) -> jax.Array:
     """hlog(xq) @ hlog(wq).  xq: (M, K); wq: (K, N); int-valued float32.
+    ``interpret=None`` interprets on CPU only.
 
     Shapes must tile evenly (callers pad); VMEM per step is
     ``bm*bk + bk*bn + bm*bn`` floats (default 192 KiB), well inside the
@@ -78,5 +83,5 @@ def hlog_qmatmul(xq: jax.Array, wq: jax.Array, bm: int = 128, bn: int = 128,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xq, wq)

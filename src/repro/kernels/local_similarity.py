@@ -14,10 +14,13 @@ be large (2048 default) to amortise grid overhead.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from .interpret import resolve_interpret
 
 __all__ = ["local_similarity_dist"]
 
@@ -34,8 +37,9 @@ def _kernel(spa_ref, o_ref, *, w):
 
 @functools.partial(jax.jit, static_argnames=("w", "bk", "interpret"))
 def local_similarity_dist(spa: jax.Array, w: int = 8, bk: int = 2048,
-                          interpret: bool = True) -> jax.Array:
-    """spa: (B, H, L, Lk) with L % w == 0 -> (B, H, L//w, w, w) L1 dists."""
+                          interpret: Optional[bool] = None) -> jax.Array:
+    """spa: (B, H, L, Lk) with L % w == 0 -> (B, H, L//w, w, w) L1 dists.
+    ``interpret=None`` interprets on CPU only."""
     B, H, L, Lk = spa.shape
     assert L % w == 0, (L, w)
     nw = L // w
@@ -49,6 +53,6 @@ def local_similarity_dist(spa: jax.Array, w: int = 8, bk: int = 2048,
         in_specs=[pl.BlockSpec((1, w, bk), lambda b, i, j: (b, 0, j))],
         out_specs=pl.BlockSpec((1, w, w), lambda b, i, j: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H * nw, w, w), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xf)
     return out.reshape(B, H, nw, w, w)

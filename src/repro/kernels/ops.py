@@ -1,7 +1,8 @@
 """Jit'd public wrappers over the Pallas kernels.
 
-On a real TPU these dispatch the compiled kernels (``interpret=False``); on
-CPU (this container) they run the kernel bodies in interpret mode, which is
+On an accelerator these dispatch the compiled kernels; on a CPU host they
+run the kernel bodies in interpret mode (the kernels' ``interpret=None``
+default, :func:`repro.kernels.interpret.resolve_interpret`), which is
 bit-accurate but slow -- the tests validate against the pure-jnp oracles in
 ``ref.py`` either way.  ``use_pallas=False`` falls straight through to the
 reference implementation (the default inside the model code, where XLA's own
@@ -14,7 +15,6 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
-import jax.numpy as jnp
 
 from . import ref
 from .flash_attention import flash_attention
@@ -25,10 +25,6 @@ __all__ = ["predict_matmul", "attention", "window_distances",
            "flash_attention", "hlog_qmatmul", "local_similarity_dist"]
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def predict_matmul(xq: jax.Array, wq: jax.Array,
                    use_pallas: bool = True) -> jax.Array:
     """Fused HLog-project + matmul (PAM prediction hot spot)."""
@@ -36,7 +32,7 @@ def predict_matmul(xq: jax.Array, wq: jax.Array,
     N = wq.shape[1]
     tileable = M % 128 == 0 and N % 128 == 0 and K % 128 == 0
     if use_pallas and tileable:
-        return hlog_qmatmul(xq, wq, interpret=not _on_tpu())
+        return hlog_qmatmul(xq, wq)
     return ref.hlog_qmatmul_ref(xq, wq)
 
 
@@ -49,8 +45,7 @@ def attention(q, k, v, causal: bool = True, window: Optional[int] = None,
     tileable = L % 128 == 0 and Lk % 128 == 0
     if use_pallas and tileable:
         return flash_attention(q, k, v, causal=causal, window=window,
-                               softcap=softcap, kv_keep=kv_keep,
-                               interpret=not _on_tpu())
+                               softcap=softcap, kv_keep=kv_keep)
     return ref.flash_attention_ref(q, k, v, causal=causal, window=window,
                                    softcap=softcap, kv_keep=kv_keep)
 
@@ -60,5 +55,5 @@ def window_distances(spa: jax.Array, w: int = 8,
     """Windowed pairwise L1 distances (similarity-unit hot spot)."""
     L, Lk = spa.shape[2], spa.shape[3]
     if use_pallas and L % w == 0 and Lk % 128 == 0:
-        return local_similarity_dist(spa, w=w, interpret=not _on_tpu())
+        return local_similarity_dist(spa, w=w)
     return ref.local_similarity_ref(spa, w)

@@ -38,6 +38,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .interpret import resolve_interpret
+
 __all__ = ["paged_flash_decode", "NULL_PAGE"]
 
 NULL_PAGE = 0
@@ -63,7 +65,7 @@ def _kernel(bt_ref, kl_ref, cp_ref, q_ref, k_ref, v_ref, pp_ref, o_ref,
         # page-level window skip: a page is dead once every *written* slot
         # has aged out of the window (original ids, not slot indices)
         sl = slot0 + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
-        in_w = (sl < n_valid) & (cp_ref[b] - pp_ref[0][None, :] < window)
+        in_w = (sl < n_valid) & (cp_ref[b] - pp_ref[0] < window)
         live = jnp.logical_and(live, in_w.any())
 
     @pl.when(live)
@@ -77,7 +79,7 @@ def _kernel(bt_ref, kl_ref, cp_ref, q_ref, k_ref, v_ref, pp_ref, o_ref,
         slot = slot0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         mask = slot < n_valid
         if window is not None:
-            mask &= cp_ref[b] - pp_ref[0][None, :] < window
+            mask &= cp_ref[b] - pp_ref[0] < window      # pp: (1, ps) ids
         s = jnp.where(mask, s, _NEG)
         m_prev = m_scr[...]
         m_new = jnp.maximum(m_prev, s.max(-1))
@@ -101,10 +103,13 @@ def paged_flash_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                        kv_len: jax.Array, pos: jax.Array,
                        softcap: Optional[float] = None,
                        window: Optional[int] = None,
-                       interpret: bool = True) -> jax.Array:
+                       interpret: Optional[bool] = None) -> jax.Array:
     """q: (B, KV, G, Dh) one token per sequence; k/v_pages: (KV, N, ps, Dh);
     pos_pages: (N, ps); tables: (B, P); kv_len/pos: (B,).
-    Returns (B, KV, G, Dh)."""
+    Returns (B, KV, G, Dh).  ``pos_pages`` is read through an
+    ``(N, 1, ps)`` view so each page's id row is a whole-array tile in its
+    last two dims (the TPU block rule).  ``interpret=None`` interprets on
+    CPU only."""
     B, KV, G, Dh = q.shape
     _, N, ps, _ = k_pages.shape
     P = tables.shape[1]
@@ -125,8 +130,8 @@ def paged_flash_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
             pl.BlockSpec((1, 1, ps, Dh),
                          lambda i, j, bt, kl, cp: (i % KV, bt[i // KV, j],
                                                    0, 0)),
-            pl.BlockSpec((1, ps),
-                         lambda i, j, bt, kl, cp: (bt[i // KV, j], 0)),
+            pl.BlockSpec((1, 1, ps),
+                         lambda i, j, bt, kl, cp: (bt[i // KV, j], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, G, Dh), lambda i, j, bt, kl, cp: (i, 0, 0)),
         scratch_shapes=[
@@ -140,6 +145,6 @@ def paged_flash_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                           window=window, ps=ps, kv=KV, np_=P),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * KV, G, Dh), q.dtype),
-        interpret=interpret,
-    )(tables, kv_len, pos, qf, k_pages, v_pages, pos_pages)
+        interpret=resolve_interpret(interpret),
+    )(tables, kv_len, pos, qf, k_pages, v_pages, pos_pages.reshape(N, 1, ps))
     return out.reshape(B, KV, G, Dh)

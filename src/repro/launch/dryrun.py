@@ -1,14 +1,16 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch x shape) cell on the
 production mesh and record memory / FLOPs / collective-traffic analysis.
 
-The two lines above MUST run before any jax import (jax locks the device
+The three lines above MUST run before any jax import (jax locks the device
 count on first init); they give this process 512 placeholder CPU devices so
 ``jax.make_mesh`` can build the 16x16 single-pod and 2x16x16 multi-pod
-meshes.  Nothing is allocated: inputs are ShapeDtypeStructs and the step is
-only lowered and compiled.
+meshes, and pin it to the CPU so it never claims an attached accelerator
+(the pod exists only as virtual CPU devices).  Nothing is allocated:
+inputs are ShapeDtypeStructs and the step is only lowered and compiled.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch llama3-405b \

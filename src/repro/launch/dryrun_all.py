@@ -43,6 +43,8 @@ def run_one(arch: str, shape: str, multi_pod: bool, spls: bool,
         cmd.append("--spls")
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+    # the pod is virtual CPU devices; a child must never claim the chip
+    env["JAX_PLATFORMS"] = "cpu"
     t0 = time.time()
     try:
         proc = subprocess.run(cmd, capture_output=True, text=True,

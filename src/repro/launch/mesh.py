@@ -9,22 +9,14 @@ import and only then builds the mesh.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-try:  # AxisType landed in jax 0.5; the pinned 0.4.x has no explicit axis types
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - version-dependent
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 __all__ = ["make_production_mesh", "make_cpu_mesh", "mesh_axis_sizes"]
 
 
 def _make_mesh(shape, axes) -> Mesh:
-    """jax.make_mesh with explicit Auto axis types where supported."""
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """jax.make_mesh with explicit Auto axis types."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

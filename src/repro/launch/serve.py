@@ -1,77 +1,126 @@
 """Serving launcher: ``python -m repro.launch.serve --arch <id>``.
 
-Spins up a continuous-batching engine on a smoke-scale model and drives a
-synthetic request stream through it (batched prefill+decode on CPU).
-``--paged`` selects the block-pool paged engine (chunked prefill,
-admission keyed on free pages, SPLS page pruning); the default is the
-dense fixed-slot engine.  Paged serving requires attention-only periods
+Builds a continuous-batching engine at the architecture's published
+widths, with random weights drawn from ``--seed``, and drives a synthetic
+request stream through it.  ``--paged`` selects the block-pool paged
+engine (chunked prefill, admission keyed on free pages, SPLS page
+pruning); the default is the dense fixed-slot engine.  ``--spls`` turns
+on the paper's sparsity with :data:`SPLS_SETTINGS`.  ``--smoke`` swaps in
+the architecture's CPU-sized variant (``ArchConfig.smoke()``) for runs
+without an accelerator.  Paged serving requires attention-only periods
 (SSM state is O(1) per slot and is not paged).
+
+``serving_config`` and ``build_engine`` are the construction path every
+serving entry point shares (``chip_smoke.py`` included).
 """
 
 from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import json
 import sys
+import time
 
 import jax
-import jax.numpy as jnp
+
+__all__ = ["SPLS_SETTINGS", "serving_config", "init_serving_params",
+           "build_engine", "main"]
+
+# the SPLS operating point of the serving launcher (paper defaults scaled
+# to serving chunks: window 4 divides every prefill_chunk it is run with)
+SPLS_SETTINGS = dict(k_ratio=0.25, s_threshold=0.6, f_threshold=2, window=4)
+
+
+def serving_config(arch: str, *, smoke: bool = False, spls: bool = False):
+    """The ArchConfig a serving run uses: published widths (or the smoke
+    variant), no rematerialization, and SPLS at :data:`SPLS_SETTINGS`."""
+    from repro.configs.registry import get_config
+    from repro.core.spls import SPLSConfig
+
+    cfg = get_config(arch)
+    if smoke:
+        cfg = cfg.smoke()
+    cfg = dataclasses.replace(cfg, remat=False)
+    if spls and cfg.has_attn:
+        cfg = dataclasses.replace(cfg, spls=SPLSConfig(
+            enabled=True, causal=cfg.causal, **SPLS_SETTINGS))
+    return cfg
+
+
+def init_serving_params(cfg, seed: int = 0):
+    """Random weights from ``seed``, initialized in one compiled program."""
+    from repro.models import init_params
+
+    return jax.jit(functools.partial(init_params, cfg))(
+        jax.random.PRNGKey(seed))
+
+
+def build_engine(cfg, params, *, paged: bool, **serve_kw):
+    """A ``PagedServingEngine`` (``paged``) or the dense ``ServingEngine``
+    over ``cfg``/``params``; ``serve_kw`` are ``ServeConfig`` fields."""
+    from repro.serving import PagedServingEngine, ServeConfig, ServingEngine
+
+    return (PagedServingEngine if paged else ServingEngine)(
+        cfg, params, ServeConfig(**serve_kw))
 
 
 def main(argv=None):
-    ap = argparse.ArgumentParser()
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--arch", default="qwen3-0.6b")
+    ap.add_argument("--smoke", action="store_true",
+                    help="CPU-sized variant of the architecture")
+    ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--slots", type=int, default=4)
-    ap.add_argument("--prompt-len", type=int, default=16)
-    ap.add_argument("--max-new", type=int, default=8)
+    ap.add_argument("--prompt-len", type=int, default=128)
+    ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--spls", action="store_true")
     ap.add_argument("--paged", action="store_true")
-    ap.add_argument("--page-size", type=int, default=8)
+    ap.add_argument("--page-size", type=int, default=16)
+    ap.add_argument("--prefill-chunk", type=int, default=64)
     args = ap.parse_args(argv)
 
-    from repro.configs.registry import get_config
-    from repro.models import init_params
-    from repro.serving import (PagedServingEngine, Request, ServeConfig,
-                               ServingEngine)
+    from repro.launch.compile_cache import configure_compile_cache
+    from repro.serving import Request
 
-    cfg = get_config(args.arch).smoke()
-    cfg = dataclasses.replace(cfg, remat=False)
-    if args.spls and cfg.has_attn:
-        from repro.core.spls import SPLSConfig
-        cfg = dataclasses.replace(cfg, spls=SPLSConfig(
-            enabled=True, k_ratio=0.25, s_threshold=0.6, f_threshold=2,
-            window=4, causal=cfg.causal))
-    if cfg.input_mode != "tokens":
-        print(f"{cfg.name}: embeddings-input arch; engine demo uses tokens "
-              "-- skipping")
+    configure_compile_cache()
+    cfg = serving_config(args.arch, smoke=args.smoke, spls=args.spls)
+    if cfg.input_mode != "tokens" or (args.paged and cfg.has_mamba):
+        print(f"{cfg.name}: not servable by this engine -- skipping")
         return 0
-    if args.paged and cfg.has_mamba:
-        print(f"{cfg.name}: hybrid/SSM arch; paged engine is attention-only "
-              "-- skipping")
-        return 0
-
-    params = init_params(cfg, jax.random.PRNGKey(0))
-    scfg = ServeConfig(n_slots=args.slots,
+    params = init_serving_params(cfg, args.seed)
+    # the paged engine packs SPLS prefill rows where the platform has a
+    # packed kernel ("auto"); the dense engine has no packed path
+    eng = build_engine(cfg, params, paged=args.paged, n_slots=args.slots,
                        max_len=args.prompt_len + args.max_new + 8,
-                       page_size=args.page_size)
-    eng = (PagedServingEngine if args.paged else ServingEngine)(
-        cfg, params, scfg)
+                       page_size=args.page_size,
+                       prefill_chunk=args.prefill_chunk,
+                       compute_backend="auto" if args.paged else None)
     reqs = []
     for i in range(args.requests):
-        prompt = jax.random.randint(jax.random.PRNGKey(i),
+        prompt = jax.random.randint(jax.random.PRNGKey(args.seed + i),
                                     (args.prompt_len,), 0, cfg.vocab_size)
         r = Request(rid=i, prompt=prompt, max_new_tokens=args.max_new)
         reqs.append(r)
         eng.submit(r)
-    done = eng.run_until_drained(max_ticks=1000)
-    out = {"requests": len(reqs), "retired": len(done),
+    t0 = time.perf_counter()
+    done = eng.run_until_drained(max_ticks=100000)
+    wall = time.perf_counter() - t0
+    dev = jax.devices()[0]
+    tokens = sum(len(r.output) for r in reqs)
+    out = {"arch": cfg.name, "requests": len(reqs), "retired": len(done),
            "all_done": all(r.done for r in reqs),
+           "device": {"platform": dev.platform, "kind": dev.device_kind,
+                      "count": len(jax.devices())},
+           # includes compilation: a first run's rate is mostly set-up
+           "tok_per_s_incl_compile": tokens / wall,
            "outputs": {r.rid: r.output[:8] for r in reqs[:4]}}
     if args.paged:
         out["pool"] = {k: eng.stats[k] for k in
-                       ("peak_pages", "preemptions", "prefill_chunks")}
+                       ("peak_pages", "preemptions", "prefill_chunks",
+                        "compute_backend", "decode_backend")}
     print(json.dumps(out, indent=1))
     return 0 if out["all_done"] else 1
 

@@ -33,7 +33,8 @@ layout produced by ``attention._project_qkv`` and ``o`` shaped like ``q``.
     masking is exactly the part a tiled MXU cannot skip; column + row
     sparsity is what the hardware realizes (cf. ``xla_chunked`` which shares
     these semantics and is the parity oracle under a plan).
-    Runs compiled on TPU, ``interpret=True`` elsewhere (bit-accurate, slow).
+    Runs compiled on an accelerator and interpreted on a CPU host
+    (bit-accurate, slow; :mod:`repro.kernels.interpret`).
 
 Decode backends share::
 
@@ -140,10 +141,6 @@ def get_backend(name: str) -> Callable:
             f"registered: {available_backends()}") from None
 
 
-def _platform() -> str:
-    return jax.default_backend()
-
-
 def _site_kind(decode: bool, paged: bool) -> str:
     return ("paged decode" if paged else "decode") if decode else "forward"
 
@@ -202,7 +199,7 @@ def resolve_backend(name: Optional[str], cfg, *, L: int, plan=None,
         if (name, site) not in _warned_kind_mismatch:
             _warned_kind_mismatch.add((name, site))
             warnings.warn(msg, RuntimeWarning, stacklevel=2)
-    platform = platform or _platform()
+    platform = platform or jax.default_backend()
     if decode and paged:
         return ("pallas_paged_decode" if platform == "tpu"
                 else "xla_paged_decode")
@@ -348,7 +345,6 @@ def pallas_flash(cfg, q, k, v, *, window=None, plan=None, q_capacity=None,
 
     B, KVp, Gp, L, Dh = q.shape
     H = KVp * Gp
-    interpret = _platform() != "tpu"
     qf = q.reshape(B, H, L, Dh)
     # k/v stay in the grouped (B, KV', L, Dh) layout: the kernel reads the
     # shared group K/V through its BlockSpec index map (no H-wide copy)
@@ -357,8 +353,7 @@ def pallas_flash(cfg, q, k, v, *, window=None, plan=None, q_capacity=None,
     if plan is None:
         o = flash_attention(qf, kf, vf, causal=cfg.causal, window=window,
                             softcap=cfg.attn_softcap,
-                            block_q=PALLAS_BLOCK_Q, block_k=PALLAS_BLOCK_K,
-                            interpret=interpret)
+                            block_q=PALLAS_BLOCK_Q, block_k=PALLAS_BLOCK_K)
         return o.reshape(B, KVp, Gp, L, Dh)
 
     # SPLS plan -> hardware block sparsity:
@@ -376,8 +371,7 @@ def pallas_flash(cfg, q, k, v, *, window=None, plan=None, q_capacity=None,
     op = flash_attention(qp, kf, vf, causal=cfg.causal, window=window,
                          softcap=cfg.attn_softcap, kv_keep=keep,
                          q_pos=q_perm,
-                         block_q=PALLAS_BLOCK_Q, block_k=PALLAS_BLOCK_K,
-                         interpret=interpret)
+                         block_q=PALLAS_BLOCK_Q, block_k=PALLAS_BLOCK_K)
     o = unpack_by_leader(op, q_slot, leader)
     return o.reshape(B, KVp, Gp, L, Dh)
 
@@ -415,8 +409,7 @@ def pallas_flash_decode(cfg, q, k, v, *, pos, window=None) -> jax.Array:
         k = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
     return flash_decode(q, k, v, pos, softcap=cfg.attn_softcap,
-                        window=window, block_k=bk,
-                        interpret=_platform() != "tpu")
+                        window=window, block_k=bk)
 
 
 # ---------------------------------------------------------------------------
@@ -459,5 +452,4 @@ def pallas_paged_decode(cfg, q, k_pages, v_pages, *, pos_pages, tables,
 
     return paged_flash_decode(q, k_pages, v_pages, pos_pages, tables,
                               kv_len, pos, softcap=cfg.attn_softcap,
-                              window=window,
-                              interpret=_platform() != "tpu")
+                              window=window)

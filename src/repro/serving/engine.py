@@ -33,7 +33,7 @@ import numpy as np
 
 from repro.configs.base import ArchConfig
 from repro.models import decode_step, init_cache, prefill
-from repro.models.attn_backend import AUTO
+from repro.models.attn_backend import AUTO, resolve_backend
 from repro.observability import Telemetry, tree_bytes
 from repro.sparse_compute import (CapacityController, chunk_flops, is_packed,
                                   resolve_compute_backend)
@@ -54,8 +54,12 @@ class Request:
     prompt: jnp.ndarray            # (Lp,) int32
     max_new_tokens: int = 16
     eos_id: Optional[int] = None
+    # keep the logits the first token was sampled from (a host copy of
+    # one vocab row), e.g. to check serving against a reference
+    return_logits: bool = False
     # filled by the engine:
     output: List[int] = dataclasses.field(default_factory=list)
+    first_logits: Optional[np.ndarray] = None
     done: bool = False
 
 
@@ -115,22 +119,27 @@ class ServeConfig:
     telemetry: bool = True
 
 
-def _backend_for_site(name: Optional[str], *, decode: bool,
-                      paged: bool = False) -> Optional[str]:
+def _backend_for_site(name: Optional[str], cfg_name: Optional[str], *,
+                      decode: bool, paged: bool = False) -> Optional[str]:
     """Route a ServeConfig.attn_backend name to one engine site.
 
     The single config field intentionally drives every site an engine
-    has; a site of a different kind resolves ``"auto"``.  Doing the kind
-    split *here* keeps the registry's kind-mismatch warning reserved for
-    genuine configuration errors instead of firing on the engines' own
-    documented fall-through (and keeps ``STRICT_BACKEND_KIND`` usable
-    with the engines)."""
+    has; a site of a different kind keeps the model config's own backend
+    (``cfg_name``, ``"auto"`` by default), so an engine can pin its
+    decode backend through ServeConfig and its forward backend through
+    the ArchConfig.  Doing the kind split *here* keeps the registry's
+    kind-mismatch warning reserved for genuine configuration errors
+    instead of firing on the engines' own documented fall-through (and
+    keeps ``STRICT_BACKEND_KIND`` usable with the engines).  A name no
+    site knows is passed through, so the registry rejects it."""
     if name is None or name == AUTO:
         return name
     from repro.models import available_backends
 
+    if name not in available_backends():
+        return name
     return (name if name in available_backends(decode=decode, paged=paged)
-            else AUTO)
+            else cfg_name)
 
 
 def _sample_tokens(key: Optional[jax.Array], logits: jax.Array,
@@ -183,9 +192,9 @@ class ServingEngine(_SamplerMixin):
         cfg_fwd, cfg_dec = cfg, cfg
         if scfg.attn_backend is not None:
             cfg_fwd = dataclasses.replace(cfg, attn_backend=_backend_for_site(
-                scfg.attn_backend, decode=False))
+                scfg.attn_backend, cfg.attn_backend, decode=False))
             cfg_dec = dataclasses.replace(cfg, attn_backend=_backend_for_site(
-                scfg.attn_backend, decode=True))
+                scfg.attn_backend, cfg.attn_backend, decode=True))
         self.cfg, self.params = cfg, params
         self._init_sampler(scfg)
         self.telemetry = Telemetry(enabled=scfg.telemetry)
@@ -234,6 +243,8 @@ class ServingEngine(_SamplerMixin):
             self.cache = jax.tree.map(
                 lambda full, one: full.at[:, s:s + 1].set(one),
                 self.cache, cache1)
+            if req.return_logits:
+                req.first_logits = np.asarray(logits[0, -1], np.float32)
             nxt = int(self._pick(logits[0, -1]))
             req.output.append(nxt)
             self.telemetry.span_end("full_prefill", rid=req.rid)
@@ -304,9 +315,10 @@ class PagedServingEngine(_SamplerMixin):
         cfg_fwd, cfg_pgd = cfg, cfg
         if scfg.attn_backend is not None:
             cfg_fwd = dataclasses.replace(cfg, attn_backend=_backend_for_site(
-                scfg.attn_backend, decode=False))
+                scfg.attn_backend, cfg.attn_backend, decode=False))
             cfg_pgd = dataclasses.replace(cfg, attn_backend=_backend_for_site(
-                scfg.attn_backend, decode=True, paged=True))
+                scfg.attn_backend, cfg.attn_backend, decode=True,
+                paged=True))
         # chunked prefill needs causal cross-chunk attention.  SPLS no
         # longer disables it: the plan streams one window-aligned chunk at
         # a time (the paper's progressive generation scheme) and the
@@ -405,12 +417,19 @@ class PagedServingEngine(_SamplerMixin):
         self.pred_cache = None
         self._n_pages = n_pages
         self._retired: List[Request] = []
+        # resolved once (the same call paged_decode_step makes), so a
+        # wrong-kind name fails at construction under STRICT_BACKEND_KIND
+        # and stats name the kernel every decode tick dispatches to
+        self.decode_backend = dec_be = resolve_backend(
+            cfg_pgd.attn_backend, cfg, L=n_pages * ps, decode=True,
+            paged=True)
         # the old cache / pos_pages references die on reassignment every
         # tick, so donate them: decode scatters one token in place instead
         # of copying the whole page pool (donation is a no-op on CPU)
         self._decode = jax.jit(
             lambda p, c, pp, tb, kl, cp, t: paged_decode_step(
-                cfg_pgd, p, c, pp, tb, kl, cp, t), donate_argnums=(1, 2))
+                cfg, p, c, pp, tb, kl, cp, t, backend=dec_be),
+            donate_argnums=(1, 2))
         plan_mode = "progressive" if cfg.spls.enabled else "auto"
         self._prefill = jax.jit(
             lambda p, toks: prefill(cfg_fwd, p, toks, plan_mode=plan_mode))
@@ -477,6 +496,7 @@ class PagedServingEngine(_SamplerMixin):
                "free_pages": self.pool.free_pages,
                "guard_trips": self.pool.guard_trips,
                "compute_backend": self._compute,
+               "decode_backend": self.decode_backend,
                "flops_saved_pct": self.sched.flops_saved_pct()}
         if self._cap_q is not None:
             out["capacity_q"] = self._cap_q.snapshot()
@@ -683,6 +703,8 @@ class PagedServingEngine(_SamplerMixin):
                      args={"kept": n_kept, "prompt_len": Lp})
 
     def _emit_first(self, st: SeqState, logits_row: jax.Array) -> None:
+        if st.req.return_logits:
+            st.req.first_logits = np.asarray(logits_row, np.float32)
         tok = int(self._pick(logits_row))
         st.req.output.append(tok)
         st.budget -= 1

@@ -15,14 +15,14 @@ broadcast).
     simulation-mode semantics (zero compute saving; the numerics oracle).
   * ``packed_xla``    -- XLA ``pack_by_mask``-style execution: gather the
     packed rows, matmul at the reduced size, scatter through the leader
-    map.  Row subsets of an XLA dot are bitwise-stable, so this path is
-    bit-for-bit equal to ``dense`` whenever capacity covers every
-    critical row.
+    map.  Equal to ``dense`` up to float32 summation order (a row
+    subset of a dot need not sum in the full dot's order) whenever
+    capacity covers every critical row.
   * ``packed_pallas`` -- :mod:`repro.kernels.gathered_matmul`: the gather
     rides in the matmul's DMA schedule (scalar-prefetched row indices,
     per-row async copies into the VMEM panel) and the leader scatter is a
-    BlockSpec-index-map gather.  Compiled on TPU, ``interpret=True``
-    elsewhere (bit-accurate, slow).
+    BlockSpec-index-map gather.  Compiled on an accelerator, interpreted
+    on a CPU host (bit-accurate, slow).
 
 ``"auto"`` resolves from the platform and whether a sparsity plan exists;
 the ``dense`` default keeps every existing path byte-identical until a
@@ -76,10 +76,6 @@ def is_packed(name: Optional[str]) -> bool:
     return name in ("packed_xla", "packed_pallas")
 
 
-def _platform() -> str:
-    return jax.default_backend()
-
-
 def resolve_compute_backend(name: Optional[str], *, sparse: bool,
                             platform: Optional[str] = None) -> str:
     """Map a configured compute-backend name (possibly ``"auto"``/None) to
@@ -94,7 +90,7 @@ def resolve_compute_backend(name: Optional[str], *, sparse: bool,
     if name == AUTO:
         if not sparse:
             return DENSE
-        platform = platform or _platform()
+        platform = platform or jax.default_backend()
         return "packed_pallas" if platform == "tpu" else "packed_xla"
     if name not in _REGISTRY:
         raise ValueError(
@@ -137,14 +133,17 @@ def _packed_pallas_gathered_matmul(x: jax.Array, w: jax.Array,
                                    ) -> jax.Array:
     from repro.kernels.gathered_matmul import gathered_matmul
 
-    return gathered_matmul(x, w, perm, src_slot=src_slot,
-                           interpret=_platform() != "tpu")
+    # the kernel accumulates in float32; round to the operands' result
+    # dtype like the XLA backends' einsum does, so every backend hands
+    # the next op the same dtype (bf16 serving rounds at the same point)
+    return gathered_matmul(x, w, perm, src_slot=src_slot).astype(
+        jnp.result_type(x, w))
 
 
 def _packed_pallas_gather_rows(rows: jax.Array, idx: jax.Array) -> jax.Array:
     from repro.kernels.gathered_matmul import gather_rows_kernel
 
-    return gather_rows_kernel(rows, idx, interpret=_platform() != "tpu")
+    return gather_rows_kernel(rows, idx)
 
 
 register_compute_backend(

@@ -1,9 +1,9 @@
 """Packed execution of the SPLS-sparsified linear ops.
 
 Two operations, both dispatched through the compute-backend registry
-(:mod:`repro.sparse_compute.backend`) and both row-for-row bitwise equal
-to their dense counterparts (row subsets of an XLA dot are bitwise
-stable; the Pallas backend runs the whole contraction per tile -- see
+(:mod:`repro.sparse_compute.backend`) and both row-for-row equal to their
+dense counterparts up to float32 summation order (a row subset of a dot
+need not sum in the full dot's order -- see
 ``kernels/gathered_matmul.py``):
 
 * :func:`packed_project_q` -- Q projection of a packed row subset in the
@@ -38,9 +38,10 @@ def packed_project_q(cfg, p: dict, xn: jax.Array, positions: jax.Array,
 
     xn: (1, L, D) normalized block input; positions: (L,) original row
     ids; perm: (C,) packed source rows.  Returns ``(1, KV, G, C, Dh)``
-    whose slot ``c`` is bit-for-bit row ``perm[c]`` of
+    whose slot ``c`` is row ``perm[c]`` of
     :func:`repro.models.attention.project_qkv`'s q output (einsum row
-    subset + row-wise qk-norm/RoPE) -- the parity tests pin this.
+    subset + row-wise qk-norm/RoPE), to float32 summation order -- the
+    parity tests pin this.
     """
     D, KV, Dh = cfg.d_model, cfg.n_kv_heads, cfg.resolved_head_dim
     G = cfg.n_heads // KV
@@ -65,11 +66,11 @@ def packed_project_kv(cfg, p: dict, xn: jax.Array, positions: jax.Array,
     ids; perm: (C,) packed source rows (the horizon-finalized keep
     decision of :func:`repro.core.planner.own_column_keep`, packed by
     :func:`repro.core.sparse_exec.pack_by_mask`).  Returns
-    ``(k, v)`` of shape ``(1, KV, C, Dh)`` whose slot ``c`` is
-    bit-for-bit row ``perm[c]`` of
-    :func:`repro.models.attention.project_kv`'s dense output (einsum row
-    subset + row-wise k-norm/RoPE at the original positions) -- the
-    parity tests pin this.  This is the K/V half of the paper's
+    ``(k, v)`` of shape ``(1, KV, C, Dh)`` whose slot ``c`` is row
+    ``perm[c]`` of :func:`repro.models.attention.project_kv`'s dense
+    output (einsum row subset + row-wise k-norm/RoPE at the original
+    positions), to float32 summation order -- the parity tests pin
+    this.  This is the K/V half of the paper's
     end-to-end sparsity: columns the horizon vote finalized as pruned are
     never projected at all.
     """

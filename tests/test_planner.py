@@ -428,7 +428,11 @@ class TestPackedKV:
     @pytest.mark.parametrize("backend", ["packed_xla", "packed_pallas"])
     def test_packed_project_kv_bitwise(self, backend):
         """packed_project_kv slot c == row perm[c] of the dense
-        project_kv output, bit for bit (XLA and Pallas-interpret)."""
+        project_kv output (XLA and Pallas-interpret), to float32
+        summation order: the reference runs at "highest" matmul precision
+        and a row subset of a dot need not sum in the full dot's order,
+        so the bound is a few ulps of the D=32 contraction (D * eps ~
+        4e-6 relative), far below any bf16 or wrong-row error."""
         from repro.models.attention import project_kv
         cfg = _spls_cfg()
         params = _params(cfg)
@@ -436,14 +440,15 @@ class TestPackedKV:
         p = jax.tree.map(lambda a: a.astype(jnp.float32), blk0["attn"])
         xn = jax.random.normal(jax.random.PRNGKey(7), (1, 16, cfg.d_model))
         positions = jnp.arange(16)[None, :]
-        kd, vd = project_kv(cfg, p, xn, positions, "structured")
+        with jax.default_matmul_precision("highest"):
+            kd, vd = project_kv(cfg, p, xn, positions, "structured")
         perm = jnp.asarray([3, 0, 7, 12, 12, 5], jnp.int32)
         kp, vp = project_kv(cfg, p, xn, positions, "structured", perm=perm,
                             compute_backend=backend)
-        np.testing.assert_array_equal(np.asarray(kp),
-                                      np.asarray(kd[:, :, perm]))
-        np.testing.assert_array_equal(np.asarray(vp),
-                                      np.asarray(vd[:, :, perm]))
+        np.testing.assert_allclose(np.asarray(kp), np.asarray(kd[:, :, perm]),
+                                   rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(np.asarray(vp), np.asarray(vd[:, :, perm]),
+                                   rtol=1e-5, atol=1e-5)
 
     def test_whole_prompt_packed_routing(self):
         """Short prompts (<= one chunk) under a packed compute backend
@@ -477,10 +482,14 @@ class TestPackedKV:
 
     def test_double_buffered_gather_multi_tile(self):
         """The double-buffered per-row DMA gather stays bitwise equal to
-        the XLA oracle across multiple row tiles (interpret mode)."""
+        the XLA oracle across multiple row tiles (interpret mode).  The
+        data are small integers, so every product and partial sum is
+        exact and equality holds whatever order each dot sums in."""
         from repro.kernels.gathered_matmul import gathered_matmul
-        x = jax.random.normal(jax.random.PRNGKey(11), (100, 32))
-        w = jax.random.normal(jax.random.PRNGKey(12), (32, 48))
+        x = jax.random.randint(jax.random.PRNGKey(11), (100, 32), -8,
+                               9).astype(jnp.float32)
+        w = jax.random.randint(jax.random.PRNGKey(12), (32, 48), -8,
+                               9).astype(jnp.float32)
         perm = jax.random.randint(jax.random.PRNGKey(13), (70,), 0, 100)
         out = gathered_matmul(x, w, perm, bm=16, interpret=True)
         np.testing.assert_array_equal(np.asarray(out),

@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 from repro.configs.base import ArchConfig, BlockCfg
 from repro.core.spls import SPLSConfig
@@ -185,9 +186,9 @@ def _iter_jaxprs(j):
         for v in eqn.params.values():
             vs = v if isinstance(v, (tuple, list)) else (v,)
             for u in vs:
-                if isinstance(u, jax.core.ClosedJaxpr):
+                if isinstance(u, ClosedJaxpr):
                     yield from _iter_jaxprs(u.jaxpr)
-                elif isinstance(u, jax.core.Jaxpr):
+                elif isinstance(u, Jaxpr):
                     yield from _iter_jaxprs(u)
 
 

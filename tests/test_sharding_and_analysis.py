@@ -144,8 +144,6 @@ class TestHLOAnalysis:
         compiled = jax.jit(f).lower(x, w).compile()
         stats = parse_hlo_stats(compiled.as_text())
         ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):  # jax<0.5 returns [dict] per device
-            ca = ca[0]
         # dots dominate; analyzer within 10% of XLA flops
         assert abs(stats["dot_flops"] - ca["flops"]) / ca["flops"] < 0.1
 

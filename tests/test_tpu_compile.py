@@ -1,0 +1,119 @@
+"""Every Pallas kernel the TPU ``auto`` backends can select, compiled for
+a described (not attached) TPU v5e chip at published widths.
+
+Interpret mode accepts block shapes and DMA slices the TPU compiler
+refuses, so each main-path kernel is compiled here with
+``interpret=False`` and checked to lower to a Mosaic ``tpu_custom_call``:
+
+* Qwen3-0.6B serving widths -- 16 query / 8 KV heads of dim 128, d_model
+  1024, d_ff 3072, page size 16, prefill chunk 64;
+* bert-base-esact's non-causal attention (12 heads of dim 64, L = 512).
+
+The topology is described inside a fixture, never at import: only one
+process may load the TPU library at a time, so every test worker must
+collect the same tests and only the worker running this file loads it.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.flash_decode import flash_decode
+from repro.kernels.gathered_matmul import gather_rows_kernel, gathered_matmul
+from repro.kernels.paged_decode import paged_flash_decode
+
+BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
+# Qwen3-0.6B (configs/qwen3_0_6b.py) at the serving engine's page/chunk
+H, KV, DH, D, FF = 16, 8, 128, 1024, 3072
+PAGE, CHUNK, SLOTS, MAX_LEN = 16, 64, 4, 1024
+N_PAGES = SLOTS * MAX_LEN // PAGE + 1
+# bert-base-esact (configs/bert_base_esact.py) at the paper's L = 512
+BERT_H, BERT_DH, BERT_L = 12, 64, 512
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    # the compiler's own logs would otherwise land in the temp directory
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler on this host
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    # a described chip's compiles cannot be read back from the persistent
+    # cache without the chip, so keep them out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _flash(causal):
+    return lambda q, k, v: flash_attention(q, k, v, causal=causal,
+                                           interpret=False)
+
+
+def _flash_spls(causal):
+    return lambda q, k, v, qp, keep: flash_attention(
+        q, k, v, causal=causal, q_pos=qp, kv_keep=keep, interpret=False)
+
+
+# name -> (fn, [(shape, dtype), ...])
+CASES = {
+    "flash_qwen_causal": (_flash(True), [
+        ((1, H, MAX_LEN, DH), BF16), ((1, KV, MAX_LEN, DH), BF16),
+        ((1, KV, MAX_LEN, DH), BF16)]),
+    "flash_qwen_spls": (_flash_spls(True), [
+        ((1, H, MAX_LEN // 2, DH), BF16), ((1, KV, MAX_LEN, DH), BF16),
+        ((1, KV, MAX_LEN, DH), BF16), ((1, H, MAX_LEN // 2), I32),
+        ((1, H, MAX_LEN), jnp.bool_)]),
+    "flash_bert_noncausal": (_flash(False), [
+        ((1, BERT_H, BERT_L, BERT_DH), BF16)] * 3),
+    "flash_bert_noncausal_spls": (_flash_spls(False), [
+        ((1, BERT_H, BERT_L // 2, BERT_DH), BF16),
+        ((1, BERT_H, BERT_L, BERT_DH), BF16),
+        ((1, BERT_H, BERT_L, BERT_DH), BF16), ((1, BERT_H, BERT_L // 2), I32),
+        ((1, BERT_H, BERT_L), jnp.bool_)]),
+    "paged_flash_decode": (
+        lambda q, kp, vp, pp, tb, kl, pos: paged_flash_decode(
+            q, kp, vp, pp, tb, kl, pos, interpret=False), [
+            ((SLOTS, KV, H // KV, DH), BF16),
+            ((KV, N_PAGES, PAGE, DH), BF16), ((KV, N_PAGES, PAGE, DH), BF16),
+            ((N_PAGES, PAGE), I32), ((SLOTS, MAX_LEN // PAGE), I32),
+            ((SLOTS,), I32), ((SLOTS,), I32)]),
+    "flash_decode": (
+        lambda q, k, v, pos: flash_decode(q, k, v, pos, interpret=False), [
+            ((SLOTS, KV, H // KV, DH), BF16), ((SLOTS, KV, MAX_LEN, DH), BF16),
+            ((SLOTS, KV, MAX_LEN, DH), BF16), ((SLOTS,), I32)]),
+    # packed Q projection of a chunk: (CHUNK, D) @ (D, H*DH) on 48 rows
+    "gathered_matmul_q": (
+        lambda x, w, p: gathered_matmul(x, w, p, interpret=False), [
+            ((CHUNK, D), BF16), ((D, H * DH), BF16), ((48,), I32)]),
+    # packed FFN up-projection with the fused leader scatter
+    "gathered_matmul_ffn_scatter": (
+        lambda x, w, p, s: gathered_matmul(x, w, p, src_slot=s,
+                                           interpret=False), [
+            ((CHUNK, D), BF16), ((D, FF), BF16), ((48,), I32),
+            ((CHUNK,), I32)]),
+    "gather_rows_kernel": (
+        lambda src, idx: gather_rows_kernel(src, idx, interpret=False), [
+            ((48, D), BF16), ((CHUNK,), I32)]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_compiles_for_v5e(one_chip, name):
+    fn, specs = CASES[name]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in specs]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text(), name
