@@ -118,9 +118,9 @@ def run():
     # timed pair isolates what the two-semaphore DMA pipeline buys.  On
     # CPU both run interpret-mode (parity only); on TPU they compile and
     # the timing delta is the measurement ROADMAP carries forward.  The
-    # dispatch is wrapped in jax.profiler.TraceAnnotation
-    # ("gathered_matmul/{buffered,serialized}"), so a jax.profiler trace
-    # of this benchmark names each variant on the TPU timeline.
+    # kernel's ops carry the named scope
+    # "gathered_matmul/{buffered,serialized}", so a jax.profiler trace of
+    # this benchmark names each variant beside its ops on the TPU.
     from repro.kernels import gathered_matmul
 
     L, D, F, C = 512, 256, 256, 128

@@ -164,9 +164,10 @@ def gathered_matmul(x: jax.Array, w: jax.Array, perm: jax.Array,
     ``double_buffer=False`` serializes the row gather (start+wait per
     row, no overlap) -- bitwise identical, kept as the timing baseline
     that isolates what the two-semaphore pipeline buys
-    (``benchmarks/bench_kernels.py`` times both; the dispatch carries a
-    ``jax.profiler.TraceAnnotation`` so on-TPU profiles name the
-    variant).  ``interpret=None`` interprets on CPU only.
+    (``benchmarks/bench_kernels.py`` times both; the kernel's ops carry
+    the named scope ``gathered_matmul/<variant>``, so a device trace
+    names the variant beside each op).  ``interpret=None`` interprets on
+    CPU only.
     """
     interpret = resolve_interpret(interpret)
     L, D = x.shape
@@ -183,11 +184,10 @@ def gathered_matmul(x: jax.Array, w: jax.Array, perm: jax.Array,
     pf = (-F) % bn
     if pf:
         w = jnp.pad(w, ((0, 0), (0, pf)))
-    # named profiler annotation: on-TPU traces (and Perfetto exports of
-    # jax.profiler captures) attribute the dispatch to the exact gather
-    # schedule being measured
+    # a named scope, kept in the compiled ops' metadata: a device trace
+    # attributes the kernel's ops to the gather schedule being measured
     variant = "buffered" if double_buffer else "serialized"
-    with jax.profiler.TraceAnnotation(f"gathered_matmul/{variant}"):
+    with jax.named_scope(f"gathered_matmul/{variant}"):
         out = _gathered_matmul_padded(x.astype(jnp.float32),
                                       w.astype(jnp.float32),
                                       perm.astype(jnp.int32),

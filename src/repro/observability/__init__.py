@@ -11,7 +11,7 @@ how to open traces in Perfetto.
 
 from .metrics import (Counter, CounterDictView, Gauge, Histogram,
                       MetricsRegistry, NullInstrument, percentile)
-from .trace import ENGINE_TRACK, TraceRecorder
+from .trace import ENGINE_TRACK, PHASE_PID, TraceRecorder
 from .sparsity import SparsityInstruments, tree_bytes
 from .telemetry import RequestRecord, Telemetry
 from .report import (SCHEMA_VERSION, latency_ms, serving_report,
@@ -19,7 +19,8 @@ from .report import (SCHEMA_VERSION, latency_ms, serving_report,
 
 __all__ = [
     "Counter", "CounterDictView", "Gauge", "Histogram", "MetricsRegistry",
-    "NullInstrument", "percentile", "ENGINE_TRACK", "TraceRecorder",
+    "NullInstrument", "percentile", "ENGINE_TRACK", "PHASE_PID",
+    "TraceRecorder",
     "SparsityInstruments", "tree_bytes",
     "RequestRecord", "Telemetry",
     "SCHEMA_VERSION", "latency_ms", "serving_report", "validate_report",

@@ -20,17 +20,26 @@ One :class:`Telemetry` object per engine bundles the pieces:
 Every timestamp comes from the injected monotonic clock via
 ``Telemetry.now()`` -- host-side only, after device readback; nothing
 here is ever traced by jit.
+
+:meth:`Telemetry.span` times one host phase on two clocks at once: a
+Chrome B/E pair (on the phase process, :data:`~.trace.PHASE_PID`) and a
+``jax.profiler.TraceAnnotation``, which lands on the host plane of a
+``jax.profiler`` trace beside the device's ops, so a device-idle gap can
+be laid against the phase the host was in.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from typing import Dict, List, Optional
 
+import jax
+
 from .metrics import MetricsRegistry
 from .sparsity import SparsityInstruments
-from .trace import TraceRecorder
+from .trace import ENGINE_TRACK, PHASE_PID, TraceRecorder
 
 __all__ = ["RequestRecord", "Telemetry"]
 
@@ -65,6 +74,32 @@ class RequestRecord:
             return None
         return (self.last_token_ts - self.first_token_ts) \
             / (self.n_tokens - 1)
+
+
+# what a disabled Telemetry.span returns: reentrant and shared
+_NO_SPAN = contextlib.nullcontext()
+
+
+class _Span:
+    """One host phase on both sinks; see :meth:`Telemetry.span`."""
+
+    __slots__ = ("tel", "name", "args", "annotation")
+
+    def __init__(self, tel: "Telemetry", name: str, args: dict):
+        self.tel, self.name, self.args = tel, name, args
+
+    def __enter__(self) -> None:
+        self.tel.trace.begin(self.name, self.tel.now(), ENGINE_TRACK,
+                             args=self.args or None, pid=PHASE_PID)
+        self.annotation = jax.profiler.TraceAnnotation(self.name,
+                                                       **self.args)
+        self.annotation.__enter__()
+
+    def __exit__(self, *exc) -> bool:
+        self.annotation.__exit__(*exc)
+        self.tel.trace.end(self.name, self.tel.now(), ENGINE_TRACK,
+                           pid=PHASE_PID)
+        return False
 
 
 class Telemetry:
@@ -192,6 +227,17 @@ class Telemetry:
         self.metrics.counter("tokens/emitted").inc(len(rids))
 
     # -- engine phases -------------------------------------------------
+    def span(self, name: str, **args):
+        """``with tel.span("engine/decode_readback"):`` -- one host phase,
+        recorded as a Chrome span on the phase process and as a profiler
+        annotation (``args`` become the annotation's stats).  It times the
+        host only: a phase that dispatches a jitted step ends at the
+        enqueue, not at the device's end of the step.  Disabled, it
+        returns a shared no-op context and records nothing."""
+        if not self.enabled:
+            return _NO_SPAN
+        return _Span(self, name, args)
+
     def span_begin(self, name: str, rid: Optional[int] = None,
                    args: Optional[dict] = None) -> None:
         if not self.enabled:

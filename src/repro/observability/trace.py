@@ -8,6 +8,9 @@ events (``first_token``, ``preempt``, ``abort``) as instants.  Export is
 the Chrome trace-event JSON array format: load the file at
 https://ui.perfetto.dev (or chrome://tracing) and each request renders
 as its own track (``tid`` = request id; ``tid 0`` is the engine track).
+The engine's host phases (``engine/tick`` and the phases inside it, from
+:meth:`repro.observability.Telemetry.span`) render as a process of their
+own (``pid`` :data:`PHASE_PID`), so they nest apart from ``decode_tick``.
 
 Timestamps come from the caller (the registry's injected monotonic
 clock), converted to the format's microsecond unit at export.  Spans are
@@ -25,11 +28,15 @@ from __future__ import annotations
 import json
 from typing import List, Optional
 
-__all__ = ["TraceRecorder", "ENGINE_TRACK"]
+__all__ = ["TraceRecorder", "ENGINE_TRACK", "PHASE_PID"]
 
 # tid of the engine-scope track (requests use tid = rid + 1 so rid 0
 # does not collide with the engine track)
 ENGINE_TRACK = 0
+# pid of the engine's host-phase spans: a phase such as
+# ``engine/decode_prepare`` starts before ``decode_tick`` and ends inside
+# it, so the two cannot nest on one track
+PHASE_PID = 2
 
 
 class TraceRecorder:
@@ -60,25 +67,26 @@ class TraceRecorder:
         return rid + 1
 
     def begin(self, name: str, ts: float, tid: int = ENGINE_TRACK,
-              args: Optional[dict] = None) -> None:
+              args: Optional[dict] = None,
+              pid: Optional[int] = None) -> None:
         if not self.enabled:
             return
-        ev = {"ph": "B", "name": name, "ts": ts, "pid": self.pid,
-              "tid": tid}
+        pid = self.pid if pid is None else pid
+        ev = {"ph": "B", "name": name, "ts": ts, "pid": pid, "tid": tid}
         if args:
             ev["args"] = args
-        self._stacks.setdefault((self.pid, tid), []).append(name)
+        self._stacks.setdefault((pid, tid), []).append(name)
         self._emit(ev)
 
     def end(self, name: str, ts: float, tid: int = ENGINE_TRACK,
-            args: Optional[dict] = None) -> None:
+            args: Optional[dict] = None, pid: Optional[int] = None) -> None:
         if not self.enabled:
             return
-        ev = {"ph": "E", "name": name, "ts": ts, "pid": self.pid,
-              "tid": tid}
+        pid = self.pid if pid is None else pid
+        ev = {"ph": "E", "name": name, "ts": ts, "pid": pid, "tid": tid}
         if args:
             ev["args"] = args
-        stack = self._stacks.get((self.pid, tid))
+        stack = self._stacks.get((pid, tid))
         if stack and stack[-1] == name:
             stack.pop()
         self._emit(ev)

@@ -22,6 +22,7 @@ with ``temperature`` through a PRNG key threaded from ``seed``.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 import warnings
 from collections import deque
@@ -142,9 +143,24 @@ def _backend_for_site(name: Optional[str], cfg_name: Optional[str], *,
             else cfg_name)
 
 
-def _sample_tokens(key: Optional[jax.Array], logits: jax.Array,
-                   greedy: bool, temperature: float) -> jax.Array:
-    """logits (..., V) -> (...,) int32 token ids."""
+def _program(fn, *bound, **fixed):
+    """``fn`` with its leading arguments ``bound`` and keywords ``fixed``,
+    under ``fn``'s own name: ``jax.jit`` of it compiles a program named
+    ``jit_<fn>``, which is how the device trace names it (a lambda or a
+    ``functools.partial`` would read ``jit__lambda`` or
+    ``jit__unknown``)."""
+    def program(*args, **kwargs):
+        return fn(*bound, *args, **fixed, **kwargs)
+
+    program.__name__ = program.__qualname__ = fn.__name__
+    return program
+
+
+@functools.partial(jax.jit, static_argnames=("greedy", "temperature"))
+def sample_tokens(key: Optional[jax.Array], logits: jax.Array,
+                  greedy: bool, temperature: float) -> jax.Array:
+    """logits (..., V) -> (...,) int32 token ids; one program,
+    ``jit_sample_tokens``."""
     if greedy or temperature <= 0.0:
         return jnp.argmax(logits, axis=-1).astype(jnp.int32)
     return jax.random.categorical(
@@ -161,8 +177,8 @@ class _SamplerMixin:
         key = None
         if not self.scfg.greedy:
             self._key, key = jax.random.split(self._key)
-        return _sample_tokens(key, logits, self.scfg.greedy,
-                              self.scfg.temperature)
+        return sample_tokens(key, logits, greedy=self.scfg.greedy,
+                             temperature=self.scfg.temperature)
 
 
 # ---------------------------------------------------------------------------
@@ -204,15 +220,14 @@ class ServingEngine(_SamplerMixin):
         self.tokens = jnp.zeros((scfg.n_slots, 1), jnp.int32)
         self.cache = init_cache(cfg, scfg.n_slots, scfg.max_len)
         self._retired: List[Request] = []
-        self._decode = jax.jit(
-            lambda p, c, t, pos: decode_step(cfg_dec, p, c, t, pos))
+        self._decode = jax.jit(_program(decode_step, cfg_dec))
         # SPLS configs prefill with the progressive (streaming-
         # reproducible) plan builder so this engine stays the exact parity
         # oracle for the paged engine's chunked SPLS prefill
         plan_mode = "progressive" if cfg.spls.enabled else "auto"
-        self._prefill = jax.jit(
-            lambda p, toks: prefill(cfg_fwd, p, toks, max_len=scfg.max_len,
-                                    plan_mode=plan_mode))
+        self._prefill = jax.jit(_program(prefill, cfg_fwd,
+                                         max_len=scfg.max_len,
+                                         plan_mode=plan_mode))
 
     # ------------------------------------------------------------------
     @property
@@ -425,28 +440,24 @@ class PagedServingEngine(_SamplerMixin):
             paged=True)
         # the old cache / pos_pages references die on reassignment every
         # tick, so donate them: decode scatters one token in place instead
-        # of copying the whole page pool (donation is a no-op on CPU)
+        # of copying the whole page pool (donation is a no-op on CPU).
+        # Every program keeps its function's name (jit_paged_decode_step,
+        # ...), so the device trace can say which one an op ran in.
         self._decode = jax.jit(
-            lambda p, c, pp, tb, kl, cp, t: paged_decode_step(
-                cfg, p, c, pp, tb, kl, cp, t, backend=dec_be),
+            _program(paged_decode_step, cfg, backend=dec_be),
             donate_argnums=(1, 2))
         plan_mode = "progressive" if cfg.spls.enabled else "auto"
-        self._prefill = jax.jit(
-            lambda p, toks: prefill(cfg_fwd, p, toks, plan_mode=plan_mode))
-        self._votes = jax.jit(
-            lambda p, toks: spls_token_votes(cfg, p, toks))
-        self._chunk = jax.jit(
-            lambda p, c, pp, tb, start, toks, valid: paged_prefill_chunk(
-                cfg, p, c, pp, tb, start, toks, valid),
-            donate_argnums=(1, 2))
+        self._prefill = jax.jit(_program(prefill, cfg_fwd,
+                                         plan_mode=plan_mode))
+        self._votes = jax.jit(_program(spls_token_votes, cfg))
+        self._chunk = jax.jit(_program(paged_prefill_chunk, cfg),
+                              donate_argnums=(1, 2))
         # SPLS chunk step: one jit covers *all* prompt lengths (top-k
         # count, start, and valid ride in as traced scalars); under packed
         # compute, one jit per capacity-bucket pair (the controller keeps
         # the pair set small)
         self._chunk_spls_jits: dict = {}
-        self._compact = jax.jit(
-            lambda c, pp, tb, keep: compact_slots(c, pp, tb, keep),
-            donate_argnums=(0, 1))
+        self._compact = jax.jit(compact_slots, donate_argnums=(0, 1))
         # pool byte gauges (metadata only, no device sync); the predictor
         # cache gauge updates when its lazy allocation lands
         self.telemetry.sparsity.note_pool_bytes(tree_bytes(self.cache))
@@ -456,31 +467,18 @@ class PagedServingEngine(_SamplerMixin):
         """Jitted SPLS chunk step for one capacity-bucket triple (dense
         compute uses the single ``(None, None, None)`` entry); ``horizon``
         adds the liveness-mask + decode-anchor operands of the
-        horizon-finalized vote."""
+        horizon-finalized vote, passed as the keywords ``live`` and
+        ``last_keep``."""
         key = (cq, cf, ckv, horizon)
         fn = self._chunk_spls_jits.get(key)
         if fn is None:
-            cfg, cb = self.cfg, self._compute
-            need = self._vote_need
+            fixed = dict(q_capacity=cq, ffn_capacity=cf,
+                         compute_backend=self._compute)
             if horizon:
-                fn = jax.jit(
-                    lambda p, c, pc, pp, tb, start, toks, valid, k, lv, lk:
-                    paged_prefill_chunk_spls(cfg, p, c, pc, pp, tb, start,
-                                             toks, valid, k, q_capacity=cq,
-                                             ffn_capacity=cf,
-                                             kv_capacity=ckv,
-                                             compute_backend=cb,
-                                             live=lv, last_keep=lk,
-                                             kv_vote_need=need),
-                    donate_argnums=(1, 2, 3))
-            else:
-                fn = jax.jit(
-                    lambda p, c, pc, pp, tb, start, toks, valid, k:
-                    paged_prefill_chunk_spls(cfg, p, c, pc, pp, tb, start,
-                                             toks, valid, k, q_capacity=cq,
-                                             ffn_capacity=cf,
-                                             compute_backend=cb),
-                    donate_argnums=(1, 2, 3))
+                fixed.update(kv_capacity=ckv, kv_vote_need=self._vote_need)
+            fn = jax.jit(_program(paged_prefill_chunk_spls, self.cfg,
+                                  **fixed),
+                         donate_argnums=(1, 2, 3))
             self._chunk_spls_jits[key] = fn
         return fn
 
@@ -530,49 +528,76 @@ class PagedServingEngine(_SamplerMixin):
         return row
 
     def _full_prefill(self, st: SeqState) -> None:
+        """Whole-prompt prefill.  ``engine/full_prefill`` covers the
+        dispatch and, with page pruning, the vote readback."""
         tel = self.telemetry
-        tel.span_begin("full_prefill", rid=st.req.rid,
-                       args={"prompt_len": st.prompt_len})
-        toks = jnp.asarray(st.tokens, jnp.int32)[None, :]
-        logits, dense_cache = self._prefill(self.params, toks)
-        if self._prune:
-            keep = keep_from_votes(self._votes(self.params, toks[0]),
-                                   self.cfg.n_heads,
-                                   self.scfg.spls_prune_vote)
-        else:
-            keep = np.ones((st.prompt_len,), bool)
-        keep_idx = np.nonzero(keep)[0]
-        n_kept = len(keep_idx)
-        if not self.sched.grow_to(st, n_kept):
-            # st itself was preempted (span unwound by the preempt/abort
-            # telemetry); prefill recomputes later
-            return
-        dest = self._dest_slots(st, n_kept)
-        self.cache, self.pos_pages = scatter_prefill(
-            self.cache, self.pos_pages, dense_cache,
-            jnp.asarray(keep_idx, jnp.int32), jnp.asarray(dest, jnp.int32))
-        st.kv_len = n_kept
-        st.cur_pos = st.prompt_len
-        st.prefilled = st.prompt_len
-        # whole-prompt prefill runs dense/simulation compute (packed
-        # capacities apply on the chunked path); charged dense == executed
-        self.sched.note_flops(chunk_flops(self.cfg, st.prompt_len,
-                                          st.prompt_len))
-        if self._prune:
-            self.sched.note_prune(st.prompt_len, n_kept)
-            tel.sparsity.note_prune(st.prompt_len, n_kept)
-        tel.span_end("full_prefill", rid=st.req.rid,
-                     args={"kept": n_kept})
+        with tel.span("engine/full_prefill", rid=st.req.rid,
+                      prompt_len=st.prompt_len):
+            tel.span_begin("full_prefill", rid=st.req.rid,
+                           args={"prompt_len": st.prompt_len})
+            toks = jnp.asarray(st.tokens, jnp.int32)[None, :]
+            logits, dense_cache = self._prefill(self.params, toks)
+            if self._prune:
+                keep = keep_from_votes(self._votes(self.params, toks[0]),
+                                       self.cfg.n_heads,
+                                       self.scfg.spls_prune_vote)
+            else:
+                keep = np.ones((st.prompt_len,), bool)
+            keep_idx = np.nonzero(keep)[0]
+            n_kept = len(keep_idx)
+            if not self.sched.grow_to(st, n_kept):
+                # st itself was preempted (span unwound by the
+                # preempt/abort telemetry); prefill recomputes later
+                return
+            dest = self._dest_slots(st, n_kept)
+            self.cache, self.pos_pages = scatter_prefill(
+                self.cache, self.pos_pages, dense_cache,
+                jnp.asarray(keep_idx, jnp.int32),
+                jnp.asarray(dest, jnp.int32))
+            st.kv_len = n_kept
+            st.cur_pos = st.prompt_len
+            st.prefilled = st.prompt_len
+            # whole-prompt prefill runs dense/simulation compute (packed
+            # capacities apply on the chunked path); charged dense ==
+            # executed
+            self.sched.note_flops(chunk_flops(self.cfg, st.prompt_len,
+                                              st.prompt_len))
+            if self._prune:
+                self.sched.note_prune(st.prompt_len, n_kept)
+                tel.sparsity.note_prune(st.prompt_len, n_kept)
+            tel.span_end("full_prefill", rid=st.req.rid,
+                         args={"kept": n_kept})
         self._emit_first(st, logits[0, -1])
 
     def _chunk_prefill(self, st: SeqState) -> None:
-        tel = self.telemetry
+        """One prompt chunk.  ``engine/prefill_chunk`` covers the page
+        growth, the host preparation and the dispatch; the dense step is
+        enqueued and not waited for, while the SPLS step reads its votes
+        back inside the span."""
         cs = self.sched.cfg.prefill_chunk
         start = st.prefilled                 # == st.kv_len (columns stay
         #                          dense until the end-of-prefill compaction)
         valid = min(cs, st.prompt_len - start)
-        if not self.sched.grow_to(st, start + valid):
+        with self.telemetry.span("engine/prefill_chunk", rid=st.req.rid,
+                                 start=start, valid=valid):
+            logits = self._chunk_step(st, start, valid)
+        if logits is None:
             return   # preempted/aborted; telemetry unwound the track
+        if st.phase == "decode":
+            if self._prune and self.cfg.spls.enabled:
+                with self.telemetry.span("engine/prune_compact",
+                                         rid=st.req.rid):
+                    self._finish_chunk_prune(st)
+            self._emit_first(st, logits[0, 0])
+
+    def _chunk_step(self, st: SeqState, start: int,
+                    valid: int) -> Optional[jax.Array]:
+        """Grow the sequence's pages and run the chunk step; the logits,
+        or None where growing preempted the sequence."""
+        tel = self.telemetry
+        cs = self.sched.cfg.prefill_chunk
+        if not self.sched.grow_to(st, start + valid):
+            return None
         tel.span_begin("prefill_chunk", rid=st.req.rid,
                        args={"start": start, "valid": valid})
         chunk = np.zeros((cs,), np.int32)
@@ -600,14 +625,15 @@ class PagedServingEngine(_SamplerMixin):
                     jnp.asarray(start, jnp.int32),
                     jnp.asarray(chunk)[None, :],
                     jnp.asarray(valid, jnp.int32), jnp.asarray(k, jnp.int32)]
+            kw = {}
             if horizon is not None:
                 if st.live is None:
                     st.live = np.ones((S,), bool)
-                args += [jnp.asarray(st.live),
-                         jnp.asarray(last_keep, jnp.int32)]
+                kw = dict(live=jnp.asarray(st.live),
+                          last_keep=jnp.asarray(last_keep, jnp.int32))
             (logits, self.cache, self.pred_cache, self.pos_pages,
              kv_any, counts) = self._get_chunk_spls(
-                cq, cf, ckv, horizon is not None)(*args)
+                cq, cf, ckv, horizon is not None)(*args, **kw)
             if self._prune:
                 # cross-chunk vote accumulator: a head's "some row kept
                 # this column" bit only ever turns on, so OR is exact
@@ -659,10 +685,7 @@ class PagedServingEngine(_SamplerMixin):
         st.cur_pos += valid
         self.sched.stats["prefill_chunks"] += 1
         tel.span_end("prefill_chunk", rid=st.req.rid)
-        if st.phase == "decode":
-            if self._prune and self.cfg.spls.enabled:
-                self._finish_chunk_prune(st)
-            self._emit_first(st, logits[0, 0])
+        return logits
 
     def _finish_chunk_prune(self, st: SeqState) -> None:
         """The page-prune vote is final once every prompt row has voted
@@ -703,40 +726,77 @@ class PagedServingEngine(_SamplerMixin):
                      args={"kept": n_kept, "prompt_len": Lp})
 
     def _emit_first(self, st: SeqState, logits_row: jax.Array) -> None:
-        if st.req.return_logits:
-            st.req.first_logits = np.asarray(logits_row, np.float32)
-        tok = int(self._pick(logits_row))
-        st.req.output.append(tok)
-        st.budget -= 1
+        """The first token; its readback waits for the prefill step."""
+        with self.telemetry.span("engine/emit_first", rid=st.req.rid):
+            if st.req.return_logits:
+                st.req.first_logits = np.asarray(logits_row, np.float32)
+            tok = int(self._pick(logits_row))
+            st.req.output.append(tok)
+            st.budget -= 1
         self.telemetry.first_token(st.req.rid)
 
     # ------------------------------------------------------------------
     def tick(self) -> int:
-        """One engine iteration; returns number of slots decoded."""
-        self.sched.admit()
+        """One engine iteration; returns number of slots decoded.
 
-        for st in self.sched.plan_prefills():
-            if self.sched.slots[st.slot] is not st:
-                continue  # preempted by an earlier prefill this tick
-            if self.sched.use_chunks(st.prompt_len):
-                self._chunk_prefill(st)
-            else:
-                self._full_prefill(st)
-        self._retire_finished()  # prefill-emitted token may hit eos/budget
+        With telemetry on, ``engine/tick`` holds one span per host phase
+        (:meth:`Telemetry.span`): ``engine/admit``, then per planned
+        prefill ``engine/prefill_chunk`` (or ``engine/full_prefill``),
+        ``engine/prune_compact`` and ``engine/emit_first``, then
+        ``engine/retire``, ``engine/decode_prepare``,
+        ``engine/decode_dispatch``, ``engine/decode_readback``,
+        ``engine/retire`` and ``engine/pool_observe``.  None of them
+        waits for the device."""
+        tel = self.telemetry
+        with tel.span("engine/tick"):
+            with tel.span("engine/admit"):
+                self.sched.admit()
+                plans = self.sched.plan_prefills()
+            for st in plans:
+                if self.sched.slots[st.slot] is not st:
+                    continue  # preempted by an earlier prefill this tick
+                if self.sched.use_chunks(st.prompt_len):
+                    self._chunk_prefill(st)
+                else:
+                    self._full_prefill(st)
+            with tel.span("engine/retire"):
+                # a prefill-emitted token may hit eos/budget
+                self._retire_finished()
+            n_decoded = self._decode_tick()
+            with tel.span("engine/retire"):
+                self._retire_finished()
+            with tel.span("engine/pool_observe"):
+                # sample after retirement so a drained pool reads 0
+                tel.sparsity.observe_pool(self.pool)
+        return n_decoded
 
-        # grow pages for every decode-ready row (may preempt the youngest)
-        for st in list(self.sched.decode_ready()):
-            if self.sched.slots[st.slot] is not st or st.budget <= 0:
-                continue
-            self.sched.grow_to(st, st.kv_len + 1)
-        active = [st for st in self.sched.decode_ready() if st.budget > 0
-                  and len(st.pages) * self.page_size > st.kv_len]
+    def _decode_tick(self) -> int:
+        """One batched decode step over every decode-ready sequence.
 
-        n_decoded = 0
-        if active:
-            self.telemetry.span_begin("decode_tick",
-                                      args={"n_active": len(active)})
+        The ``decode_tick`` span runs from the block tables to the tokens'
+        readback; its args count the active slots, the pages that hold
+        their KV once this step has written (``pages_live``), and the
+        pages the decode kernel's grid visits (``pages_grid``: every page
+        of every slot)."""
+        tel = self.telemetry
+        with tel.span("engine/decode_prepare"):
+            # grow pages for every decode-ready row (may preempt the
+            # youngest)
+            for st in list(self.sched.decode_ready()):
+                if self.sched.slots[st.slot] is not st or st.budget <= 0:
+                    continue
+                self.sched.grow_to(st, st.kv_len + 1)
+            active = [st for st in self.sched.decode_ready()
+                      if st.budget > 0
+                      and len(st.pages) * self.page_size > st.kv_len]
+            if not active:
+                return 0
             n_slots = self.scfg.n_slots
+            tel.span_begin("decode_tick", args={
+                "n_active": len(active),
+                "pages_live": sum(self.pool.pages_for(st.kv_len + 1)
+                                  for st in active),
+                "pages_grid": n_slots * self.pages_per_seq})
             tables = np.full((n_slots, self.pages_per_seq), NULL_PAGE,
                              np.int32)
             kv_len = np.zeros((n_slots,), np.int32)
@@ -747,24 +807,21 @@ class PagedServingEngine(_SamplerMixin):
                 kv_len[st.slot] = st.kv_len
                 cur_pos[st.slot] = st.cur_pos
                 tokens[st.slot, 0] = st.req.output[-1]
+            step_args = (jnp.asarray(tables), jnp.asarray(kv_len),
+                         jnp.asarray(cur_pos), jnp.asarray(tokens))
+        with tel.span("engine/decode_dispatch"):
             logits, self.cache, self.pos_pages = self._decode(
-                self.params, self.cache, self.pos_pages,
-                jnp.asarray(tables), jnp.asarray(kv_len),
-                jnp.asarray(cur_pos), jnp.asarray(tokens))
+                self.params, self.cache, self.pos_pages, *step_args)
+        with tel.span("engine/decode_readback"):
             nxt = self._pick(logits[:, 0])
             for st in active:
                 st.req.output.append(int(nxt[st.slot]))
                 st.kv_len += 1
                 st.cur_pos += 1
                 st.budget -= 1
-            n_decoded = len(active)
-            self.telemetry.span_end("decode_tick")
-            self.telemetry.tokens_decoded([st.req.rid for st in active])
-
-        self._retire_finished()
-        # sample after retirement so a drained pool reads 0 in the gauge
-        self.telemetry.sparsity.observe_pool(self.pool)
-        return n_decoded
+        tel.span_end("decode_tick")
+        tel.tokens_decoded([st.req.rid for st in active])
+        return len(active)
 
     def _retire_finished(self) -> None:
         # requests the scheduler aborted (optimistic admission that never

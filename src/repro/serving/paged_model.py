@@ -20,6 +20,15 @@ a shared block table, and original-position ids instead of a dense
 
 All functions are functional: caches/pos_pages go in, updated ones come
 out; the engine owns jit boundaries and the host-side pool bookkeeping.
+
+Every op of the steps runs under one of the :data:`STAGES` names
+(``jax.named_scope``), which the compiled program keeps as op metadata and
+a device trace shows beside each op; an op's stage is the innermost stage
+name in its name stack.  ``kv_pool`` covers every op that moves page-pool
+data: the per-layer pool slices the layer scan feeds each layer and the
+write-back of each layer's pages (so the scan itself runs under
+``kv_pool``, its body's compute under the compute stages), the token or
+chunk K/V writes, the ``pos_pages`` update and the page gathers.
 """
 
 from __future__ import annotations
@@ -39,7 +48,13 @@ from repro.models.moe import ffn_forward
 from .pager import POS_SENTINEL, PagedKVCache
 
 __all__ = ["paged_decode_step", "paged_prefill_chunk",
-           "paged_prefill_chunk_spls", "scatter_prefill", "compact_slots"]
+           "paged_prefill_chunk_spls", "scatter_prefill", "compact_slots",
+           "STAGES"]
+
+# the stages of a model step, as its ops' named scopes; ``plan`` is the
+# SPLS prediction and plan, in the SPLS chunk step only
+STAGES = ("weights_cast", "embed", "qkv", "attention", "kv_pool", "ffn",
+          "lm_head", "plan")
 
 
 def _cast_params(pparams, dtype):
@@ -52,11 +67,13 @@ def _write_token(kc: PagedKVCache, k_new: jax.Array, v_new: jax.Array,
                  flat: jax.Array) -> PagedKVCache:
     """Scatter one token's K/V (B, KV, 1, Dh) into flat page slots (B,)."""
     KV, N, ps, Dh = kc.k_pages.shape
-    kf = kc.k_pages.reshape(KV, N * ps, Dh)
-    vf = kc.v_pages.reshape(KV, N * ps, Dh)
-    kf = kf.at[:, flat].set(jnp.moveaxis(k_new[:, :, 0], 0, 1))
-    vf = vf.at[:, flat].set(jnp.moveaxis(v_new[:, :, 0], 0, 1))
-    return PagedKVCache(kf.reshape(KV, N, ps, Dh), vf.reshape(KV, N, ps, Dh))
+    with jax.named_scope("kv_pool"):
+        kf = kc.k_pages.reshape(KV, N * ps, Dh)
+        vf = kc.v_pages.reshape(KV, N * ps, Dh)
+        kf = kf.at[:, flat].set(jnp.moveaxis(k_new[:, :, 0], 0, 1))
+        vf = vf.at[:, flat].set(jnp.moveaxis(v_new[:, :, 0], 0, 1))
+        return PagedKVCache(kf.reshape(KV, N, ps, Dh),
+                            vf.reshape(KV, N, ps, Dh))
 
 
 def _decode_flat_slots(tables: jax.Array, kv_len: jax.Array,
@@ -81,12 +98,13 @@ def _chunk_slots(table: jax.Array, pos_pages: jax.Array, start: jax.Array,
     new_pos_pages)``.
     """
     N, ps = pos_pages.shape
-    idx = jnp.arange(CS, dtype=jnp.int32)
-    sl = start + idx
-    page = table[sl // ps]
-    flat = jnp.where(idx < valid, page * ps + sl % ps, 0)
-    pos_pages = pos_pages.reshape(N * ps).at[flat].set(
-        jnp.where(idx < valid, sl, POS_SENTINEL)).reshape(N, ps)
+    with jax.named_scope("kv_pool"):
+        idx = jnp.arange(CS, dtype=jnp.int32)
+        sl = start + idx
+        page = table[sl // ps]
+        flat = jnp.where(idx < valid, page * ps + sl % ps, 0)
+        pos_pages = pos_pages.reshape(N * ps).at[flat].set(
+            jnp.where(idx < valid, sl, POS_SENTINEL)).reshape(N, ps)
     return sl, flat, pos_pages
 
 
@@ -94,9 +112,11 @@ def _write_chunk_kv(kc: PagedKVCache, k_new: jax.Array, v_new: jax.Array,
                     flat: jax.Array) -> PagedKVCache:
     """Scatter a chunk's K/V rows (1, KV, CS, Dh) into flat page slots."""
     KV, N, ps, Dh = kc.k_pages.shape
-    kf = kc.k_pages.reshape(KV, N * ps, Dh).at[:, flat].set(k_new[0])
-    vf = kc.v_pages.reshape(KV, N * ps, Dh).at[:, flat].set(v_new[0])
-    return PagedKVCache(kf.reshape(KV, N, ps, Dh), vf.reshape(KV, N, ps, Dh))
+    with jax.named_scope("kv_pool"):
+        kf = kc.k_pages.reshape(KV, N * ps, Dh).at[:, flat].set(k_new[0])
+        vf = kc.v_pages.reshape(KV, N * ps, Dh).at[:, flat].set(v_new[0])
+        return PagedKVCache(kf.reshape(KV, N, ps, Dh),
+                            vf.reshape(KV, N, ps, Dh))
 
 
 def _residual_ffn(cfg: ArchConfig, blk, bp, x: jax.Array, h: jax.Array,
@@ -144,35 +164,45 @@ def paged_decode_step(cfg: ArchConfig, params, cache, pos_pages: jax.Array,
     """
     ps = pos_pages.shape[1]
     N = pos_pages.shape[0]
-    flat = _decode_flat_slots(tables, kv_len, ps)
-    pos_pages = pos_pages.reshape(N * ps).at[flat].set(cur_pos) \
-        .reshape(N, ps)
+    with jax.named_scope("kv_pool"):
+        flat = _decode_flat_slots(tables, kv_len, ps)
+        pos_pages = pos_pages.reshape(N * ps).at[flat].set(cur_pos) \
+            .reshape(N, ps)
     n_valid = kv_len + 1
     name = resolve_backend(backend or cfg.attn_backend, cfg, L=N * ps,
                            decode=True, paged=True)
     fn = get_backend(name)
     dtype = dtype_of(cfg.compute_dtype)
-    x = embed_inputs(cfg, params, tokens)
+    with jax.named_scope("embed"):
+        x = embed_inputs(cfg, params, tokens)
 
     def scan_body(x, inp):
         pparams, pcache = inp
-        pparams = _cast_params(pparams, dtype)
+        with jax.named_scope("weights_cast"):
+            pparams = _cast_params(pparams, dtype)
         new_caches = []
         for blk, bp, kc in zip(cfg.period, pparams, pcache):
-            xn = rms_norm(x, bp["ln1"], cfg.norm_eps)
-            q, k_new, v_new = project_qkv(cfg, bp["attn"], xn,
-                                          cur_pos[:, None], "structured")
+            with jax.named_scope("qkv"):
+                xn = rms_norm(x, bp["ln1"], cfg.norm_eps)
+                q, k_new, v_new = project_qkv(cfg, bp["attn"], xn,
+                                              cur_pos[:, None], "structured")
             kc = _write_token(kc, k_new, v_new, flat)
-            o = fn(cfg, q[:, :, :, 0], kc.k_pages, kc.v_pages,
-                   pos_pages=pos_pages, tables=tables, kv_len=n_valid,
-                   pos=cur_pos, window=blk.window)
-            h = output_proj(cfg, bp["attn"], o[:, :, :, None], "structured")
-            x = _residual_ffn(cfg, blk, bp, x, h)
+            with jax.named_scope("attention"):
+                o = fn(cfg, q[:, :, :, 0], kc.k_pages, kc.v_pages,
+                       pos_pages=pos_pages, tables=tables, kv_len=n_valid,
+                       pos=cur_pos, window=blk.window)
+                h = output_proj(cfg, bp["attn"], o[:, :, :, None],
+                                "structured")
+            with jax.named_scope("ffn"):
+                x = _residual_ffn(cfg, blk, bp, x, h)
             new_caches.append(kc)
         return x, tuple(new_caches)
 
-    x, new_cache = jax.lax.scan(scan_body, x, (params["periods"], cache))
-    return head_logits(cfg, params, x), new_cache, pos_pages
+    with jax.named_scope("kv_pool"):
+        x, new_cache = jax.lax.scan(scan_body, x, (params["periods"], cache))
+    with jax.named_scope("lm_head"):
+        logits = head_logits(cfg, params, x)
+    return logits, new_cache, pos_pages
 
 
 # ---------------------------------------------------------------------------
@@ -206,15 +236,18 @@ def paged_prefill_chunk(cfg: ArchConfig, params, cache,
     sl, flat, pos_pages = _chunk_slots(table, pos_pages, start, valid, CS)
     positions = sl[None, :]                            # original ids
     n_valid = start + valid
-    pg = pos_pages[table].reshape(S)                   # slot -> original id
+    with jax.named_scope("kv_pool"):
+        pg = pos_pages[table].reshape(S)               # slot -> original id
     slot_idx = jnp.arange(S)
 
-    x = embed_inputs(cfg, params, tokens)
+    with jax.named_scope("embed"):
+        x = embed_inputs(cfg, params, tokens)
 
     def attend(blk, q, kc):
         KV = kc.k_pages.shape[0]
-        kg = kc.k_pages[:, table][None].reshape(1, KV, S, -1)
-        vg = kc.v_pages[:, table][None].reshape(1, KV, S, -1)
+        with jax.named_scope("kv_pool"):
+            kg = kc.k_pages[:, table][None].reshape(1, KV, S, -1)
+            vg = kc.v_pages[:, table][None].reshape(1, KV, S, -1)
         Dh = q.shape[-1]
         s = jnp.einsum("bkgqd,bkld->bkgql", q, kg) * (Dh ** -0.5)
         s = _softcap(s, cfg.attn_softcap)
@@ -228,22 +261,29 @@ def paged_prefill_chunk(cfg: ArchConfig, params, cache,
 
     def scan_body(x, inp):
         pparams, pcache = inp
-        pparams = _cast_params(pparams, dtype)
+        with jax.named_scope("weights_cast"):
+            pparams = _cast_params(pparams, dtype)
         new_caches = []
         for blk, bp, kc in zip(cfg.period, pparams, pcache):
-            xn = rms_norm(x, bp["ln1"], cfg.norm_eps)
-            q, k_new, v_new = project_qkv(cfg, bp["attn"], xn, positions,
-                                          "structured")
+            with jax.named_scope("qkv"):
+                xn = rms_norm(x, bp["ln1"], cfg.norm_eps)
+                q, k_new, v_new = project_qkv(cfg, bp["attn"], xn,
+                                              positions, "structured")
             kc = _write_chunk_kv(kc, k_new, v_new, flat)
-            o = attend(blk, q, kc)
-            h = output_proj(cfg, bp["attn"], o, "structured")
-            x = _residual_ffn(cfg, blk, bp, x, h)
+            with jax.named_scope("attention"):
+                o = attend(blk, q, kc)
+                h = output_proj(cfg, bp["attn"], o, "structured")
+            with jax.named_scope("ffn"):
+                x = _residual_ffn(cfg, blk, bp, x, h)
             new_caches.append(kc)
         return x, tuple(new_caches)
 
-    x, new_cache = jax.lax.scan(scan_body, x, (params["periods"], cache))
-    x_last = jax.lax.dynamic_slice_in_dim(x, valid - 1, 1, axis=1)
-    return head_logits(cfg, params, x_last), new_cache, pos_pages
+    with jax.named_scope("kv_pool"):
+        x, new_cache = jax.lax.scan(scan_body, x, (params["periods"], cache))
+    with jax.named_scope("lm_head"):
+        x_last = jax.lax.dynamic_slice_in_dim(x, valid - 1, 1, axis=1)
+        logits = head_logits(cfg, params, x_last)
+    return logits, new_cache, pos_pages
 
 
 # ---------------------------------------------------------------------------
@@ -355,7 +395,8 @@ def paged_prefill_chunk_spls(cfg: ArchConfig, params, cache, pred_cache,
     n_valid = start + valid
     slot_idx = jnp.arange(S)
 
-    x = embed_inputs(cfg, params, tokens)
+    with jax.named_scope("embed"):
+        x = embed_inputs(cfg, params, tokens)
 
     def scan_body(carry, inp):
         if Ckv is not None:
@@ -364,147 +405,159 @@ def paged_prefill_chunk_spls(cfg: ArchConfig, params, cache, pred_cache,
             x = carry
             kv_written_c = live_all_c = n_kv_c = None
         pparams, pcache, ppred, p_idx = inp
-        pparams = _cast_params(pparams, dtype)
+        with jax.named_scope("weights_cast"):
+            pparams = _cast_params(pparams, dtype)
         new_caches, new_preds = [], []
         kv_any0 = None
         counts = jnp.zeros((3,), jnp.int32)
         ridx = jnp.arange(CS, dtype=jnp.int32)
         for bi, (blk, bp, kc, pk) in enumerate(
                 zip(cfg.period, pparams, pcache, ppred)):
-            xn = rms_norm(x, bp["ln1"], cfg.norm_eps)
+            with jax.named_scope("qkv"):
+                xn = rms_norm(x, bp["ln1"], cfg.norm_eps)
             # -- prediction: extend the predictor code pages, emit the
             # plan block (all plan math lives in core.planner)
-            qh, k_codes, k_scale = ctx.encode_pred_qk(bp["attn"], xn)
-            codes_pg = pk.codes.reshape(KV, N * ps, Dh).at[:, flat] \
-                .set(k_codes).reshape(KV, N, ps, Dh)
-            scale_pg = pk.scale.reshape(N * ps).at[flat].set(k_scale) \
-                .reshape(N, ps)
-            pk = PredKCache(codes=codes_pg, scale=scale_pg)
-            kh_all = ctx.decode_pred_k(codes_pg[:, table].reshape(KV, S, Dh),
-                                       scale_pg[table].reshape(S),
-                                       dtype=dtype)[None]
-            # the prediction/vote pipeline is deliberately horizon-
-            # independent (no col_live): finalized columns are denied
-            # materialization and attention below, but still occupy their
-            # top-k candidacy -- this keeps the vote trajectory identical
-            # to the end-of-prefill path's, which is what makes the kept
-            # set monotone in the horizon
-            pb = ctx.plan_block(qh, kh_all, k=topk_k, row0=start,
-                                n_valid_rows=valid, n_cols=n_valid)
-            if kv_any0 is None:
-                kv_any0 = pb.kv_any
-            lead_local = pb.q_leader - start
-            # capacity-controller observations: union of per-head critical
-            # rows (the Q pack) and valid FFN-critical rows (padded rows
-            # report FFN-critical but never count)
-            crit_any = jnp.any(pb.q_critical, axis=(1, 2))     # (1, CS)
-            n_ffn = (pb.ffn_critical[0] & (ridx < valid)).sum()
-            if Ckv is not None and bi == 0:
-                # layer 0 decides which of this chunk's own columns get a
-                # K/V projection at all (vote_horizon == 1: the chunk's
-                # own plan votes are final); later layers and periods
-                # reuse the carried decision -- lax.cond runs the
-                # decision exactly once per chunk
-                def _decide(_):
-                    ok = own_column_keep(
-                        pb.kv_any, start=start, chunk=CS, valid=valid,
-                        last_keep=last_keep, vote_need=kv_vote_need)
-                    anchor = start + ridx == last_keep
-                    w = pack_within_capacity(ok, Ckv, anchor=anchor)
-                    live_new = jax.lax.dynamic_update_slice(
-                        jnp.pad(live, (0, CS)), w, (start,))[:S]
-                    return w, live_new, ok.sum().astype(jnp.int32)
+            with jax.named_scope("plan"):
+                qh, k_codes, k_scale = ctx.encode_pred_qk(bp["attn"], xn)
+            with jax.named_scope("kv_pool"):
+                codes_pg = pk.codes.reshape(KV, N * ps, Dh).at[:, flat] \
+                    .set(k_codes).reshape(KV, N, ps, Dh)
+                scale_pg = pk.scale.reshape(N * ps).at[flat].set(k_scale) \
+                    .reshape(N, ps)
+                pk = PredKCache(codes=codes_pg, scale=scale_pg)
+                codes_seq = codes_pg[:, table].reshape(KV, S, Dh)
+                scale_seq = scale_pg[table].reshape(S)
+            with jax.named_scope("plan"):
+                kh_all = ctx.decode_pred_k(codes_seq, scale_seq,
+                                           dtype=dtype)[None]
+                # the prediction/vote pipeline is deliberately horizon-
+                # independent (no col_live): finalized columns are denied
+                # materialization and attention below, but still occupy their
+                # top-k candidacy -- this keeps the vote trajectory identical
+                # to the end-of-prefill path's, which is what makes the kept
+                # set monotone in the horizon
+                pb = ctx.plan_block(qh, kh_all, k=topk_k, row0=start,
+                                    n_valid_rows=valid, n_cols=n_valid)
+                if kv_any0 is None:
+                    kv_any0 = pb.kv_any
+                lead_local = pb.q_leader - start
+                # capacity-controller observations: union of per-head critical
+                # rows (the Q pack) and valid FFN-critical rows (padded rows
+                # report FFN-critical but never count)
+                crit_any = jnp.any(pb.q_critical, axis=(1, 2))     # (1, CS)
+                n_ffn = (pb.ffn_critical[0] & (ridx < valid)).sum()
+                if Ckv is not None and bi == 0:
+                    # layer 0 decides which of this chunk's own columns get a
+                    # K/V projection at all (vote_horizon == 1: the chunk's
+                    # own plan votes are final); later layers and periods
+                    # reuse the carried decision -- lax.cond runs the
+                    # decision exactly once per chunk
+                    def _decide(_):
+                        ok = own_column_keep(
+                            pb.kv_any, start=start, chunk=CS, valid=valid,
+                            last_keep=last_keep, vote_need=kv_vote_need)
+                        anchor = start + ridx == last_keep
+                        w = pack_within_capacity(ok, Ckv, anchor=anchor)
+                        live_new = jax.lax.dynamic_update_slice(
+                            jnp.pad(live, (0, CS)), w, (start,))[:S]
+                        return w, live_new, ok.sum().astype(jnp.int32)
 
-                kv_written_c, live_all_c, n_kv_c = jax.lax.cond(
-                    p_idx == 0, _decide,
-                    lambda _: (kv_written_c, live_all_c, n_kv_c), None)
-            if Ckv is not None:
-                counts = jnp.maximum(counts, jnp.stack(
-                    [crit_any.sum(), n_ffn, n_kv_c]).astype(jnp.int32))
-            else:
-                counts = jnp.maximum(counts, jnp.stack(
-                    [crit_any.sum(), n_ffn,
-                     jnp.zeros((), jnp.int32)]).astype(jnp.int32))
-            # -- formal K/V at original positions.  Dense for every chunk
-            # row by default (columns must materialize until the prune
-            # vote finalizes); under vote_horizon == 1 the project_kv
-            # seam runs packed over only the vote-surviving columns.
-            if packed:
+                    kv_written_c, live_all_c, n_kv_c = jax.lax.cond(
+                        p_idx == 0, _decide,
+                        lambda _: (kv_written_c, live_all_c, n_kv_c), None)
                 if Ckv is not None:
-                    # pack order over the anchor-reserved written set: at
-                    # most Ckv True rows, so every written column lands
-                    # in the perm (filler slots scatter to the null page)
-                    kv_perm, _ = pack_by_mask(kv_written_c, Ckv)
-                    k_new, v_new = project_kv(
-                        cfg, bp["attn"], xn, positions, "structured",
-                        perm=kv_perm, compute_backend=compute_backend)
-                    flat_kv = jnp.where(jnp.take(kv_written_c, kv_perm),
-                                        jnp.take(flat, kv_perm), 0)
-                    kc = _write_chunk_kv(kc, k_new, v_new, flat_kv)
+                    counts = jnp.maximum(counts, jnp.stack(
+                        [crit_any.sum(), n_ffn, n_kv_c]).astype(jnp.int32))
                 else:
-                    k_new, v_new = project_kv(cfg, bp["attn"], xn,
-                                              positions, "structured")
+                    counts = jnp.maximum(counts, jnp.stack(
+                        [crit_any.sum(), n_ffn,
+                         jnp.zeros((), jnp.int32)]).astype(jnp.int32))
+            with jax.named_scope("qkv"):
+                # -- formal K/V at original positions.  Dense for every chunk
+                # row by default (columns must materialize until the prune
+                # vote finalizes); under vote_horizon == 1 the project_kv
+                # seam runs packed over only the vote-surviving columns.
+                if packed:
+                    if Ckv is not None:
+                        # pack order over the anchor-reserved written set: at
+                        # most Ckv True rows, so every written column lands
+                        # in the perm (filler slots scatter to the null page)
+                        kv_perm, _ = pack_by_mask(kv_written_c, Ckv)
+                        k_new, v_new = project_kv(
+                            cfg, bp["attn"], xn, positions, "structured",
+                            perm=kv_perm, compute_backend=compute_backend)
+                        flat_kv = jnp.where(jnp.take(kv_written_c, kv_perm),
+                                            jnp.take(flat, kv_perm), 0)
+                        kc = _write_chunk_kv(kc, k_new, v_new, flat_kv)
+                    else:
+                        k_new, v_new = project_kv(cfg, bp["attn"], xn,
+                                                  positions, "structured")
+                        kc = _write_chunk_kv(kc, k_new, v_new, flat)
+                else:
+                    q, k_new, v_new = project_qkv(cfg, bp["attn"], xn,
+                                                  positions, "structured")
                     kc = _write_chunk_kv(kc, k_new, v_new, flat)
-            else:
-                q, k_new, v_new = project_qkv(cfg, bp["attn"], xn,
-                                              positions, "structured")
-                kc = _write_chunk_kv(kc, k_new, v_new, flat)
-            kg = kc.k_pages[:, table][None].reshape(1, KV, S, Dh)
-            vg = kc.v_pages[:, table][None].reshape(1, KV, S, Dh)
-            mask = pb.mask
-            if blk.window is not None:
-                mask = mask & (positions[0][:, None] - slot_idx[None, :]
-                               < blk.window)
-            if Ckv is not None:
-                # columns finalized dead (earlier chunks) or dropped by
-                # the kv pack (this chunk's own) were never projected /
-                # are pruned: deny them to every layer's attention
-                mask = mask & live_all_c
-            elif live is not None:
-                # finite horizon without K/V packing: earlier-finalized
-                # columns are pruned; this chunk's own columns always
-                # materialize
-                mask = mask & live
-            # row selection: the two modes differ only in *which* q/mask
-            # rows the shared score/softmax/AV block sees.
-            if packed:
-                # packed SPLS attention: compute only the union rows'
-                # scores (every head's leaders are in the union), then
-                # every row reads its leader's packed slot.  Bit-for-bit
-                # the simulation-mode path at Cq == CS; overflow rows
-                # fall back to their window leader.
-                qcomp = compact_rows(crit_any, Cq, leader=lead_local,
-                                     window=scfg.window)
-                q_sel = packed_project_q(cfg, bp["attn"], xn, sl,
-                                         qcomp.perm[0], compute_backend)
-                perm_idx = qcomp.perm[:, None, None, :, None]
-                mask_sel = jnp.take_along_axis(mask, perm_idx, axis=-2)
-            else:
-                # simulation-mode SPLS attention over all written slots:
-                # similar rows use their leader's Q row and mask row
-                # (leaders are window-local, hence chunk-local)
-                q_sel = gather_rows(q, lead_local)
-                mask_sel = jnp.take_along_axis(mask, lead_local[..., None],
-                                               axis=-2)
-            s = jnp.einsum("bkgqd,bkld->bkgql", q_sel, kg) * (Dh ** -0.5)
-            if cfg.attn_softcap is not None:
-                s = jnp.tanh(s / cfg.attn_softcap) * cfg.attn_softcap
-            a = _masked_softmax(s, mask_sel)
-            o = jnp.einsum("bkgql,bkld->bkgqd", a, vg)
-            if packed:
-                o = jnp.take_along_axis(o, qcomp.src_slot[..., None],
-                                        axis=-2)
-            h = output_proj(cfg, bp["attn"], o, "structured")
-            ffn_comp = None
-            if packed and scfg.ffn_sparsity and not blk.use_moe:
-                ffn_comp = compact_rows(pb.ffn_critical, Cf,
-                                        leader=pb.ffn_leader - start,
-                                        window=scfg.window)
-            x = _residual_ffn(cfg, blk, bp, x, h,
-                              ffn_leader=(pb.ffn_leader - start
-                                          if scfg.ffn_sparsity else None),
-                              ffn_comp=ffn_comp,
-                              compute_backend=compute_backend)
+            with jax.named_scope("kv_pool"):
+                kg = kc.k_pages[:, table][None].reshape(1, KV, S, Dh)
+                vg = kc.v_pages[:, table][None].reshape(1, KV, S, Dh)
+            with jax.named_scope("attention"):
+                mask = pb.mask
+                if blk.window is not None:
+                    mask = mask & (positions[0][:, None] - slot_idx[None, :]
+                                   < blk.window)
+                if Ckv is not None:
+                    # columns finalized dead (earlier chunks) or dropped by
+                    # the kv pack (this chunk's own) were never projected /
+                    # are pruned: deny them to every layer's attention
+                    mask = mask & live_all_c
+                elif live is not None:
+                    # finite horizon without K/V packing: earlier-finalized
+                    # columns are pruned; this chunk's own columns always
+                    # materialize
+                    mask = mask & live
+                # row selection: the two modes differ only in *which* q/mask
+                # rows the shared score/softmax/AV block sees.
+                if packed:
+                    # packed SPLS attention: compute only the union rows'
+                    # scores (every head's leaders are in the union), then
+                    # every row reads its leader's packed slot.  Bit-for-bit
+                    # the simulation-mode path at Cq == CS; overflow rows
+                    # fall back to their window leader.
+                    qcomp = compact_rows(crit_any, Cq, leader=lead_local,
+                                         window=scfg.window)
+                    with jax.named_scope("qkv"):
+                        q_sel = packed_project_q(cfg, bp["attn"], xn, sl,
+                                                 qcomp.perm[0],
+                                                 compute_backend)
+                    perm_idx = qcomp.perm[:, None, None, :, None]
+                    mask_sel = jnp.take_along_axis(mask, perm_idx, axis=-2)
+                else:
+                    # simulation-mode SPLS attention over all written slots:
+                    # similar rows use their leader's Q row and mask row
+                    # (leaders are window-local, hence chunk-local)
+                    q_sel = gather_rows(q, lead_local)
+                    mask_sel = jnp.take_along_axis(mask, lead_local[..., None],
+                                                   axis=-2)
+                s = jnp.einsum("bkgqd,bkld->bkgql", q_sel, kg) * (Dh ** -0.5)
+                if cfg.attn_softcap is not None:
+                    s = jnp.tanh(s / cfg.attn_softcap) * cfg.attn_softcap
+                a = _masked_softmax(s, mask_sel)
+                o = jnp.einsum("bkgql,bkld->bkgqd", a, vg)
+                if packed:
+                    o = jnp.take_along_axis(o, qcomp.src_slot[..., None],
+                                            axis=-2)
+                h = output_proj(cfg, bp["attn"], o, "structured")
+            with jax.named_scope("ffn"):
+                ffn_comp = None
+                if packed and scfg.ffn_sparsity and not blk.use_moe:
+                    ffn_comp = compact_rows(pb.ffn_critical, Cf,
+                                            leader=pb.ffn_leader - start,
+                                            window=scfg.window)
+                x = _residual_ffn(cfg, blk, bp, x, h,
+                                  ffn_leader=(pb.ffn_leader - start
+                                              if scfg.ffn_sparsity else None),
+                                  ffn_comp=ffn_comp,
+                                  compute_backend=compute_backend)
             new_caches.append(kc)
             new_preds.append(pk)
         carry_out = ((x, kv_written_c, live_all_c, n_kv_c)
@@ -516,14 +569,17 @@ def paged_prefill_chunk_spls(cfg: ArchConfig, params, cache, pred_cache,
         carry0 = (x, jnp.zeros((CS,), bool), live, jnp.zeros((), jnp.int32))
     else:
         carry0 = x
-    carry, (new_cache, new_pred, kv_any, counts) = jax.lax.scan(
-        scan_body, carry0,
-        (params["periods"], cache, pred_cache,
-         jnp.arange(cfg.n_periods, dtype=jnp.int32)))
+    with jax.named_scope("kv_pool"):
+        carry, (new_cache, new_pred, kv_any, counts) = jax.lax.scan(
+            scan_body, carry0,
+            (params["periods"], cache, pred_cache,
+             jnp.arange(cfg.n_periods, dtype=jnp.int32)))
     x = carry[0] if Ckv is not None else carry
-    x_last = jax.lax.dynamic_slice_in_dim(x, valid - 1, 1, axis=1)
-    return (head_logits(cfg, params, x_last), new_cache, new_pred,
-            pos_pages, jax.tree.map(lambda a: a[0], kv_any), counts)
+    with jax.named_scope("lm_head"):
+        x_last = jax.lax.dynamic_slice_in_dim(x, valid - 1, 1, axis=1)
+        logits = head_logits(cfg, params, x_last)
+    return (logits, new_cache, new_pred, pos_pages,
+            jax.tree.map(lambda a: a[0], kv_any), counts)
 
 
 def compact_slots(cache, pos_pages: jax.Array, table: jax.Array,
@@ -540,26 +596,28 @@ def compact_slots(cache, pos_pages: jax.Array, table: jax.Array,
     """
     N, ps = pos_pages.shape
     S = table.shape[0] * ps
-    sl = jnp.arange(S)
-    flat = table[sl // ps] * ps + sl % ps
-    perm = jnp.argsort(~keep, stable=True)
-    n_kept = keep.sum()
-    src = flat[perm]
-    pos_flat = pos_pages.reshape(N * ps)
-    # unallocated table tails alias null-page slots: every such collision
-    # writes POS_SENTINEL (j >= n_kept), so the scatter stays deterministic
-    vals = jnp.where(sl < n_kept, pos_flat[src], POS_SENTINEL)
-    pos_pages = pos_flat.at[flat].set(vals).reshape(N, ps)
+    with jax.named_scope("kv_pool"):
+        sl = jnp.arange(S)
+        flat = table[sl // ps] * ps + sl % ps
+        perm = jnp.argsort(~keep, stable=True)
+        n_kept = keep.sum()
+        src = flat[perm]
+        pos_flat = pos_pages.reshape(N * ps)
+        # unallocated table tails alias null-page slots: every such
+        # collision writes POS_SENTINEL (j >= n_kept), so the scatter
+        # stays deterministic
+        vals = jnp.where(sl < n_kept, pos_flat[src], POS_SENTINEL)
+        pos_pages = pos_flat.at[flat].set(vals).reshape(N, ps)
 
-    new_blocks = []
-    for pc in cache:
-        nP, KV, N_, ps_, Dh = pc.k_pages.shape
-        kf = pc.k_pages.reshape(nP, KV, N_ * ps_, Dh)
-        vf = pc.v_pages.reshape(nP, KV, N_ * ps_, Dh)
-        kf = kf.at[:, :, flat].set(kf[:, :, src])
-        vf = vf.at[:, :, flat].set(vf[:, :, src])
-        new_blocks.append(PagedKVCache(kf.reshape(nP, KV, N_, ps_, Dh),
-                                       vf.reshape(nP, KV, N_, ps_, Dh)))
+        new_blocks = []
+        for pc in cache:
+            nP, KV, N_, ps_, Dh = pc.k_pages.shape
+            kf = pc.k_pages.reshape(nP, KV, N_ * ps_, Dh)
+            vf = pc.v_pages.reshape(nP, KV, N_ * ps_, Dh)
+            kf = kf.at[:, :, flat].set(kf[:, :, src])
+            vf = vf.at[:, :, flat].set(vf[:, :, src])
+            new_blocks.append(PagedKVCache(kf.reshape(nP, KV, N_, ps_, Dh),
+                                           vf.reshape(nP, KV, N_, ps_, Dh)))
     return tuple(new_blocks), pos_pages
 
 
@@ -580,17 +638,18 @@ def scatter_prefill(cache, pos_pages: jax.Array, dense_cache,
     kept columns land compacted; ``pos_pages`` records their original ids.
     """
     N, ps = pos_pages.shape
-    pos_pages = pos_pages.reshape(N * ps).at[flat] \
-        .set(keep_idx.astype(jnp.int32)).reshape(N, ps)
+    with jax.named_scope("kv_pool"):
+        pos_pages = pos_pages.reshape(N * ps).at[flat] \
+            .set(keep_idx.astype(jnp.int32)).reshape(N, ps)
 
-    new_blocks = []
-    for pc, dc in zip(cache, dense_cache):
-        nP, KV, N_, ps_, Dh = pc.k_pages.shape
-        rows_k = dc.k[:, 0][:, :, keep_idx]            # (nP, KV, n_kept, Dh)
-        rows_v = dc.v[:, 0][:, :, keep_idx]
-        kf = pc.k_pages.reshape(nP, KV, N_ * ps_, Dh).at[:, :, flat] \
-            .set(rows_k).reshape(nP, KV, N_, ps_, Dh)
-        vf = pc.v_pages.reshape(nP, KV, N_ * ps_, Dh).at[:, :, flat] \
-            .set(rows_v).reshape(nP, KV, N_, ps_, Dh)
-        new_blocks.append(PagedKVCache(kf, vf))
+        new_blocks = []
+        for pc, dc in zip(cache, dense_cache):
+            nP, KV, N_, ps_, Dh = pc.k_pages.shape
+            rows_k = dc.k[:, 0][:, :, keep_idx]        # (nP, KV, n_kept, Dh)
+            rows_v = dc.v[:, 0][:, :, keep_idx]
+            kf = pc.k_pages.reshape(nP, KV, N_ * ps_, Dh).at[:, :, flat] \
+                .set(rows_k).reshape(nP, KV, N_, ps_, Dh)
+            vf = pc.v_pages.reshape(nP, KV, N_ * ps_, Dh).at[:, :, flat] \
+                .set(rows_v).reshape(nP, KV, N_, ps_, Dh)
+            new_blocks.append(PagedKVCache(kf, vf))
     return tuple(new_blocks), pos_pages
