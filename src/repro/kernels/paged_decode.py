@@ -20,12 +20,22 @@ Layout (see ``src/repro/serving/README.md`` for the lifecycle):
   pos:               (B,) int32                    -- original position of
       the current query token (inclusive upper bound of the window).
 
-Grid: (B*KV, P).  The block table, lengths, and positions ride in as
-scalar-prefetch operands, so each grid step's BlockSpec index map resolves
-the *physical* page to bring into VMEM -- the gather happens in the DMA
-schedule and no contiguous cache is ever materialized.  The online-softmax
-recurrence is the same as ``flash_decode``; pages past ``kv_len`` (and, with
-a window, pages whose slots all fell out of the window) are skipped whole.
+Grid: (B,), one step per sequence with all of its KV heads.  The pools
+stay in HBM (``memory_space=pl.ANY``); the block table, lengths and
+positions ride in as scalar-prefetch operands.  Inside a step a loop walks
+the sequence in blocks of ``pages_per_block(P)`` pages and runs
+``ceil(kv_len / (pages_per_block * page_size))`` times, so the work
+follows the live length, not ``P``.  Each block's pages are copied with
+``pltpu.make_async_copy`` -- one copy per page, all KV heads at once, the
+physical page read from the block table -- into one half of a
+double-buffered VMEM block, and the next block's copies start before the
+current block is computed.  Pages past ``kv_len`` are never copied; the
+partial last block is masked by slot index, and its rows that no copy
+filled are zeroed before P.V.  The online-softmax recurrence is the same
+as ``flash_decode`` and runs in float32.  With a window, the rows'
+original ids (``pos_pages`` gathered through the tables) mask every slot,
+and a block whose written slots all fell out of the window is skipped
+whole.
 """
 
 from __future__ import annotations
@@ -35,66 +45,122 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .interpret import resolve_interpret
 
-__all__ = ["paged_flash_decode", "NULL_PAGE"]
+__all__ = ["paged_flash_decode", "pages_per_block", "pages_visited",
+           "NULL_PAGE"]
 
 NULL_PAGE = 0
+PAGES_PER_BLOCK = 8
 _NEG = -1e30
 
 
-def _kernel(bt_ref, kl_ref, cp_ref, q_ref, k_ref, v_ref, pp_ref, o_ref,
-            m_scr, l_scr, acc_scr, *, scale, softcap, window, ps, kv, np_):
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-    b = i // kv
+def pages_per_block(pages_per_seq: int) -> int:
+    """Pages the kernel copies and computes per loop step: a block of
+    ``PAGES_PER_BLOCK`` pages, or the whole table when it is shorter."""
+    return min(PAGES_PER_BLOCK, pages_per_seq)
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, _NEG)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    n_valid = kl_ref[b]
-    slot0 = j * ps
-    live = slot0 < n_valid
-    if window is not None:
-        # page-level window skip: a page is dead once every *written* slot
-        # has aged out of the window (original ids, not slot indices)
-        sl = slot0 + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
-        in_w = (sl < n_valid) & (cp_ref[b] - pp_ref[0] < window)
-        live = jnp.logical_and(live, in_w.any())
+def pages_visited(n_valid, page_size: int) -> int:
+    """Pages the kernel copies for rows attending over ``n_valid`` slots
+    (an int or an array of per-row counts): the same arithmetic as its
+    loop, which copies the pages holding slots ``0 .. n_valid - 1`` and
+    nothing past them.  A row the engine leaves inactive attends over one
+    slot (of the null page), so it counts one page."""
+    n = np.asarray(n_valid, np.int64)
+    return int((-(-n // page_size)).sum())
 
-    @pl.when(live)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)          # (G, Dh)
-        k = k_ref[0, 0].astype(jnp.float32)       # (ps, Dh)
-        v = v_ref[0, 0].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        if softcap is not None:
-            s = jnp.tanh(s / softcap) * softcap
-        slot = slot0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = slot < n_valid
+
+def _kernel(bt_ref, kl_ref, cp_ref, q_ref, k_hbm, v_hbm, *rest,
+            scale, softcap, window, ps, ppb, kv, np_):
+    if window is None:
+        pp_ref = None
+        o_ref, k_buf, v_buf, sem, m_scr, l_scr, acc_scr = rest
+    else:
+        pp_ref, o_ref, k_buf, v_buf, sem, m_scr, l_scr, acc_scr = rest
+    b = pl.program_id(0)
+    bk = ppb * ps
+    n_valid = jnp.minimum(kl_ref[b], np_ * ps)
+    n_blk = (n_valid + bk - 1) // bk
+
+    def copies(blk, slot, p):
+        page = bt_ref[b, blk * ppb + p]
+        dst = pl.ds(p * ps, ps)
+        return (pltpu.make_async_copy(k_hbm.at[:, page],
+                                      k_buf.at[slot, :, dst], sem.at[0, slot]),
+                pltpu.make_async_copy(v_hbm.at[:, page],
+                                      v_buf.at[slot, :, dst], sem.at[1, slot]))
+
+    def each_live_page(blk, slot, fn):
+        # only pages that hold a written slot are copied (and waited for)
+        for p in range(ppb):
+            @pl.when((blk * ppb + p) * ps < n_valid)
+            def _():
+                for c in copies(blk, slot, p):
+                    fn(c)
+
+    @pl.when(n_blk > 0)
+    def _first():
+        each_live_page(0, 0, lambda c: c.start())
+
+    m_scr[...] = jnp.full_like(m_scr, _NEG)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def body(blk, carry):
+        slot = blk % 2
+
+        @pl.when(blk + 1 < n_blk)
+        def _prefetch():
+            each_live_page(blk + 1, 1 - slot, lambda c: c.start())
+
+        each_live_page(blk, slot, lambda c: c.wait())
+        slot0 = blk * bk
+        row = slot0 + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+        mask = row < n_valid                                # (1, bk)
         if window is not None:
-            mask &= cp_ref[b] - pp_ref[0] < window      # pp: (1, ps) ids
-        s = jnp.where(mask, s, _NEG)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, s.max(-1))
-        corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None]) * mask.astype(jnp.float32)
-        l_scr[...] = l_scr[...] * corr + p.sum(-1)
-        acc_scr[...] = (acc_scr[...] * corr[:, None]
-                        + jnp.dot(p, v, preferred_element_type=jnp.float32))
-        m_scr[...] = m_new
+            mask &= cp_ref[b] - pp_ref[0, pl.ds(blk, 1), :] < window
+        # rows past n_valid hold whatever the buffer held: zero them so a
+        # masked (zero) weight never meets a NaN
+        vrow = (slot0 + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
+                < n_valid)
 
-    @pl.when(j == np_ - 1)
-    def _finalize():
-        l = l_scr[...]
+        def compute():
+            for h in range(kv):
+                q = q_ref[0, h].astype(jnp.float32)             # (G, Dh)
+                k = k_buf[slot, h].astype(jnp.float32)          # (bk, Dh)
+                v = jnp.where(vrow, v_buf[slot, h].astype(jnp.float32), 0.0)
+                s = jax.lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale  # (G, bk)
+                if softcap is not None:
+                    s = jnp.tanh(s / softcap) * softcap
+                s = jnp.where(mask, s, _NEG)
+                m_prev = m_scr[h]                                # (G, 1)
+                m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+                corr = jnp.exp(m_prev - m_new)
+                p = jnp.exp(s - m_new) * mask.astype(jnp.float32)
+                l_scr[h] = l_scr[h] * corr + p.sum(-1, keepdims=True)
+                acc_scr[h] = acc_scr[h] * corr + jnp.dot(
+                    p, v, preferred_element_type=jnp.float32)
+                m_scr[h] = m_new
+
+        if window is None:
+            compute()
+        else:
+            # block-level window skip: every written slot aged out
+            pl.when(jnp.any(mask))(compute)
+        return carry
+
+    jax.lax.fori_loop(0, n_blk, body, 0)
+    for h in range(kv):
+        l = l_scr[h]
         safe = jnp.where(l > 0, l, 1.0)
-        o_ref[0] = (acc_scr[...] / safe[:, None]).astype(o_ref.dtype)
+        o_ref[0, h] = (acc_scr[h] / safe).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("softcap", "window", "interpret"))
@@ -106,45 +172,54 @@ def paged_flash_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                        interpret: Optional[bool] = None) -> jax.Array:
     """q: (B, KV, G, Dh) one token per sequence; k/v_pages: (KV, N, ps, Dh);
     pos_pages: (N, ps); tables: (B, P); kv_len/pos: (B,).
-    Returns (B, KV, G, Dh).  ``pos_pages`` is read through an
-    ``(N, 1, ps)`` view so each page's id row is a whole-array tile in its
-    last two dims (the TPU block rule).  ``interpret=None`` interprets on
-    CPU only."""
+    Returns (B, KV, G, Dh).  ``pos_pages`` is read only with a window:
+    gathered through the tables into one ``(n_blocks, block)`` id row per
+    sequence, so each block's ids are one sublane of it.  ``interpret=None``
+    interprets on CPU only."""
     B, KV, G, Dh = q.shape
     _, N, ps, _ = k_pages.shape
     P = tables.shape[1]
-    scale = Dh ** -0.5
-    qf = q.reshape(B * KV, G, Dh)
+    ppb = pages_per_block(P)
+    bk = ppb * ps
     tables = tables.astype(jnp.int32)
     kv_len = kv_len.astype(jnp.int32)
     pos = pos.astype(jnp.int32)
 
+    in_specs = [
+        pl.BlockSpec((1, KV, G, Dh), lambda b, bt, kl, cp: (b, 0, 0, 0)),
+        pl.BlockSpec(memory_space=pl.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
+    ]
+    operands = [q, k_pages, v_pages]
+    if window is not None:
+        n_blk = -(-P // ppb)
+        ids = pos_pages.astype(jnp.int32)[tables].reshape(B, P * ps)
+        ids = jnp.pad(ids, ((0, 0), (0, n_blk * bk - P * ps)))
+        operands.append(ids.reshape(B, n_blk, bk))
+        in_specs.append(pl.BlockSpec((1, n_blk, bk),
+                                     lambda b, bt, kl, cp: (b, 0, 0)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B * KV, P),
-        in_specs=[
-            pl.BlockSpec((1, G, Dh), lambda i, j, bt, kl, cp: (i, 0, 0)),
-            pl.BlockSpec((1, 1, ps, Dh),
-                         lambda i, j, bt, kl, cp: (i % KV, bt[i // KV, j],
-                                                   0, 0)),
-            pl.BlockSpec((1, 1, ps, Dh),
-                         lambda i, j, bt, kl, cp: (i % KV, bt[i // KV, j],
-                                                   0, 0)),
-            pl.BlockSpec((1, 1, ps),
-                         lambda i, j, bt, kl, cp: (bt[i // KV, j], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, G, Dh), lambda i, j, bt, kl, cp: (i, 0, 0)),
+        grid=(B,),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, KV, G, Dh),
+                               lambda b, bt, kl, cp: (b, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G, Dh), jnp.float32),
+            pltpu.VMEM((2, KV, bk, Dh), k_pages.dtype),
+            pltpu.VMEM((2, KV, bk, Dh), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((KV, G, 1), jnp.float32),
+            pltpu.VMEM((KV, G, 1), jnp.float32),
+            pltpu.VMEM((KV, G, Dh), jnp.float32),
         ],
     )
-    out = pl.pallas_call(
-        functools.partial(_kernel, scale=scale, softcap=softcap,
-                          window=window, ps=ps, kv=KV, np_=P),
+    return pl.pallas_call(
+        functools.partial(_kernel, scale=Dh ** -0.5, softcap=softcap,
+                          window=window, ps=ps, ppb=ppb, kv=KV, np_=P),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B * KV, G, Dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, KV, G, Dh), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=resolve_interpret(interpret),
-    )(tables, kv_len, pos, qf, k_pages, v_pages, pos_pages.reshape(N, 1, ps))
-    return out.reshape(B, KV, G, Dh)
+        name="paged_flash_decode",
+    )(tables, kv_len, pos, *operands)

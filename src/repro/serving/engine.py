@@ -33,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ArchConfig
+from repro.kernels.paged_decode import pages_visited
 from repro.models import decode_step, init_cache, prefill
 from repro.models.attn_backend import AUTO, resolve_backend
 from repro.observability import Telemetry, tree_bytes
@@ -776,8 +777,9 @@ class PagedServingEngine(_SamplerMixin):
         The ``decode_tick`` span runs from the block tables to the tokens'
         readback; its args count the active slots, the pages that hold
         their KV once this step has written (``pages_live``), and the
-        pages the decode kernel's grid visits (``pages_grid``: every page
-        of every slot)."""
+        pages the Pallas decode kernel copies (``pages_grid``: the pages
+        holding each row's attended slots, one null page for an inactive
+        row; ``paged_decode.pages_visited`` of this step's lengths)."""
         tel = self.telemetry
         with tel.span("engine/decode_prepare"):
             # grow pages for every decode-ready row (may preempt the
@@ -792,19 +794,21 @@ class PagedServingEngine(_SamplerMixin):
             if not active:
                 return 0
             n_slots = self.scfg.n_slots
+            kv_len = np.zeros((n_slots,), np.int32)
+            for st in active:
+                kv_len[st.slot] = st.kv_len
+            # the step attends over kv_len + 1 slots of every row
             tel.span_begin("decode_tick", args={
                 "n_active": len(active),
                 "pages_live": sum(self.pool.pages_for(st.kv_len + 1)
                                   for st in active),
-                "pages_grid": n_slots * self.pages_per_seq})
+                "pages_grid": pages_visited(kv_len + 1, self.page_size)})
             tables = np.full((n_slots, self.pages_per_seq), NULL_PAGE,
                              np.int32)
-            kv_len = np.zeros((n_slots,), np.int32)
             cur_pos = np.zeros((n_slots,), np.int32)
             tokens = np.zeros((n_slots, 1), np.int32)
             for st in active:
                 tables[st.slot] = self._table_row(st)
-                kv_len[st.slot] = st.kv_len
                 cur_pos[st.slot] = st.cur_pos
                 tokens[st.slot, 0] = st.req.output[-1]
             step_args = (jnp.asarray(tables), jnp.asarray(kv_len),

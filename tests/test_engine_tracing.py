@@ -7,11 +7,13 @@ import glob
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.profiler import ProfileData
 
 from repro.configs.base import ArchConfig, BlockCfg
 from repro.core.spls import SPLSConfig
+from repro.kernels.paged_decode import pages_visited
 from repro.models import init_params
 from repro.observability import PHASE_PID, Telemetry
 from repro.serving import PagedServingEngine, Request, ServeConfig
@@ -155,20 +157,49 @@ class TestPhaseSpans:
 # the decode counter
 # ---------------------------------------------------------------------------
 
+def _decode_ticks(eng):
+    return [e["args"] for e in eng.telemetry.trace.events
+            if e["name"] == "decode_tick" and e["ph"] == "B"]
+
+
 def test_decode_tick_counts_live_and_visited_pages():
     eng = _engine(n_slots=3)
     for r in _reqs([20, 7, 13], max_new=6):
         eng.submit(r)
     eng.run_until_drained(max_ticks=200)
-    ticks = [e["args"] for e in eng.telemetry.trace.events
-             if e["name"] == "decode_tick" and e["ph"] == "B"]
+    ticks = _decode_ticks(eng)
     assert ticks
-    grid = 3 * eng.pages_per_seq
     for a in ticks:
-        assert a["pages_grid"] == grid
-        assert a["n_active"] <= a["pages_live"] <= a["pages_grid"]
+        # the kernel copies the live pages, plus one null page for each
+        # inactive row, and never more than the whole tables
+        assert (a["n_active"] <= a["pages_live"] <= a["pages_grid"]
+                <= 3 * eng.pages_per_seq)
+        assert a["pages_grid"] == a["pages_live"] + 3 - a["n_active"]
     # a 20-token prompt's first decode step writes slot 20: 6 pages of 4
     assert max(a["pages_live"] for a in ticks) >= 6
+
+
+def test_pages_grid_is_what_the_kernel_copies():
+    """``pages_grid`` is ``pages_visited`` of the lengths the decode step
+    is handed (it attends over ``kv_len + 1`` slots), with the Pallas
+    kernel serving the batch (interpret mode on the CPU)."""
+    eng = _engine(n_slots=3, attn_backend="pallas_paged_decode")
+    seen = []
+    decode = eng._decode
+
+    def spy(params, cache, pos_pages, tables, kv_len, *rest):
+        seen.append(np.asarray(kv_len))
+        return decode(params, cache, pos_pages, tables, kv_len, *rest)
+
+    eng._decode = spy
+    for r in _reqs([20, 7], max_new=4):
+        eng.submit(r)
+    eng.run_until_drained(max_ticks=200)
+    ticks = _decode_ticks(eng)
+    assert ticks and len(ticks) == len(seen)
+    for a, kv_len in zip(ticks, seen):
+        assert a["pages_grid"] == pages_visited(kv_len + 1, eng.page_size)
+        assert a["pages_live"] <= a["pages_grid"] <= 3 * eng.pages_per_seq
 
 
 # ---------------------------------------------------------------------------

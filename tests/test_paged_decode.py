@@ -1,6 +1,7 @@
-"""Paged decode kernels (Pallas scalar-prefetch gather + XLA fallback) vs
-the dense/gathered oracles: ragged lengths, GQA, sliding window, softcap,
-null-page masking, and SPLS-compacted (pruned) layouts."""
+"""Paged decode kernels (Pallas live-page loop + XLA fallback) vs the
+dense/gathered oracles: ragged lengths, GQA, sliding window, softcap,
+null-page masking, SPLS-compacted (pruned) layouts, and lengths around the
+kernel's block edges."""
 
 import jax
 import jax.numpy as jnp
@@ -8,7 +9,8 @@ import numpy as np
 import pytest
 
 from repro.configs.base import ArchConfig, BlockCfg
-from repro.kernels.paged_decode import paged_flash_decode
+from repro.kernels.paged_decode import (PAGES_PER_BLOCK, pages_per_block,
+                                       pages_visited, paged_flash_decode)
 from repro.kernels.ref import flash_decode_ref, paged_decode_ref
 from repro.models import get_backend
 from repro.serving.pager import POS_SENTINEL
@@ -156,6 +158,162 @@ class TestPagedKernelParity:
             a = a / a.sum(-1, keepdims=True)
             want[b] = np.einsum("kgl,kld->kgd", a, np.asarray(vd[b]))
         np.testing.assert_allclose(np.asarray(out), want, atol=2e-5)
+
+
+def _xla(q, kp, vp, pos, tables, kv_len, cur, window=None):
+    cfg = ArchConfig(period=(BlockCfg(),))
+    return get_backend("xla_paged_decode")(
+        cfg, q, kp, vp, pos_pages=pos, tables=tables, kv_len=kv_len, pos=cur,
+        window=window)
+
+
+def _shuffled_tables(B, P, npages, seed):
+    """(B, P) tables of distinct shuffled pool pages, null past ``npages``
+    of each row."""
+    rng = np.random.default_rng(seed)
+    perm = 1 + rng.permutation(B * P).reshape(B, P)
+    return np.where(np.arange(P)[None, :] < np.asarray(npages)[:, None],
+                    perm, 0)
+
+
+class TestLivePageLoop:
+    """The loop over blocks of ``pages_per_block`` pages: lengths at and
+    around block edges, inactive rows, never-read pages, and a window in
+    the compacted layout, each against ``xla_paged_decode``."""
+
+    KV, G, Dh, ps = 2, 2, 16, 4
+    P = 2 * PAGES_PER_BLOCK + PAGES_PER_BLOCK // 2  # 2.5 blocks a table
+
+    def _pool(self, B, seed):
+        N = B * self.P + 1
+        ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+        q = jax.random.normal(ks[0], (B, self.KV, self.G, self.Dh))
+        kp = jax.random.normal(ks[1], (self.KV, N, self.ps, self.Dh))
+        vp = jax.random.normal(ks[2], (self.KV, N, self.ps, self.Dh))
+        return q, kp, vp, N
+
+    @pytest.mark.parametrize("where", ["one", "block", "block+1", "2block",
+                                       "2block+1", "max_len"])
+    def test_lengths_at_block_edges(self, where):
+        bk = pages_per_block(self.P) * self.ps
+        n = {"one": 1, "block": bk, "block+1": bk + 1, "2block": 2 * bk,
+             "2block+1": 2 * bk + 1, "max_len": self.P * self.ps}[where]
+        B = 2
+        q, kp, vp, N = self._pool(B, seed=21)
+        kv_len = np.asarray([n, max(1, n - 3)])
+        tables = jnp.asarray(_shuffled_tables(
+            B, self.P, -(-kv_len // self.ps), seed=1), jnp.int32)
+        kv_len = jnp.asarray(kv_len, jnp.int32)
+        pos = _contiguous_layout(np.asarray(tables), kv_len, N, self.ps)
+        cur = kv_len - 1
+        out = paged_flash_decode(q, kp, vp, pos, tables, kv_len, cur,
+                                 interpret=True)
+        want = _xla(q, kp, vp, pos, tables, kv_len, cur)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                                   atol=2e-5)
+
+    def test_inactive_rows_beside_full_rows(self):
+        """Rows the engine leaves inactive (all-null tables, one attended
+        slot of the null page) mixed with rows at ``max_len``."""
+        B = 4
+        q, kp, vp, N = self._pool(B, seed=22)
+        full = self.P * self.ps
+        kv_len = np.asarray([full, 1, full, 1])
+        npages = np.where(kv_len > 1, self.P, 0)
+        tables = jnp.asarray(_shuffled_tables(B, self.P, npages, seed=2),
+                             jnp.int32)
+        kv_len = jnp.asarray(kv_len, jnp.int32)
+        pos = _contiguous_layout(np.asarray(tables), kv_len, N, self.ps)
+        cur = jnp.maximum(kv_len - 1, 0)
+        out = paged_flash_decode(q, kp, vp, pos, tables, kv_len, cur,
+                                 interpret=True)
+        want = _xla(q, kp, vp, pos, tables, kv_len, cur)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                                   atol=2e-5)
+
+    @pytest.mark.parametrize("window", [None, 9])
+    def test_pages_past_kv_len_are_never_read(self, window):
+        """Every pool page no row attends (the null page, the pages a table
+        holds past ``kv_len``, pages no table holds) and the unwritten tail
+        of each last page hold NaN.  The output is finite and equals the
+        reference on the same pool with those entries zeroed."""
+        B = 3
+        q, kp, vp, N = self._pool(B, seed=23)
+        bk = pages_per_block(self.P) * self.ps
+        kv_len = np.asarray([bk + 5, 5, 2 * bk])
+        # allocated pages run two past the written ones (as if grown early)
+        tables = _shuffled_tables(B, self.P, -(-kv_len // self.ps) + 2,
+                                  seed=3)
+        live = np.zeros((N, self.ps), bool)
+        for b, n in enumerate(kv_len):
+            for s in range(n):
+                live[tables[b, s // self.ps], s % self.ps] = True
+        tables = jnp.asarray(tables, jnp.int32)
+        kv_len = jnp.asarray(kv_len, jnp.int32)
+        pos = _contiguous_layout(np.asarray(tables), kv_len, N, self.ps)
+        cur = kv_len - 1
+        dead = jnp.asarray(~live)[None, :, :, None]
+        out = paged_flash_decode(q, jnp.where(dead, jnp.nan, kp),
+                                 jnp.where(dead, jnp.nan, vp), pos, tables,
+                                 kv_len, cur, window=window, interpret=True)
+        assert np.isfinite(np.asarray(out)).all()
+        want = _xla(q, jnp.where(dead, 0.0, kp), jnp.where(dead, 0.0, vp),
+                    pos, tables, kv_len, cur, window=window)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                                   atol=2e-5)
+
+    @pytest.mark.parametrize("edge", ["straddle", "skip"])
+    def test_window_in_compacted_layout_across_blocks(self, edge):
+        """SPLS-compacted rows (slot != position) over two blocks.  The
+        window's live slots of row 0 start three slots before the first
+        block edge (``straddle``), or two slots after it, so that every
+        slot of row 0's first block is out and the block is skipped
+        whole (``skip``)."""
+        B = 2
+        bk = pages_per_block(self.P) * self.ps
+        L = 4 * bk
+        q, kp0, vp0, N = self._pool(B, seed=24)
+        rng = np.random.default_rng(4)
+        keep = [np.sort(rng.choice(L, 2 * bk, replace=False)),
+                np.sort(rng.choice(L, 2 * bk - 5, replace=False))]
+        kd = np.asarray(jax.random.normal(jax.random.PRNGKey(5),
+                                          (B, self.KV, L, self.Dh)))
+        vd = np.asarray(jax.random.normal(jax.random.PRNGKey(6),
+                                          (B, self.KV, L, self.Dh)))
+        kp, vp = np.asarray(kp0).copy(), np.asarray(vp0).copy()
+        pos = np.full((N, self.ps), POS_SENTINEL, np.int64)
+        kv_len = np.asarray([len(k) for k in keep])
+        tables = _shuffled_tables(B, self.P, -(-kv_len // self.ps), seed=5)
+        cur = np.asarray([L - 1, L - 1])
+        for b, idx in enumerate(keep):
+            for i, j in enumerate(idx):
+                pg, off = tables[b, i // self.ps], i % self.ps
+                kp[:, pg, off] = kd[b, :, j]
+                vp[:, pg, off] = vd[b, :, j]
+                pos[pg, off] = j
+        first = bk - 3 if edge == "straddle" else bk + 2
+        window = int(cur[0] - keep[0][first]) + 1
+        assert int(np.argmax(cur[0] - keep[0] < window)) == first
+        args = (jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(pos, jnp.int32),
+                jnp.asarray(tables, jnp.int32), jnp.asarray(kv_len, jnp.int32),
+                jnp.asarray(cur, jnp.int32))
+        out = paged_flash_decode(q, *args, window=window, interpret=True)
+        want = _xla(q, *args, window=window)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                                   atol=2e-5)
+
+
+class TestPagesVisited:
+    def test_counts_pages_holding_attended_slots(self):
+        assert pages_visited(1, 16) == 1
+        assert pages_visited(16, 16) == 1
+        assert pages_visited(17, 16) == 2
+        assert pages_visited(np.asarray([1, 1, 2048, 300]), 16) == 1 + 1 + \
+            128 + 19
+
+    def test_block_is_a_constant_capped_by_the_table(self):
+        assert pages_per_block(128) == PAGES_PER_BLOCK
+        assert pages_per_block(2) == 2
 
 
 class TestPagedBackendRegistry:

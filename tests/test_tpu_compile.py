@@ -90,6 +90,23 @@ CASES = {
             ((KV, N_PAGES, PAGE, DH), BF16), ((KV, N_PAGES, PAGE, DH), BF16),
             ((N_PAGES, PAGE), I32), ((SLOTS, MAX_LEN // PAGE), I32),
             ((SLOTS,), I32), ((SLOTS,), I32)]),
+    # the benchmark's engine: 20 slots of 128 pages; and a window, whose
+    # ids ride in gathered through the tables, at 100 pages a sequence
+    "paged_flash_decode_20x128": (
+        lambda q, kp, vp, pp, tb, kl, pos: paged_flash_decode(
+            q, kp, vp, pp, tb, kl, pos, interpret=False), [
+            ((20, KV, H // KV, DH), BF16),
+            ((KV, 20 * 128 + 1, PAGE, DH), BF16),
+            ((KV, 20 * 128 + 1, PAGE, DH), BF16),
+            ((20 * 128 + 1, PAGE), I32), ((20, 128), I32),
+            ((20,), I32), ((20,), I32)]),
+    "paged_flash_decode_window": (
+        lambda q, kp, vp, pp, tb, kl, pos: paged_flash_decode(
+            q, kp, vp, pp, tb, kl, pos, window=512, interpret=False), [
+            ((SLOTS, KV, H // KV, DH), BF16),
+            ((KV, N_PAGES, PAGE, DH), BF16), ((KV, N_PAGES, PAGE, DH), BF16),
+            ((N_PAGES, PAGE), I32), ((SLOTS, 100), I32),
+            ((SLOTS,), I32), ((SLOTS,), I32)]),
     "flash_decode": (
         lambda q, k, v, pos: flash_decode(q, k, v, pos, interpret=False), [
             ((SLOTS, KV, H // KV, DH), BF16), ((SLOTS, KV, MAX_LEN, DH), BF16),
