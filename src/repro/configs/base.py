@@ -69,13 +69,25 @@ class ArchConfig:
     # attention features
     rope_theta: float = 10000.0
     qk_norm: bool = False
+    # "head": RMSNorm over each head's Dh (gains (Dh,), Qwen3); "full": over
+    # the whole q / k projection before the head split (gains (H*Dh,) and
+    # (KV*Dh,), OLMoE)
+    qk_norm_mode: str = "head"
     attn_softcap: Optional[float] = None
     final_softcap: Optional[float] = None
     causal: bool = True
     # MoE
     moe_experts: int = 0
     moe_topk: int = 0
+    # renormalise the top-k gates to sum to one (OLMoE: no)
+    moe_norm_topk: bool = True
+    # capacity_factor sizes the capacity formulation (training, dry-runs);
+    # a config that sets moe_held runs the dropless held-expert layer in
+    # every path instead (repro.models.moe)
     capacity_factor: float = 1.25
+    # the experts this chip holds of an expert-parallel layer (None: all);
+    # the router still spans all moe_experts
+    moe_held: Optional[Tuple[int, ...]] = None
     # Mamba2 / SSD
     ssm_state: int = 0
     mamba_headdim: int = 64
@@ -125,6 +137,15 @@ class ArchConfig:
             raise ValueError(
                 f"{self.name}: n_layers={self.n_layers} not divisible by "
                 f"period length {len(self.period)}")
+        if self.qk_norm_mode not in ("head", "full"):
+            raise ValueError(f"{self.name}: qk_norm_mode must be 'head' or "
+                             f"'full', got {self.qk_norm_mode!r}")
+        if self.moe_held is not None:
+            held = tuple(self.moe_held)
+            if not held or len(set(held)) != len(held) or not all(
+                    0 <= e < self.moe_experts for e in held):
+                raise ValueError(f"{self.name}: moe_held {held} must name "
+                                 f"distinct experts of {self.moe_experts}")
 
     @property
     def n_periods(self) -> int:
@@ -154,6 +175,17 @@ class ArchConfig:
     def has_moe(self) -> bool:
         return any(b.use_moe for b in self.period)
 
+    @property
+    def moe_held_ids(self) -> Tuple[int, ...]:
+        """The experts held here, in the order of the expert weights."""
+        if self.moe_held is None:
+            return tuple(range(self.moe_experts))
+        return tuple(self.moe_held)
+
+    @property
+    def n_held_experts(self) -> int:
+        return len(self.moe_held_ids)
+
     def moe_capacity(self, n_tokens: int) -> int:
         """Per-expert token capacity, rounded up to a multiple of 8."""
         c = math.ceil(n_tokens * self.moe_topk * self.capacity_factor
@@ -182,7 +214,8 @@ class ArchConfig:
                 mult = 3 if self.ffn_activation in ("silu", "gelu") else 2
                 f = mult * D * self.d_ff
                 if b.use_moe:
-                    per_period += self.moe_experts * f + D * self.moe_experts
+                    per_period += (self.n_held_experts * f
+                                   + D * self.moe_experts)
                 else:
                     per_period += f
             per_period += 2 * D  # norms
@@ -201,7 +234,10 @@ class ArchConfig:
 
     # ------------------------------------------------------------------
     def smoke(self) -> "ArchConfig":
-        """Structurally identical, CPU-sized variant for tests."""
+        """Structurally identical, CPU-sized variant for tests.  A config
+        that holds a share of its experts keeps a share: 4 of 16 experts,
+        4 per token."""
+        held = self.moe_held is not None
         return dataclasses.replace(
             self,
             name=self.name + "-smoke",
@@ -212,8 +248,11 @@ class ArchConfig:
             head_dim=16,
             d_ff=128 if self.d_ff else 0,
             vocab_size=256,
-            moe_experts=min(self.moe_experts, 4) if self.moe_experts else 0,
-            moe_topk=min(self.moe_topk, 2) if self.moe_topk else 0,
+            moe_experts=(16 if held else min(self.moe_experts, 4))
+            if self.moe_experts else 0,
+            moe_topk=(4 if held else min(self.moe_topk, 2))
+            if self.moe_topk else 0,
+            moe_held=(0, 1, 2, 3) if held else None,
             ssm_state=16 if self.ssm_state else 0,
             mamba_headdim=16,
             period=tuple(dataclasses.replace(

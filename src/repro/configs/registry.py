@@ -23,9 +23,13 @@ _MODULES: Dict[str, str] = {
     "pixtral-12b": "pixtral_12b",
     # the paper's own workload (not part of the 40-cell assignment)
     "bert-base-esact": "bert_base_esact",
+    # one chip's share of olmoe-1b-7b served 8-way expert parallel
+    "olmoe-1b-7b-ep8": "olmoe_1b_7b_ep8",
 }
 
-ARCH_IDS: List[str] = [k for k in _MODULES if k != "bert-base-esact"]
+# the 40-cell assignment: neither the paper's workload nor a serving cut
+ARCH_IDS: List[str] = [k for k in _MODULES
+                       if k not in ("bert-base-esact", "olmoe-1b-7b-ep8")]
 
 
 def get_config(name: str) -> ArchConfig:

@@ -29,7 +29,7 @@ K/V skipping + packed critical Q rows on the Pallas path.
 
 from __future__ import annotations
 
-from typing import NamedTuple, Optional
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -42,7 +42,7 @@ from .common import apply_rope, dense_init, rms_norm, rope_freqs
 
 __all__ = ["init_attention", "attention_forward", "attention_decode",
            "KVCache", "init_kv_cache", "head_shard_mode", "project_qkv",
-           "project_kv", "output_proj"]
+           "project_kv", "output_proj", "norm_q", "norm_k"]
 
 
 class KVCache(NamedTuple):
@@ -100,9 +100,39 @@ def init_attention(cfg: ArchConfig, key: jax.Array, dtype) -> dict:
         "wo": dense_init(ks[3], (KV, G, Dh, D), dtype, fan_in=KV * G * Dh),
     }
     if cfg.qk_norm:
-        p["q_norm"] = jnp.zeros((Dh,), dtype)
-        p["k_norm"] = jnp.zeros((Dh,), dtype)
+        full = cfg.qk_norm_mode == "full"
+        p["q_norm"] = jnp.zeros((cfg.n_heads * Dh,) if full else (Dh,), dtype)
+        p["k_norm"] = jnp.zeros((KV * Dh,) if full else (Dh,), dtype)
     return p
+
+
+def _full_rms_norm(x: jax.Array, gain: jax.Array, eps: float,
+                   head_axes: Tuple[int, ...]) -> jax.Array:
+    """RMSNorm over a whole projection whose heads are split out on
+    ``head_axes`` (ascending, in the projection's order) and the last axis:
+    the same numbers as normalising the unsplit ``(..., heads * Dh)``
+    projection with the flat gain, as ``rms_norm`` does (offset gain)."""
+    dt = x.dtype
+    axes = tuple(head_axes) + (x.ndim - 1,)
+    xf = x.astype(jnp.float32)
+    var = jnp.mean(jnp.square(xf), axis=axes, keepdims=True)
+    g = gain.astype(jnp.float32).reshape(
+        [x.shape[a] if a in axes else 1 for a in range(x.ndim)])
+    return (xf * jax.lax.rsqrt(var + eps) * (1.0 + g)).astype(dt)
+
+
+def norm_q(cfg: ArchConfig, p: dict, q: jax.Array) -> jax.Array:
+    """qk-norm of q in the structured layout (B, KV, G, L, Dh)."""
+    if cfg.qk_norm_mode == "full":
+        return _full_rms_norm(q, p["q_norm"], cfg.norm_eps, (1, 2))
+    return rms_norm(q, p["q_norm"], cfg.norm_eps)
+
+
+def norm_k(cfg: ArchConfig, p: dict, k: jax.Array) -> jax.Array:
+    """qk-norm of k in the structured layout (B, KV, L, Dh)."""
+    if cfg.qk_norm_mode == "full":
+        return _full_rms_norm(k, p["k_norm"], cfg.norm_eps, (1,))
+    return rms_norm(k, p["k_norm"], cfg.norm_eps)
 
 
 def _project_qkv(cfg: ArchConfig, p: dict, x: jax.Array, positions: jax.Array,
@@ -115,6 +145,9 @@ def _project_qkv(cfg: ArchConfig, p: dict, x: jax.Array, positions: jax.Array,
     KV, Dh = cfg.n_kv_heads, cfg.resolved_head_dim
     G = cfg.n_heads // KV
     if mode in ("flat", "padded"):
+        if cfg.qk_norm and cfg.qk_norm_mode == "full":
+            raise NotImplementedError(
+                "full-width qk-norm runs in the structured head layout")
         H = KV * G
         wq = p["wq"].reshape(D, H, Dh)
         wk, wv = p["wk"], p["wv"]
@@ -145,8 +178,8 @@ def _project_qkv(cfg: ArchConfig, p: dict, x: jax.Array, positions: jax.Array,
         k = constrain(k, ("batch", "kv_heads", "seq", None))
         v = constrain(v, ("batch", "kv_heads", "seq", None))
     if cfg.qk_norm:
-        q = rms_norm(q, p["q_norm"], cfg.norm_eps)
-        k = rms_norm(k, p["k_norm"], cfg.norm_eps)
+        q = norm_q(cfg, p, q)
+        k = norm_k(cfg, p, k)
     sin, cos = rope_freqs(positions, Dh, cfg.rope_theta)
     q = apply_rope(q, sin[:, None, None], cos[:, None, None])
     k = apply_rope(k, sin[:, None], cos[:, None])
@@ -198,7 +231,7 @@ def _project_kv(cfg: ArchConfig, p: dict, x: jax.Array,
     k = constrain(k, ("batch", "kv_heads", "seq", None))
     v = constrain(v, ("batch", "kv_heads", "seq", None))
     if cfg.qk_norm:
-        k = rms_norm(k, p["k_norm"], cfg.norm_eps)
+        k = norm_k(cfg, p, k)
     sin, cos = rope_freqs(positions, Dh, cfg.rope_theta)
     k = apply_rope(k, sin[:, None], cos[:, None])
     return k, v

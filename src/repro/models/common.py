@@ -7,13 +7,25 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-__all__ = ["dtype_of", "rms_norm", "layer_norm", "rope_freqs", "apply_rope",
+__all__ = ["dtype_of", "cast_compute", "rms_norm", "layer_norm", "rope_freqs", "apply_rope",
            "dense_init", "softcap", "Activations"]
 
 
 def dtype_of(name: str):
     return {"float32": jnp.float32, "bfloat16": jnp.bfloat16,
             "float16": jnp.float16}[name]
+
+
+def cast_compute(params, dtype):
+    """Floating parameters cast to the compute dtype, except an MoE
+    router's weights (key ``router``), which route in float32."""
+    def cast(path, a):
+        if not jnp.issubdtype(a.dtype, jnp.floating) or any(
+                getattr(k, "key", None) == "router" for k in path):
+            return a
+        return a.astype(dtype)
+
+    return jax.tree_util.tree_map_with_path(cast, params)
 
 
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:

@@ -24,7 +24,7 @@ from repro.configs.base import ArchConfig
 from repro.sharding.logical import constrain
 from .blocks import (block_decode, block_forward, init_block,
                      init_block_cache)
-from .common import dense_init, dtype_of, rms_norm, softcap
+from .common import cast_compute, dense_init, dtype_of, rms_norm, softcap
 
 __all__ = ["init_params", "abstract_params", "forward", "loss_fn",
            "init_cache", "decode_step", "prefill", "embed_inputs",
@@ -101,9 +101,7 @@ def head_logits(cfg: ArchConfig, params: Params, x: jax.Array) -> jax.Array:
 
 def _period_fn(cfg: ArchConfig, x: jax.Array, pparams) -> jax.Array:
     dtype = dtype_of(cfg.compute_dtype)
-    pparams = jax.tree.map(
-        lambda a: a.astype(dtype) if jnp.issubdtype(a.dtype, jnp.floating)
-        else a, pparams)
+    pparams = cast_compute(pparams, dtype)
     # layer-boundary activations (the remat save points) are seq-sharded
     # over the model axis (Megatron sequence parallelism)
     x = constrain(x, ("batch", "act_seq", "embed"))
@@ -173,9 +171,7 @@ def decode_step(cfg: ArchConfig, params: Params, cache, tokens: jax.Array,
 
     def scan_body(x, inp):
         pparams, pcache = inp
-        pparams = jax.tree.map(
-            lambda a: a.astype(dtype)
-            if jnp.issubdtype(a.dtype, jnp.floating) else a, pparams)
+        pparams = cast_compute(pparams, dtype)
         new_caches = []
         for blk, bp, bc in zip(cfg.period, pparams, pcache):
             x, nc = block_decode(cfg, blk, bp, x, bc, pos)
@@ -204,9 +200,7 @@ def prefill(cfg: ArchConfig, params: Params, inputs: jax.Array,
     x = _embed_in(cfg, params, inputs)
 
     def scan_body(x, pparams):
-        pparams = jax.tree.map(
-            lambda a: a.astype(dtype)
-            if jnp.issubdtype(a.dtype, jnp.floating) else a, pparams)
+        pparams = cast_compute(pparams, dtype)
         caches = []
         for blk, bp in zip(cfg.period, pparams):
             x, c = block_forward(cfg, blk, bp, x, cache_len=S,

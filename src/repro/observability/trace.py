@@ -21,6 +21,11 @@ engine traces.
 A ``TraceRecorder(enabled=False)`` drops everything (records nothing);
 ``max_events`` bounds memory on long runs, with the overflow counted in
 ``dropped`` instead of silently truncating.
+
+A step's device-side counters join a span's args through :meth:`defer`
+without a host sync: the recorder keeps the device array and reads it
+back when the events are read (or when :data:`MAX_PENDING` are held, by
+which time their steps have long finished).
 """
 
 from __future__ import annotations
@@ -37,6 +42,8 @@ ENGINE_TRACK = 0
 # ``engine/decode_prepare`` starts before ``decode_tick`` and ends inside
 # it, so the two cannot nest on one track
 PHASE_PID = 2
+# deferred device counters held before they are read back in one batch
+MAX_PENDING = 1024
 
 
 class TraceRecorder:
@@ -45,16 +52,44 @@ class TraceRecorder:
         self.enabled = enabled
         self.pid = pid
         self.max_events = max_events
-        self.events: List[dict] = []
+        self._events: List[dict] = []
         self.dropped = 0
         self._stacks: dict = {}   # (pid, tid) -> [open span names]
+        self._pending: list = []  # (args, names, device array)
+
+    @property
+    def events(self) -> List[dict]:
+        """The recorded events, every deferred counter read back."""
+        self._resolve()
+        return self._events
+
+    def defer(self, args: Optional[dict], names, value) -> None:
+        """Set ``args[names[i]] = int(value[i])`` once ``value`` (a device
+        array the caller has just dispatched) is read back: when the
+        events are read, or with the next :data:`MAX_PENDING` in one
+        batched transfer.  ``args`` is the dict a span was begun with."""
+        if not self.enabled or args is None:
+            return
+        self._pending.append((args, tuple(names), value))
+        if len(self._pending) >= MAX_PENDING:
+            self._resolve()
+
+    def _resolve(self) -> None:
+        if not self._pending:
+            return
+        import jax
+
+        pending, self._pending = self._pending, []
+        host = jax.device_get([v for _, _, v in pending])
+        for (args, names, _), vals in zip(pending, host):
+            args.update({n: int(v) for n, v in zip(names, vals)})
 
     # ------------------------------------------------------------------
     def _emit(self, ev: dict) -> None:
-        if len(self.events) >= self.max_events:
+        if len(self._events) >= self.max_events:
             self.dropped += 1
             return
-        self.events.append(ev)
+        self._events.append(ev)
 
     def open_spans(self, tid: int) -> List[str]:
         """Names of currently open spans on a track, outermost first

@@ -36,6 +36,7 @@ from repro.configs.base import ArchConfig
 from repro.kernels.paged_decode import pages_visited
 from repro.models import decode_step, init_cache, prefill
 from repro.models.attn_backend import AUTO, resolve_backend
+from repro.models.moe import MOE_STATS
 from repro.observability import Telemetry, tree_bytes
 from repro.sparse_compute import (CapacityController, chunk_flops, is_packed,
                                   resolve_compute_backend)
@@ -599,8 +600,8 @@ class PagedServingEngine(_SamplerMixin):
         cs = self.sched.cfg.prefill_chunk
         if not self.sched.grow_to(st, start + valid):
             return None
-        tel.span_begin("prefill_chunk", rid=st.req.rid,
-                       args={"start": start, "valid": valid})
+        span_args = {"start": start, "valid": valid}
+        tel.span_begin("prefill_chunk", rid=st.req.rid, args=span_args)
         chunk = np.zeros((cs,), np.int32)
         chunk[:valid] = st.tokens[start:start + valid]
         if self.cfg.spls.enabled:
@@ -675,11 +676,13 @@ class PagedServingEngine(_SamplerMixin):
                 self.cfg, cs, start + valid, q_rows=cq, ffn_rows=cf,
                 kv_rows=ckv))
         else:
-            logits, self.cache, self.pos_pages = self._chunk(
+            logits, self.cache, self.pos_pages, *moe = self._chunk(
                 self.params, self.cache, self.pos_pages,
                 jnp.asarray(self._table_row(st)),
                 jnp.asarray(start, jnp.int32), jnp.asarray(chunk)[None, :],
                 jnp.asarray(valid, jnp.int32))
+            if moe:
+                tel.trace.defer(span_args, MOE_STATS, moe[0])
             self.sched.note_flops(chunk_flops(self.cfg, cs, start + valid))
         st.prefilled += valid
         st.kv_len += valid
@@ -779,7 +782,11 @@ class PagedServingEngine(_SamplerMixin):
         their KV once this step has written (``pages_live``), and the
         pages the Pallas decode kernel copies (``pages_grid``: the pages
         holding each row's attended slots, one null page for an inactive
-        row; ``paged_decode.pages_visited`` of this step's lengths)."""
+        row; ``paged_decode.pages_visited`` of this step's lengths).  A
+        model with held experts adds the step's ``moe_pairs``,
+        ``moe_touched`` and ``moe_peak`` (``repro.models.moe.MOE_STATS``),
+        read back without a sync (``TraceRecorder.defer``); the
+        ``prefill_chunk`` span carries the chunk step's the same way."""
         tel = self.telemetry
         with tel.span("engine/decode_prepare"):
             # grow pages for every decode-ready row (may preempt the
@@ -798,11 +805,12 @@ class PagedServingEngine(_SamplerMixin):
             for st in active:
                 kv_len[st.slot] = st.kv_len
             # the step attends over kv_len + 1 slots of every row
-            tel.span_begin("decode_tick", args={
+            span_args = {
                 "n_active": len(active),
                 "pages_live": sum(self.pool.pages_for(st.kv_len + 1)
                                   for st in active),
-                "pages_grid": pages_visited(kv_len + 1, self.page_size)})
+                "pages_grid": pages_visited(kv_len + 1, self.page_size)}
+            tel.span_begin("decode_tick", args=span_args)
             tables = np.full((n_slots, self.pages_per_seq), NULL_PAGE,
                              np.int32)
             cur_pos = np.zeros((n_slots,), np.int32)
@@ -814,8 +822,10 @@ class PagedServingEngine(_SamplerMixin):
             step_args = (jnp.asarray(tables), jnp.asarray(kv_len),
                          jnp.asarray(cur_pos), jnp.asarray(tokens))
         with tel.span("engine/decode_dispatch"):
-            logits, self.cache, self.pos_pages = self._decode(
+            logits, self.cache, self.pos_pages, *moe = self._decode(
                 self.params, self.cache, self.pos_pages, *step_args)
+            if moe:
+                tel.trace.defer(span_args, MOE_STATS, moe[0])
         with tel.span("engine/decode_readback"):
             nxt = self._pick(logits[:, 0])
             for st in active:

@@ -41,9 +41,10 @@ import jax.numpy as jnp
 from repro.configs.base import ArchConfig
 from repro.models import resolve_backend, get_backend
 from repro.models.attention import output_proj, project_kv, project_qkv
-from repro.models.common import dtype_of, rms_norm, softcap as _softcap
+from repro.models.common import (cast_compute, dtype_of, rms_norm,
+                                 softcap as _softcap)
 from repro.models.model import embed_inputs, head_logits
-from repro.models.moe import ffn_forward
+from repro.models.moe import ffn_forward, moe_held_forward
 
 from .pager import POS_SENTINEL, PagedKVCache
 
@@ -52,15 +53,23 @@ __all__ = ["paged_decode_step", "paged_prefill_chunk",
            "STAGES"]
 
 # the stages of a model step, as its ops' named scopes; ``plan`` is the
-# SPLS prediction and plan, in the SPLS chunk step only
+# SPLS prediction and plan, in the SPLS chunk step only; ``moe`` is the
+# held-expert layer (router, pair layout, the ``moe_gmm`` kernel and the
+# combine), inside ``ffn``
 STAGES = ("weights_cast", "embed", "qkv", "attention", "kv_pool", "ffn",
-          "lm_head", "plan")
+          "lm_head", "plan", "moe")
 
 
-def _cast_params(pparams, dtype):
-    return jax.tree.map(
-        lambda a: a.astype(dtype) if jnp.issubdtype(a.dtype, jnp.floating)
-        else a, pparams)
+def _with_moe(out: tuple, stats: Optional[jax.Array]) -> tuple:
+    """A step's outputs, with the held-expert layer's counters appended
+    where a layer holds experts: per-layer (..., 3)
+    :data:`~repro.models.moe.MOE_STATS` -> (3,), pairs and touched
+    experts summed over layers, the peak the largest."""
+    if stats is None:
+        return out
+    s = stats.reshape(-1, 3)
+    return out + (jnp.stack([s[:, 0].sum(), s[:, 1].sum(),
+                             s[:, 2].max()]),)
 
 
 def _write_token(kc: PagedKVCache, k_new: jax.Array, v_new: jax.Array,
@@ -121,29 +130,42 @@ def _write_chunk_kv(kc: PagedKVCache, k_new: jax.Array, v_new: jax.Array,
 
 def _residual_ffn(cfg: ArchConfig, blk, bp, x: jax.Array, h: jax.Array,
                   ffn_leader: jax.Array = None, ffn_comp=None,
-                  compute_backend: str = "dense") -> jax.Array:
+                  compute_backend: str = "dense",
+                  row_valid: Optional[jax.Array] = None):
     """Attention residual + optional post-norms + FFN residual, shared by
     the decode and chunked-prefill scan bodies.  ``ffn_leader`` (local row
     ids) enables simulation-mode sparse FFN: similar tokens copy their MFI
     leader's output.  ``ffn_comp`` (a :class:`~repro.core.sparse_exec.Compaction`)
     switches to *packed* sparse FFN through the compute-backend registry:
-    only critical rows are computed, leaders broadcast to followers."""
+    only critical rows are computed, leaders broadcast to followers.
+    Returns ``(x, moe_stats)``: the held-expert layer's counters
+    (``row_valid`` False rows route to nothing), None for other FFNs."""
     if cfg.use_post_norm:
         h = rms_norm(h, bp["post_ln1"], cfg.norm_eps)
     x = x + h
+    stats = None
     if blk.has_ffn:
         xn2 = rms_norm(x, bp["ln2"], cfg.norm_eps)
         if ffn_comp is not None and not blk.use_moe:
             from repro.sparse_compute import packed_mlp
             h2 = packed_mlp(cfg, bp["ffn"], xn2, ffn_comp, compute_backend)
         else:
-            h2 = ffn_forward(cfg, blk.use_moe, bp["ffn"], xn2)
+            if blk.use_moe and cfg.moe_held is not None:
+                h2, stats = moe_held_forward(cfg, bp["ffn"], xn2, row_valid)
+            else:
+                h2 = ffn_forward(cfg, blk.use_moe, bp["ffn"], xn2)
             if ffn_leader is not None:
                 h2 = jnp.take_along_axis(h2, ffn_leader[..., None], axis=-2)
         if cfg.use_post_norm:
             h2 = rms_norm(h2, bp["post_ln2"], cfg.norm_eps)
         x = x + h2
-    return x
+    return x, stats
+
+
+def _stack_stats(stats) -> Optional[jax.Array]:
+    """A period's blocks' MoE counters, (n_moe_blocks, 3), or None."""
+    stats = [s for s in stats if s is not None]
+    return jnp.stack(stats) if stats else None
 
 
 # ---------------------------------------------------------------------------
@@ -160,7 +182,10 @@ def paged_decode_step(cfg: ArchConfig, params, cache, pos_pages: jax.Array,
     cur_pos: (B,) original position of this token.  Every layer writes the
     token's K/V at slot ``kv_len`` (whose page the engine has already
     ensured) and attends over ``kv_len + 1`` slots.  Returns
-    ``(logits (B, 1, V), new_cache, new_pos_pages)``.
+    ``(logits (B, 1, V), new_cache, new_pos_pages)``, and where a layer
+    holds experts a fourth output: the held-expert layer's (3,) counters
+    over every layer (rows with ``kv_len == 0``, the inactive slots,
+    route to nothing).
     """
     ps = pos_pages.shape[1]
     N = pos_pages.shape[0]
@@ -176,11 +201,13 @@ def paged_decode_step(cfg: ArchConfig, params, cache, pos_pages: jax.Array,
     with jax.named_scope("embed"):
         x = embed_inputs(cfg, params, tokens)
 
+    row_valid = (kv_len > 0)[:, None]
+
     def scan_body(x, inp):
         pparams, pcache = inp
         with jax.named_scope("weights_cast"):
-            pparams = _cast_params(pparams, dtype)
-        new_caches = []
+            pparams = cast_compute(pparams, dtype)
+        new_caches, stats = [], []
         for blk, bp, kc in zip(cfg.period, pparams, pcache):
             with jax.named_scope("qkv"):
                 xn = rms_norm(x, bp["ln1"], cfg.norm_eps)
@@ -194,15 +221,18 @@ def paged_decode_step(cfg: ArchConfig, params, cache, pos_pages: jax.Array,
                 h = output_proj(cfg, bp["attn"], o[:, :, :, None],
                                 "structured")
             with jax.named_scope("ffn"):
-                x = _residual_ffn(cfg, blk, bp, x, h)
+                x, st = _residual_ffn(cfg, blk, bp, x, h,
+                                      row_valid=row_valid)
             new_caches.append(kc)
-        return x, tuple(new_caches)
+            stats.append(st)
+        return x, (tuple(new_caches), _stack_stats(stats))
 
     with jax.named_scope("kv_pool"):
-        x, new_cache = jax.lax.scan(scan_body, x, (params["periods"], cache))
+        x, (new_cache, moe) = jax.lax.scan(scan_body, x,
+                                           (params["periods"], cache))
     with jax.named_scope("lm_head"):
         logits = head_logits(cfg, params, x)
-    return logits, new_cache, pos_pages
+    return _with_moe((logits, new_cache, pos_pages), moe)
 
 
 # ---------------------------------------------------------------------------
@@ -225,7 +255,9 @@ def paged_prefill_chunk(cfg: ArchConfig, params, cache,
     ``(logits (1, 1, V) for the chunk's last valid position, new_cache,
     new_pos_pages)``; only the final chunk's logits are meaningful (they
     seed the first decoded token) -- the LM head is not run for the other
-    ``CS - 1`` rows.
+    ``CS - 1`` rows.  Where a layer holds experts a fourth output: the
+    held-expert layer's (3,) counters over every layer (padded rows route
+    to nothing).
     """
     assert cfg.causal, "chunked prefill needs causal attention"
     _, CS = tokens.shape
@@ -259,11 +291,13 @@ def paged_prefill_chunk(cfg: ArchConfig, params, cache,
         a = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(q.dtype)
         return jnp.einsum("bkgql,bkld->bkgqd", a, vg)
 
+    row_valid = (jnp.arange(CS) < valid)[None, :]
+
     def scan_body(x, inp):
         pparams, pcache = inp
         with jax.named_scope("weights_cast"):
-            pparams = _cast_params(pparams, dtype)
-        new_caches = []
+            pparams = cast_compute(pparams, dtype)
+        new_caches, stats = [], []
         for blk, bp, kc in zip(cfg.period, pparams, pcache):
             with jax.named_scope("qkv"):
                 xn = rms_norm(x, bp["ln1"], cfg.norm_eps)
@@ -274,16 +308,19 @@ def paged_prefill_chunk(cfg: ArchConfig, params, cache,
                 o = attend(blk, q, kc)
                 h = output_proj(cfg, bp["attn"], o, "structured")
             with jax.named_scope("ffn"):
-                x = _residual_ffn(cfg, blk, bp, x, h)
+                x, st = _residual_ffn(cfg, blk, bp, x, h,
+                                      row_valid=row_valid)
             new_caches.append(kc)
-        return x, tuple(new_caches)
+            stats.append(st)
+        return x, (tuple(new_caches), _stack_stats(stats))
 
     with jax.named_scope("kv_pool"):
-        x, new_cache = jax.lax.scan(scan_body, x, (params["periods"], cache))
+        x, (new_cache, moe) = jax.lax.scan(scan_body, x,
+                                           (params["periods"], cache))
     with jax.named_scope("lm_head"):
         x_last = jax.lax.dynamic_slice_in_dim(x, valid - 1, 1, axis=1)
         logits = head_logits(cfg, params, x_last)
-    return logits, new_cache, pos_pages
+    return _with_moe((logits, new_cache, pos_pages), moe)
 
 
 # ---------------------------------------------------------------------------
@@ -406,7 +443,7 @@ def paged_prefill_chunk_spls(cfg: ArchConfig, params, cache, pred_cache,
             kv_written_c = live_all_c = n_kv_c = None
         pparams, pcache, ppred, p_idx = inp
         with jax.named_scope("weights_cast"):
-            pparams = _cast_params(pparams, dtype)
+            pparams = cast_compute(pparams, dtype)
         new_caches, new_preds = [], []
         kv_any0 = None
         counts = jnp.zeros((3,), jnp.int32)
@@ -553,11 +590,12 @@ def paged_prefill_chunk_spls(cfg: ArchConfig, params, cache, pred_cache,
                     ffn_comp = compact_rows(pb.ffn_critical, Cf,
                                             leader=pb.ffn_leader - start,
                                             window=scfg.window)
-                x = _residual_ffn(cfg, blk, bp, x, h,
-                                  ffn_leader=(pb.ffn_leader - start
-                                              if scfg.ffn_sparsity else None),
-                                  ffn_comp=ffn_comp,
-                                  compute_backend=compute_backend)
+                x, _ = _residual_ffn(cfg, blk, bp, x, h,
+                                     ffn_leader=(pb.ffn_leader - start
+                                                 if scfg.ffn_sparsity
+                                                 else None),
+                                     ffn_comp=ffn_comp,
+                                     compute_backend=compute_backend)
             new_caches.append(kc)
             new_preds.append(pk)
         carry_out = ((x, kv_written_c, live_all_c, n_kv_c)

@@ -25,7 +25,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.sparse_exec import Compaction
-from repro.models.common import Activations, apply_rope, rms_norm, rope_freqs
+from repro.models.attention import norm_k, norm_q
+from repro.models.common import Activations, apply_rope, rope_freqs
 
 from .backend import get_compute_backend
 
@@ -52,7 +53,7 @@ def packed_project_q(cfg, p: dict, xn: jax.Array, positions: jax.Array,
     q = qg.reshape(1, C, KV, G, Dh).transpose(0, 2, 3, 1, 4)
     q = q.astype(xn.dtype)
     if cfg.qk_norm:
-        q = rms_norm(q, p["q_norm"], cfg.norm_eps)
+        q = norm_q(cfg, p, q)
     pos_p = jnp.take(positions, perm)[None, :]           # (1, C)
     sin, cos = rope_freqs(pos_p, Dh, cfg.rope_theta)
     return apply_rope(q, sin[:, None, None], cos[:, None, None])
@@ -82,7 +83,7 @@ def packed_project_kv(cfg, p: dict, xn: jax.Array, positions: jax.Array,
     k = kg.reshape(1, C, KV, Dh).transpose(0, 2, 1, 3).astype(xn.dtype)
     v = vg.reshape(1, C, KV, Dh).transpose(0, 2, 1, 3).astype(xn.dtype)
     if cfg.qk_norm:
-        k = rms_norm(k, p["k_norm"], cfg.norm_eps)
+        k = norm_k(cfg, p, k)
     pos_p = jnp.take(positions, perm)[None, :]           # (1, C)
     sin, cos = rope_freqs(pos_p, Dh, cfg.rope_theta)
     k = apply_rope(k, sin[:, None], cos[:, None])

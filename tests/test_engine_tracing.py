@@ -263,10 +263,21 @@ def test_steps_carry_every_stage_in_their_op_metadata(which):
     # float32 weights computed in bfloat16, as served: the cast has ops
     cfg = _cfg(name="tiny-tr-bf16", compute_dtype="bfloat16")
     hlo = _lowered(_engine(cfg), which).compile().as_text()
-    for st in set(STAGES) - {"plan"}:
+    # plan: the SPLS step's; moe: a model with held experts' (below)
+    for st in set(STAGES) - {"plan", "moe"}:
         assert f"/{st}/" in hlo, st
     # the layer scan's pool slices and write-back are kv_pool ops
     assert "/kv_pool/while/" in hlo
+
+
+@pytest.mark.parametrize("which", ["decode", "chunk"])
+def test_moe_steps_carry_the_moe_stage_inside_ffn(which):
+    cfg = _cfg(name="tiny-tr-moe", compute_dtype="bfloat16",
+               period=(BlockCfg(use_moe=True),), moe_experts=8, moe_topk=2,
+               moe_held=(0, 1, 2, 3))
+    hlo = _lowered(_engine(cfg), which).compile().as_text()
+    assert "/ffn/moe/" in hlo
+    assert "moe_gmm" in hlo
 
 
 def test_packed_pallas_chunk_step_names_the_gather_schedule():

@@ -6,6 +6,7 @@ back-compat, and the BENCH_serving.json report schema."""
 import json
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -194,6 +195,25 @@ class TestTraceRecorder:
         tr.end("a", 0.2, 1)
         assert tr.events == []
         tr.validate()
+
+    def test_deferred_counters_land_in_their_span_args(self, monkeypatch):
+        from repro.observability import trace as trace_mod
+        tr = TraceRecorder()
+        args = {"valid": 8}
+        tr.begin("prefill_chunk", 0.0, 1, args=args)
+        tr.defer(args, ("pairs", "peak"), jnp.asarray([12, 5], jnp.int32))
+        tr.end("prefill_chunk", 0.1, 1)
+        assert args == {"valid": 8}            # not read back yet
+        assert tr.events[0]["args"] == {"valid": 8, "pairs": 12, "peak": 5}
+        # a full batch is read back without waiting for a reader
+        monkeypatch.setattr(trace_mod, "MAX_PENDING", 2)
+        a2, a3 = {}, {}
+        tr.defer(a2, ("n",), jnp.asarray([1]))
+        tr.defer(a3, ("n",), jnp.asarray([2]))
+        assert (a2, a3) == ({"n": 1}, {"n": 2})
+        off = TraceRecorder(enabled=False)
+        off.defer(args, ("x",), jnp.asarray([1]))
+        assert "x" not in args and off.events == []
 
     def test_max_events_counts_drops(self):
         tr = TraceRecorder(max_events=3)

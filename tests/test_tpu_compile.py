@@ -7,6 +7,9 @@ refuses, so each main-path kernel is compiled here with
 
 * Qwen3-0.6B serving widths -- 16 query / 8 KV heads of dim 128, d_model
   1024, d_ff 3072, page size 16, prefill chunk 64;
+* OLMoE-1B-7B's chip share (olmoe-1b-7b-ep8) -- 16 query and 16 KV heads
+  of dim 128 (G = 1), 8 held experts of width 1024 at d_model 2048, 8
+  slots of 4096 tokens, prefill chunk 1024;
 * bert-base-esact's non-causal attention (12 heads of dim 64, L = 512).
 
 The topology is described inside a fixture, never at import: only one
@@ -24,6 +27,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.flash_decode import flash_decode
 from repro.kernels.gathered_matmul import gather_rows_kernel, gathered_matmul
+from repro.kernels.moe_gmm import moe_gmm, tile_rows
 from repro.kernels.paged_decode import paged_flash_decode
 
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
@@ -31,6 +35,10 @@ BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 H, KV, DH, D, FF = 16, 8, 128, 1024, 3072
 PAGE, CHUNK, SLOTS, MAX_LEN = 16, 64, 4, 1024
 N_PAGES = SLOTS * MAX_LEN // PAGE + 1
+# olmoe-1b-7b-ep8 (configs/olmoe_1b_7b_ep8.py) at its benchmark cell
+OL_KV, OL_D, OL_F, OL_E, OL_EH, OL_K = 16, 2048, 1024, 64, 8, 8
+OL_SLOTS, OL_MAX, OL_CHUNK = 8, 4096, 1024
+OL_PAGES = OL_SLOTS * OL_MAX // PAGE + 1
 # bert-base-esact (configs/bert_base_esact.py) at the paper's L = 512
 BERT_H, BERT_DH, BERT_L = 12, 64, 512
 
@@ -65,6 +73,16 @@ def _flash(causal):
 def _flash_spls(causal):
     return lambda q, k, v, qp, keep: flash_attention(
         q, k, v, causal=causal, q_pos=qp, kv_keep=keep, interpret=False)
+
+
+def _moe_gmm_case(tokens):
+    bm = tile_rows(tokens, OL_K, OL_E)
+    nt = -(-tokens * OL_K // bm) + OL_EH
+    fn = lambda x, wg, wu, wd, tg, n: moe_gmm(x, wg, wu, wd, tg, n, bm=bm,
+                                              interpret=False)
+    return fn, [((nt * bm, OL_D), BF16), ((OL_EH, OL_D, OL_F), BF16),
+                ((OL_EH, OL_D, OL_F), BF16), ((OL_EH, OL_F, OL_D), BF16),
+                ((nt,), I32), ((1,), I32)]
 
 
 # name -> (fn, [(shape, dtype), ...])
@@ -121,6 +139,19 @@ CASES = {
                                            interpret=False), [
             ((CHUNK, D), BF16), ((D, FF), BF16), ((48,), I32),
             ((CHUNK,), I32)]),
+    # MHA: 16 KV heads, one query head each, 8 slots of 256 pages
+    "paged_flash_decode_olmoe": (
+        lambda q, kp, vp, pp, tb, kl, pos: paged_flash_decode(
+            q, kp, vp, pp, tb, kl, pos, interpret=False), [
+            ((OL_SLOTS, OL_KV, 1, DH), BF16),
+            ((OL_KV, OL_PAGES, PAGE, DH), BF16),
+            ((OL_KV, OL_PAGES, PAGE, DH), BF16),
+            ((OL_PAGES, PAGE), I32), ((OL_SLOTS, OL_MAX // PAGE), I32),
+            ((OL_SLOTS,), I32), ((OL_SLOTS,), I32)]),
+    # the held experts' grouped FFN of a 1024-token chunk and of a decode
+    # step over 8 slots, the grid sized for every pair held
+    "moe_gmm_chunk": _moe_gmm_case(OL_CHUNK),
+    "moe_gmm_decode": _moe_gmm_case(OL_SLOTS),
     "gather_rows_kernel": (
         lambda src, idx: gather_rows_kernel(src, idx, interpret=False), [
             ((48, D), BF16), ((CHUNK,), I32)]),
