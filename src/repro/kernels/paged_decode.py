@@ -7,9 +7,13 @@ attends over its pages, gathered through a per-sequence block table.
 
 Layout (see ``src/repro/serving/README.md`` for the lifecycle):
 
-  k_pages / v_pages: (KV, n_pages, page_size, Dh)  -- the shared pool; page
-      0 is the reserved *null page* (block-table filler / write sink for
-      inactive batch rows; reads of it are always masked out).
+  k_pages / v_pages: (L, KV, n_pages, page_size, Dh)  -- the model's
+      stacked pool, one pool per layer; page 0 is the reserved *null page*
+      (block-table filler; reads of it are always masked out).  A single
+      layer's (KV, n_pages, page_size, Dh) pool is a stack of one.
+  layer:             () int32                      -- the layer to read:
+      the serving step's layer scan carries the stacked pool and passes its
+      layer index, so no layer is ever sliced out of the pool.
   pos_pages:         (n_pages, page_size) int32    -- original token
       position of every written slot.  Once SPLS page pruning has compacted
       a sequence, slot index != token position, so sliding-window masks must
@@ -21,12 +25,13 @@ Layout (see ``src/repro/serving/README.md`` for the lifecycle):
       the current query token (inclusive upper bound of the window).
 
 Grid: (B,), one step per sequence with all of its KV heads.  The pools
-stay in HBM (``memory_space=pl.ANY``); the block table, lengths and
-positions ride in as scalar-prefetch operands.  Inside a step a loop walks
-the sequence in blocks of ``pages_per_block(P)`` pages and runs
-``ceil(kv_len / (pages_per_block * page_size))`` times, so the work
-follows the live length, not ``P``.  Each block's pages are copied with
-``pltpu.make_async_copy`` -- one copy per page, all KV heads at once, the
+stay in HBM (``memory_space=pl.ANY``); the block table, lengths,
+positions and the layer index ride in as scalar-prefetch operands.
+Inside a step a loop walks the sequence in blocks of
+``pages_per_block(P)`` pages and runs ``ceil(kv_len / (pages_per_block *
+page_size))`` times, so the work follows the live length, not ``P``.
+Each block's pages are copied with ``pltpu.make_async_copy`` -- one copy
+per page, all KV heads at once, from ``[layer, :, page]`` with the
 physical page read from the block table -- into one half of a
 double-buffered VMEM block, and the next block's copies start before the
 current block is computed.  Pages past ``kv_len`` are never copied; the
@@ -75,7 +80,7 @@ def pages_visited(n_valid, page_size: int) -> int:
     return int((-(-n // page_size)).sum())
 
 
-def _kernel(bt_ref, kl_ref, cp_ref, q_ref, k_hbm, v_hbm, *rest,
+def _kernel(bt_ref, kl_ref, cp_ref, ly_ref, q_ref, k_hbm, v_hbm, *rest,
             scale, softcap, window, ps, ppb, kv, np_):
     if window is None:
         pp_ref = None
@@ -83,6 +88,7 @@ def _kernel(bt_ref, kl_ref, cp_ref, q_ref, k_hbm, v_hbm, *rest,
     else:
         pp_ref, o_ref, k_buf, v_buf, sem, m_scr, l_scr, acc_scr = rest
     b = pl.program_id(0)
+    layer = ly_ref[0]
     bk = ppb * ps
     n_valid = jnp.minimum(kl_ref[b], np_ * ps)
     n_blk = (n_valid + bk - 1) // bk
@@ -90,9 +96,9 @@ def _kernel(bt_ref, kl_ref, cp_ref, q_ref, k_hbm, v_hbm, *rest,
     def copies(blk, slot, p):
         page = bt_ref[b, blk * ppb + p]
         dst = pl.ds(p * ps, ps)
-        return (pltpu.make_async_copy(k_hbm.at[:, page],
+        return (pltpu.make_async_copy(k_hbm.at[layer, :, page],
                                       k_buf.at[slot, :, dst], sem.at[0, slot]),
-                pltpu.make_async_copy(v_hbm.at[:, page],
+                pltpu.make_async_copy(v_hbm.at[layer, :, page],
                                       v_buf.at[slot, :, dst], sem.at[1, slot]))
 
     def each_live_page(blk, slot, fn):
@@ -167,26 +173,34 @@ def _kernel(bt_ref, kl_ref, cp_ref, q_ref, k_hbm, v_hbm, *rest,
 def paged_flash_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                        pos_pages: jax.Array, tables: jax.Array,
                        kv_len: jax.Array, pos: jax.Array,
+                       layer: Optional[jax.Array] = None,
                        softcap: Optional[float] = None,
                        window: Optional[int] = None,
                        interpret: Optional[bool] = None) -> jax.Array:
-    """q: (B, KV, G, Dh) one token per sequence; k/v_pages: (KV, N, ps, Dh);
-    pos_pages: (N, ps); tables: (B, P); kv_len/pos: (B,).
-    Returns (B, KV, G, Dh).  ``pos_pages`` is read only with a window:
-    gathered through the tables into one ``(n_blocks, block)`` id row per
-    sequence, so each block's ids are one sublane of it.  ``interpret=None``
-    interprets on CPU only."""
+    """q: (B, KV, G, Dh) one token per sequence; k/v_pages: the stacked
+    (L, KV, N, ps, Dh) pool read at ``layer`` (a traced () int), or one
+    layer's (KV, N, ps, Dh) pool with ``layer=None``; pos_pages: (N, ps);
+    tables: (B, P); kv_len/pos: (B,).  Returns (B, KV, G, Dh).
+    ``pos_pages`` is read only with a window: gathered through the tables
+    into one ``(n_blocks, block)`` id row per sequence, so each block's ids
+    are one sublane of it.  ``interpret=None`` interprets on CPU only."""
+    if (layer is None) != (k_pages.ndim == 4):
+        raise ValueError("a stacked (L, KV, N, ps, Dh) pool takes a layer "
+                         "index; one layer's (KV, N, ps, Dh) pool takes none")
+    if layer is None:  # one layer: a stack of one
+        k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
     B, KV, G, Dh = q.shape
-    _, N, ps, _ = k_pages.shape
+    ps = k_pages.shape[3]
     P = tables.shape[1]
     ppb = pages_per_block(P)
     bk = ppb * ps
     tables = tables.astype(jnp.int32)
     kv_len = kv_len.astype(jnp.int32)
     pos = pos.astype(jnp.int32)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
 
     in_specs = [
-        pl.BlockSpec((1, KV, G, Dh), lambda b, bt, kl, cp: (b, 0, 0, 0)),
+        pl.BlockSpec((1, KV, G, Dh), lambda b, bt, kl, cp, ly: (b, 0, 0, 0)),
         pl.BlockSpec(memory_space=pl.ANY),
         pl.BlockSpec(memory_space=pl.ANY),
     ]
@@ -197,13 +211,13 @@ def paged_flash_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         ids = jnp.pad(ids, ((0, 0), (0, n_blk * bk - P * ps)))
         operands.append(ids.reshape(B, n_blk, bk))
         in_specs.append(pl.BlockSpec((1, n_blk, bk),
-                                     lambda b, bt, kl, cp: (b, 0, 0)))
+                                     lambda b, bt, kl, cp, ly: (b, 0, 0)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(B,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, KV, G, Dh),
-                               lambda b, bt, kl, cp: (b, 0, 0, 0)),
+                               lambda b, bt, kl, cp, ly: (b, 0, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((2, KV, bk, Dh), k_pages.dtype),
             pltpu.VMEM((2, KV, bk, Dh), v_pages.dtype),
@@ -222,4 +236,4 @@ def paged_flash_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
             dimension_semantics=("parallel",)),
         interpret=resolve_interpret(interpret),
         name="paged_flash_decode",
-    )(tables, kv_len, pos, *operands)
+    )(tables, kv_len, pos, layer, *operands)

@@ -50,12 +50,13 @@ Paged decode backends (the serving engine's block-pool KV cache,
 ``repro.serving``) share::
 
     fn(cfg, q, k_pages, v_pages, *, pos_pages, tables, kv_len, pos,
-       window) -> o
+       window, layer=None) -> o
 
-with ``q: (B, KV, G, Dh)``, ``k/v_pages: (KV, N, ps, Dh)`` page pools,
-``pos_pages: (N, ps)`` original-position ids, ``tables: (B, P)`` block
-tables, ``kv_len: (B,)`` written slots, ``pos: (B,)`` current original
-position.
+with ``q: (B, KV, G, Dh)``, ``k/v_pages`` the stacked ``(L, KV, N, ps,
+Dh)`` page pools read at layer ``layer`` (a traced index), or one
+layer's ``(KV, N, ps, Dh)`` pools with ``layer=None``, ``pos_pages: (N,
+ps)`` original-position ids, ``tables: (B, P)`` block tables, ``kv_len:
+(B,)`` written slots, ``pos: (B,)`` current original position.
 
   * ``xla_paged_decode``    -- XLA gather of the block table into a
     contiguous view, then dense masked scores.  The fallback / oracle.
@@ -419,17 +420,22 @@ def pallas_flash_decode(cfg, q, k, v, *, pos, window=None) -> jax.Array:
 @register_backend("xla_paged_decode", decode=True, paged=True,
                   doc="XLA block-table gather + dense masked decode")
 def xla_paged_decode(cfg, q, k_pages, v_pages, *, pos_pages, tables, kv_len,
-                     pos, window=None) -> jax.Array:
-    """q: (B, KV, G, Dh); k/v_pages: (KV, N, ps, Dh); pos_pages: (N, ps);
-    tables: (B, P); kv_len/pos: (B,).  Gathers the sequence's pages into a
-    contiguous (B, KV, P*ps, Dh) view, then runs the dense decode math with
-    a written-slot mask (slot < kv_len) and an original-position window."""
+                     pos, window=None, layer=None) -> jax.Array:
+    """q: (B, KV, G, Dh); k/v_pages: (L, KV, N, ps, Dh) read at ``layer``,
+    or (KV, N, ps, Dh) with ``layer=None``; pos_pages: (N, ps); tables:
+    (B, P); kv_len/pos: (B,).  Gathers the sequence's pages of the layer
+    into a contiguous (B, KV, P*ps, Dh) view (one gather from the stacked
+    pool), then runs the dense decode math with a written-slot mask (slot <
+    kv_len) and an original-position window."""
+    if layer is None:  # one layer: a stack of one
+        k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
     B, KV, G, Dh = q.shape
-    ps = k_pages.shape[2]
+    ps = k_pages.shape[3]
     P = tables.shape[1]
     S = P * ps
-    kg = jnp.moveaxis(k_pages[:, tables], 1, 0).reshape(B, KV, S, Dh)
-    vg = jnp.moveaxis(v_pages[:, tables], 1, 0).reshape(B, KV, S, Dh)
+    # [layer, :, tables]: the indexed dims lead -> (B, P, KV, ps, Dh)
+    kg = jnp.moveaxis(k_pages[layer, :, tables], 2, 1).reshape(B, KV, S, Dh)
+    vg = jnp.moveaxis(v_pages[layer, :, tables], 2, 1).reshape(B, KV, S, Dh)
     pg = pos_pages[tables].reshape(B, S)
     s = jnp.einsum("bkgd,bkld->bkgl", q, kg) * (Dh ** -0.5)
     s = _softcap(s, cfg.attn_softcap)
@@ -445,11 +451,11 @@ def xla_paged_decode(cfg, q, k_pages, v_pages, *, pos_pages, tables, kv_len,
 @register_backend("pallas_paged_decode", decode=True, paged=True,
                   doc="Pallas paged decode; block-table gather in the DMA")
 def pallas_paged_decode(cfg, q, k_pages, v_pages, *, pos_pages, tables,
-                        kv_len, pos, window=None) -> jax.Array:
+                        kv_len, pos, window=None, layer=None) -> jax.Array:
     """Same contract as :func:`xla_paged_decode`, executed by
     ``repro.kernels.paged_decode.paged_flash_decode``."""
     from repro.kernels.paged_decode import paged_flash_decode
 
     return paged_flash_decode(q, k_pages, v_pages, pos_pages, tables,
-                              kv_len, pos, softcap=cfg.attn_softcap,
-                              window=window)
+                              kv_len, pos, layer=layer,
+                              softcap=cfg.attn_softcap, window=window)

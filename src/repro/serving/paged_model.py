@@ -7,7 +7,7 @@ a shared block table, and original-position ids instead of a dense
 
 * :func:`paged_decode_step` -- one batched decode tick.  Each layer writes
   the new token's K/V into the slot the block table names (inactive rows
-  write to the null page) and attends through the paged decode backends
+  write nothing) and attends through the paged decode backends
   (``xla_paged_decode`` / ``pallas_paged_decode``).
 * :func:`paged_prefill_chunk` -- chunked prefill: one prompt chunk (padded
   to a static chunk size) is projected at its original positions, written
@@ -21,14 +21,30 @@ a shared block table, and original-position ids instead of a dense
 All functions are functional: caches/pos_pages go in, updated ones come
 out; the engine owns jit boundaries and the host-side pool bookkeeping.
 
+The decode and dense chunk steps keep the stacked pool (``(n_periods, KV,
+N, ps, Dh)`` per period block) in the layer scan's *carry*; the scan's
+inputs are the period parameters and the period index ``l``.  A layer
+writes its new rows straight into the carried pool a page at a time: the
+pages ``[l, :, page]`` its rows fall in are read, the rows laid over
+their slots and the pages written back, each page one (KV, ps, Dh)
+window -- one page a row for a decode token, the pages a chunk spans for
+a chunk.  It reads layer ``l`` from the pool too: the decode backends
+take ``layer=l`` and the chunk step gathers ``pool[l, :, table]``.  No op
+reads or writes a whole layer's pool, and the donated pool is updated in
+place.  (Scanned as ``xs``/``ys``, XLA would slice each layer's pool
+out, write it back whole into a new stack and copy around the loop.)
+The SPLS chunk step still scans the pool as ``xs``.
+
 Every op of the steps runs under one of the :data:`STAGES` names
 (``jax.named_scope``), which the compiled program keeps as op metadata and
 a device trace shows beside each op; an op's stage is the innermost stage
 name in its name stack.  ``kv_pool`` covers every op that moves page-pool
-data: the per-layer pool slices the layer scan feeds each layer and the
-write-back of each layer's pages (so the scan itself runs under
-``kv_pool``, its body's compute under the compute stages), the token or
-chunk K/V writes, the ``pos_pages`` update and the page gathers.
+data: the token or chunk K/V writes, the ``pos_pages`` update, the page
+gathers and, in the SPLS step, the per-layer pool slices and write-backs
+of its scan (so that scan runs under ``kv_pool``, its body's compute
+under the compute stages).  The decode and dense chunk scans move no pool
+data and run under no stage: the per-layer parameter slices they feed
+each layer are ``unscoped``.
 """
 
 from __future__ import annotations
@@ -72,17 +88,37 @@ def _with_moe(out: tuple, stats: Optional[jax.Array]) -> tuple:
                              s[:, 2].max()]),)
 
 
-def _write_token(kc: PagedKVCache, k_new: jax.Array, v_new: jax.Array,
-                 flat: jax.Array) -> PagedKVCache:
-    """Scatter one token's K/V (B, KV, 1, Dh) into flat page slots (B,)."""
-    KV, N, ps, Dh = kc.k_pages.shape
+def _lay_pages(kc: PagedKVCache, layer, page: jax.Array, mask: jax.Array,
+               k_rows: jax.Array, v_rows: jax.Array) -> PagedKVCache:
+    """Lay K/V rows over whole pages ``[layer, :, page]`` (n,) of the
+    stacked (L, KV, N, ps, Dh) pool, in place: each page is read, its
+    slots where ``mask`` holds take the rows, and it is written back.
+    ``mask`` and the rows broadcast against the pages (n, KV, ps, Dh).
+    Nothing of the pool but those pages is read or written, and each
+    page moves as one (KV, ps, Dh) window.  The null page (id 0) is
+    never written: it becomes a distinct out-of-bounds id, so the
+    indices stay unique and its write is dropped."""
+    N = kc.k_pages.shape[2]
+    page = jnp.where(page == 0, N + jnp.arange(page.shape[0]), page)
+    at = (layer, slice(None), page)
+
+    def lay(pool, rows):
+        old = pool.at[at].get(mode="clip", unique_indices=True)
+        return pool.at[at].set(jnp.where(mask, rows, old), mode="drop",
+                               unique_indices=True)
+
     with jax.named_scope("kv_pool"):
-        kf = kc.k_pages.reshape(KV, N * ps, Dh)
-        vf = kc.v_pages.reshape(KV, N * ps, Dh)
-        kf = kf.at[:, flat].set(jnp.moveaxis(k_new[:, :, 0], 0, 1))
-        vf = vf.at[:, flat].set(jnp.moveaxis(v_new[:, :, 0], 0, 1))
-        return PagedKVCache(kf.reshape(KV, N, ps, Dh),
-                            vf.reshape(KV, N, ps, Dh))
+        return PagedKVCache(lay(kc.k_pages, k_rows), lay(kc.v_pages, v_rows))
+
+
+def _write_token(kc: PagedKVCache, layer, k_new: jax.Array,
+                 v_new: jax.Array, flat: jax.Array) -> PagedKVCache:
+    """Write one token's K/V (B, KV, 1, Dh) per row at flat page slot
+    ``flat`` (B,) of layer ``layer`` of the stacked pool.  Rows whose
+    slot lies in the null page (the inactive rows) write nothing."""
+    ps = kc.k_pages.shape[3]
+    at_slot = (jnp.arange(ps) == (flat % ps)[:, None])[:, None, :, None]
+    return _lay_pages(kc, layer, flat // ps, at_slot, k_new, v_new)
 
 
 def _decode_flat_slots(tables: jax.Array, kv_len: jax.Array,
@@ -117,15 +153,45 @@ def _chunk_slots(table: jax.Array, pos_pages: jax.Array, start: jax.Array,
     return sl, flat, pos_pages
 
 
-def _write_chunk_kv(kc: PagedKVCache, k_new: jax.Array, v_new: jax.Array,
-                    flat: jax.Array) -> PagedKVCache:
-    """Scatter a chunk's K/V rows (1, KV, CS, Dh) into flat page slots."""
+def _scatter_rows(kc: PagedKVCache, k_new: jax.Array, v_new: jax.Array,
+                  flat: jax.Array) -> PagedKVCache:
+    """Scatter a chunk's K/V rows (1, KV, CS, Dh) into flat page slots of
+    one layer's (KV, N, ps, Dh) pool."""
     KV, N, ps, Dh = kc.k_pages.shape
     with jax.named_scope("kv_pool"):
         kf = kc.k_pages.reshape(KV, N * ps, Dh).at[:, flat].set(k_new[0])
         vf = kc.v_pages.reshape(KV, N * ps, Dh).at[:, flat].set(v_new[0])
         return PagedKVCache(kf.reshape(KV, N, ps, Dh),
                             vf.reshape(KV, N, ps, Dh))
+
+
+def _write_chunk_kv(kc: PagedKVCache, layer, k_new: jax.Array,
+                    v_new: jax.Array, table: jax.Array, start: jax.Array,
+                    valid: jax.Array) -> PagedKVCache:
+    """Write a chunk's K/V rows (1, KV, CS, Dh), the sequence's slots
+    ``start .. start + valid - 1``, into layer ``layer`` of the stacked
+    pool a whole page at a time: each page the chunk touches is read, the
+    chunk's rows are laid over its slots, and it is written back.  The
+    static page count covers an unaligned start; a page with no slot of
+    the chunk is not written."""
+    _, KV, N, ps, Dh = kc.k_pages.shape
+    CS = k_new.shape[2]
+    P = table.shape[0]
+    n_pg = (CS + 2 * ps - 2) // ps            # most pages CS slots can span
+    with jax.named_scope("kv_pool"):
+        lp = start // ps + jnp.arange(n_pg)            # logical pages
+        row = lp[:, None] * ps + jnp.arange(ps) - start  # chunk row a slot
+        live = (row >= 0) & (row < valid)               # (n_pg, ps)
+        page = jnp.where(live.any(-1), table[jnp.minimum(lp, P - 1)], 0)
+
+        def rows(new):  # slot (j, s) <- chunk row j*ps + s - start % ps
+            x = jnp.pad(new[0], ((0, 0), (ps, n_pg * ps - CS), (0, 0)))
+            x = jax.lax.dynamic_slice_in_dim(x, ps - start % ps, n_pg * ps,
+                                             axis=1)
+            return jnp.swapaxes(x.reshape(KV, n_pg, ps, Dh), 0, 1)
+
+        k_rows, v_rows = rows(k_new), rows(v_new)
+    return _lay_pages(kc, layer, page, live[:, None, :, None], k_rows, v_rows)
 
 
 def _residual_ffn(cfg: ArchConfig, blk, bp, x: jax.Array, h: jax.Array,
@@ -203,8 +269,9 @@ def paged_decode_step(cfg: ArchConfig, params, cache, pos_pages: jax.Array,
 
     row_valid = (kv_len > 0)[:, None]
 
-    def scan_body(x, inp):
-        pparams, pcache = inp
+    def scan_body(carry, inp):
+        x, pcache = carry
+        pparams, l = inp
         with jax.named_scope("weights_cast"):
             pparams = cast_compute(pparams, dtype)
         new_caches, stats = [], []
@@ -213,9 +280,9 @@ def paged_decode_step(cfg: ArchConfig, params, cache, pos_pages: jax.Array,
                 xn = rms_norm(x, bp["ln1"], cfg.norm_eps)
                 q, k_new, v_new = project_qkv(cfg, bp["attn"], xn,
                                               cur_pos[:, None], "structured")
-            kc = _write_token(kc, k_new, v_new, flat)
+            kc = _write_token(kc, l, k_new, v_new, flat)
             with jax.named_scope("attention"):
-                o = fn(cfg, q[:, :, :, 0], kc.k_pages, kc.v_pages,
+                o = fn(cfg, q[:, :, :, 0], kc.k_pages, kc.v_pages, layer=l,
                        pos_pages=pos_pages, tables=tables, kv_len=n_valid,
                        pos=cur_pos, window=blk.window)
                 h = output_proj(cfg, bp["attn"], o[:, :, :, None],
@@ -225,11 +292,13 @@ def paged_decode_step(cfg: ArchConfig, params, cache, pos_pages: jax.Array,
                                       row_valid=row_valid)
             new_caches.append(kc)
             stats.append(st)
-        return x, (tuple(new_caches), _stack_stats(stats))
+        return (x, tuple(new_caches)), _stack_stats(stats)
 
-    with jax.named_scope("kv_pool"):
-        x, (new_cache, moe) = jax.lax.scan(scan_body, x,
-                                           (params["periods"], cache))
+    # no stage scope: the scan moves no pool data, only each layer's
+    # parameters, and its body's ops carry their own stages
+    (x, new_cache), moe = jax.lax.scan(
+        scan_body, (x, cache),
+        (params["periods"], jnp.arange(cfg.n_periods, dtype=jnp.int32)))
     with jax.named_scope("lm_head"):
         logits = head_logits(cfg, params, x)
     return _with_moe((logits, new_cache, pos_pages), moe)
@@ -265,7 +334,7 @@ def paged_prefill_chunk(cfg: ArchConfig, params, cache,
     S = table.shape[0] * ps
     dtype = dtype_of(cfg.compute_dtype)
 
-    sl, flat, pos_pages = _chunk_slots(table, pos_pages, start, valid, CS)
+    sl, _, pos_pages = _chunk_slots(table, pos_pages, start, valid, CS)
     positions = sl[None, :]                            # original ids
     n_valid = start + valid
     with jax.named_scope("kv_pool"):
@@ -275,11 +344,14 @@ def paged_prefill_chunk(cfg: ArchConfig, params, cache,
     with jax.named_scope("embed"):
         x = embed_inputs(cfg, params, tokens)
 
-    def attend(blk, q, kc):
-        KV = kc.k_pages.shape[0]
+    def attend(blk, q, kc, l):
+        KV = kc.k_pages.shape[1]
         with jax.named_scope("kv_pool"):
-            kg = kc.k_pages[:, table][None].reshape(1, KV, S, -1)
-            vg = kc.v_pages[:, table][None].reshape(1, KV, S, -1)
+            # one gather of the table's pages of layer l: (P, KV, ps, Dh)
+            kg = jnp.moveaxis(kc.k_pages[l, :, table], 0, 1)
+            vg = jnp.moveaxis(kc.v_pages[l, :, table], 0, 1)
+            kg = kg.reshape(1, KV, S, -1)
+            vg = vg.reshape(1, KV, S, -1)
         Dh = q.shape[-1]
         s = jnp.einsum("bkgqd,bkld->bkgql", q, kg) * (Dh ** -0.5)
         s = _softcap(s, cfg.attn_softcap)
@@ -293,8 +365,9 @@ def paged_prefill_chunk(cfg: ArchConfig, params, cache,
 
     row_valid = (jnp.arange(CS) < valid)[None, :]
 
-    def scan_body(x, inp):
-        pparams, pcache = inp
+    def scan_body(carry, inp):
+        x, pcache = carry
+        pparams, l = inp
         with jax.named_scope("weights_cast"):
             pparams = cast_compute(pparams, dtype)
         new_caches, stats = [], []
@@ -303,20 +376,22 @@ def paged_prefill_chunk(cfg: ArchConfig, params, cache,
                 xn = rms_norm(x, bp["ln1"], cfg.norm_eps)
                 q, k_new, v_new = project_qkv(cfg, bp["attn"], xn,
                                               positions, "structured")
-            kc = _write_chunk_kv(kc, k_new, v_new, flat)
+            kc = _write_chunk_kv(kc, l, k_new, v_new, table, start, valid)
             with jax.named_scope("attention"):
-                o = attend(blk, q, kc)
+                o = attend(blk, q, kc, l)
                 h = output_proj(cfg, bp["attn"], o, "structured")
             with jax.named_scope("ffn"):
                 x, st = _residual_ffn(cfg, blk, bp, x, h,
                                       row_valid=row_valid)
             new_caches.append(kc)
             stats.append(st)
-        return x, (tuple(new_caches), _stack_stats(stats))
+        return (x, tuple(new_caches)), _stack_stats(stats)
 
-    with jax.named_scope("kv_pool"):
-        x, (new_cache, moe) = jax.lax.scan(scan_body, x,
-                                           (params["periods"], cache))
+    # no stage scope: the scan moves no pool data, only each layer's
+    # parameters, and its body's ops carry their own stages
+    (x, new_cache), moe = jax.lax.scan(
+        scan_body, (x, cache),
+        (params["periods"], jnp.arange(cfg.n_periods, dtype=jnp.int32)))
     with jax.named_scope("lm_head"):
         x_last = jax.lax.dynamic_slice_in_dim(x, valid - 1, 1, axis=1)
         logits = head_logits(cfg, params, x_last)
@@ -525,15 +600,15 @@ def paged_prefill_chunk_spls(cfg: ArchConfig, params, cache, pred_cache,
                             perm=kv_perm, compute_backend=compute_backend)
                         flat_kv = jnp.where(jnp.take(kv_written_c, kv_perm),
                                             jnp.take(flat, kv_perm), 0)
-                        kc = _write_chunk_kv(kc, k_new, v_new, flat_kv)
+                        kc = _scatter_rows(kc, k_new, v_new, flat_kv)
                     else:
                         k_new, v_new = project_kv(cfg, bp["attn"], xn,
                                                   positions, "structured")
-                        kc = _write_chunk_kv(kc, k_new, v_new, flat)
+                        kc = _scatter_rows(kc, k_new, v_new, flat)
                 else:
                     q, k_new, v_new = project_qkv(cfg, bp["attn"], xn,
                                                   positions, "structured")
-                    kc = _write_chunk_kv(kc, k_new, v_new, flat)
+                    kc = _scatter_rows(kc, k_new, v_new, flat)
             with jax.named_scope("kv_pool"):
                 kg = kc.k_pages[:, table][None].reshape(1, KV, S, Dh)
                 vg = kc.v_pages[:, table][None].reshape(1, KV, S, Dh)

@@ -303,6 +303,54 @@ class TestLivePageLoop:
                                    atol=2e-5)
 
 
+class TestStackedPool:
+    """Layer ``l`` of a stacked (L, KV, N, ps, Dh) pool, read through a
+    traced layer index, is exactly the one-layer call on ``pool[l]``."""
+
+    @pytest.mark.parametrize("G", [1, 2])
+    @pytest.mark.parametrize("window", [None, 6])
+    @pytest.mark.parametrize("backend", ["pallas_paged_decode",
+                                         "xla_paged_decode"])
+    def test_layer_of_stack_equals_its_slice(self, backend, window, G):
+        L, B, KV, Dh, N, ps = 3, 3, 2, 16, 12, 8
+        cfg = ArchConfig(period=(BlockCfg(),))
+        ks = jax.random.split(jax.random.PRNGKey(21), 3)
+        q = jax.random.normal(ks[0], (B, KV, G, Dh))
+        kp = jax.random.normal(ks[1], (L, KV, N, ps, Dh))
+        vp = jax.random.normal(ks[2], (L, KV, N, ps, Dh))
+        tables = jnp.asarray([[1, 2, 3, 0], [4, 5, 0, 0], [6, 7, 8, 9]],
+                             jnp.int32)
+        kv_len = jnp.asarray([20, 9, 32], jnp.int32)
+        kw = dict(pos_pages=_contiguous_layout(np.asarray(tables), kv_len,
+                                               N, ps),
+                  tables=tables, kv_len=kv_len, pos=kv_len - 1,
+                  window=window)
+        if backend == "pallas_paged_decode":
+            def fn(cfg, q, k, v, layer=None, window=None, **kw):
+                return paged_flash_decode(
+                    q, k, v, kw["pos_pages"], kw["tables"], kw["kv_len"],
+                    kw["pos"], layer=layer, window=window, interpret=True)
+        else:
+            fn = get_backend(backend)
+        stacked = jax.jit(lambda l: fn(cfg, q, kp, vp, layer=l, **kw))
+        for l in range(L):
+            got = stacked(jnp.int32(l))
+            want = fn(cfg, q, kp[l], vp[l], **kw)
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    def test_layer_index_goes_with_a_stacked_pool(self):
+        q, kp, vp = _pool()
+        tables = jnp.ones((3, 4), jnp.int32)
+        n = jnp.ones((3,), jnp.int32)
+        pos = jnp.zeros((12, 8), jnp.int32)
+        with pytest.raises(ValueError):
+            paged_flash_decode(q, kp, vp, pos, tables, n, n,
+                               layer=jnp.int32(0), interpret=True)
+        with pytest.raises(ValueError):
+            paged_flash_decode(q, kp[None], vp[None], pos, tables, n, n,
+                               interpret=True)
+
+
 class TestPagesVisited:
     def test_counts_pages_holding_attended_slots(self):
         assert pages_visited(1, 16) == 1

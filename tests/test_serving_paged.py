@@ -29,7 +29,7 @@ def _cfg(**kw):
 
 
 def _params(cfg):
-    key = (cfg.name, cfg.period, cfg.spls.enabled)
+    key = (cfg.name, cfg.n_layers, cfg.period, cfg.spls.enabled)
     if key not in _PARAMS_CACHE:
         _PARAMS_CACHE[key] = init_params(cfg, jax.random.PRNGKey(0))
     return _PARAMS_CACHE[key]
@@ -138,6 +138,179 @@ class TestPagedDenseParity:
         paged = _drain_outputs(eng, _reqs(cfg, [30, 7, 25]))
         assert eng.stats["prefill_chunks"] >= 4  # 30 -> 4 chunks of 8
         assert dense == paged
+
+
+# ---------------------------------------------------------------------------
+# the model steps on the stacked pool
+# ---------------------------------------------------------------------------
+
+_PS, _N, _CHUNK, _VALID = 4, 9, 8, 7
+_TABLE = np.asarray([1, 2, 3, 5], np.int32)      # the sequence's pages
+
+
+def _noisy_pool(cfg):
+    """A stacked pool of random values, so that a slot left alone and a
+    slot written can be told apart bit for bit."""
+    from repro.serving.pager import init_paged_cache, init_pos_pages
+    cache = init_paged_cache(cfg, _N, _PS)
+    keys = jax.random.split(jax.random.PRNGKey(5), 2 * len(cache))
+    cache = tuple(type(kc)(jax.random.normal(keys[2 * i], kc.k_pages.shape),
+                           jax.random.normal(keys[2 * i + 1],
+                                             kc.v_pages.shape))
+                  for i, kc in enumerate(cache))
+    return cache, init_pos_pages(_N, _PS)
+
+
+def _slot(pos):
+    """(page, slot) of a sequence position under ``_TABLE``."""
+    return int(_TABLE[pos // _PS]), pos % _PS
+
+
+def _assert_only_written(before, after, written):
+    """Every (page, slot) not in ``written`` is bit-identical in every
+    layer and KV head; returns the written slots' K/V after the step."""
+    for kb, ka in zip(before, after):
+        for b, a in ((kb.k_pages, ka.k_pages), (kb.v_pages, ka.v_pages)):
+            b, a = np.asarray(b), np.asarray(a)
+            keep = np.ones(b.shape[2:4], bool)
+            for pg, sl in written:
+                keep[pg, sl] = False
+            np.testing.assert_array_equal(a[:, :, keep], b[:, :, keep])
+
+
+def _reference_kv(cfg, params, tokens):
+    """The dense model's K/V of ``tokens`` per layer:
+    ((n_periods, KV, S, Dh) K, V) of the first period block."""
+    from repro.models.model import prefill
+    _, cache = prefill(cfg, params, tokens)
+    return np.asarray(cache[0].k[:, 0]), np.asarray(cache[0].v[:, 0])
+
+
+class TestStackedPoolSteps:
+    """The decode and chunk steps write only their own rows of the
+    stacked pool, and those rows hold the dense model's K/V."""
+
+    def _chunk(self, cfg, params, cache, pos_pages, tokens, start=0):
+        from repro.serving.paged_model import paged_prefill_chunk
+        valid = tokens.shape[1]
+        padded = jnp.pad(tokens, ((0, 0), (0, _CHUNK - valid)))
+        _, cache2, pos2 = paged_prefill_chunk(
+            cfg, params, cache, pos_pages, jnp.asarray(_TABLE),
+            jnp.int32(start), padded, jnp.int32(valid))
+        return cache2, pos2
+
+    def test_chunk_step_writes_only_its_rows(self):
+        cfg = _cfg(n_layers=3)
+        params = _params(cfg)
+        cache, pos_pages = _noisy_pool(cfg)
+        tokens = jax.random.randint(jax.random.PRNGKey(1), (1, _VALID), 0,
+                                    cfg.vocab_size)
+        cache2, _ = self._chunk(cfg, params, cache, pos_pages, tokens)
+        # the padded rows of the chunk write nothing, not even the null page
+        _assert_only_written(cache, cache2, [_slot(p) for p in range(_VALID)])
+        k_ref, v_ref = _reference_kv(cfg, params, tokens)
+        for p in range(_VALID):
+            pg, sl = _slot(p)
+            np.testing.assert_allclose(
+                np.asarray(cache2[0].k_pages[:, :, pg, sl]), k_ref[:, :, p],
+                atol=1e-5, rtol=1e-5)
+            np.testing.assert_allclose(
+                np.asarray(cache2[0].v_pages[:, :, pg, sl]), v_ref[:, :, p],
+                atol=1e-5, rtol=1e-5)
+
+    @pytest.mark.parametrize("start,valid", [(3, _VALID), (6, 2)])
+    def test_chunk_at_unaligned_start_writes_only_its_rows(self, start,
+                                                           valid):
+        """A chunk that starts inside a page keeps that page's earlier
+        slots and writes only its own."""
+        cfg = _cfg(n_layers=3)
+        params = _params(cfg)
+        cache, pos_pages = _noisy_pool(cfg)
+        tokens = jax.random.randint(jax.random.PRNGKey(3),
+                                    (1, start + valid), 0, cfg.vocab_size)
+        cache2, _ = self._chunk(cfg, params, cache, pos_pages,
+                                tokens[:, start:], start=start)
+        slots = [_slot(p) for p in range(start, start + valid)]
+        _assert_only_written(cache, cache2, slots)
+        # layer 0's K/V depend on the token and its position alone
+        k_ref, v_ref = _reference_kv(cfg, params, tokens)
+        kc = cache2[0]
+        for p, (pg, sl) in zip(range(start, start + valid), slots):
+            np.testing.assert_allclose(np.asarray(kc.k_pages[0, :, pg, sl]),
+                                       k_ref[0, :, p], atol=1e-5, rtol=1e-5)
+            np.testing.assert_allclose(np.asarray(kc.v_pages[0, :, pg, sl]),
+                                       v_ref[0, :, p], atol=1e-5, rtol=1e-5)
+
+    @pytest.mark.parametrize("backend", ["xla_paged_decode",
+                                         "pallas_paged_decode"])
+    def test_decode_step_writes_only_its_rows(self, backend):
+        from repro.serving.paged_model import paged_decode_step
+        cfg = _cfg(n_layers=3)
+        params = _params(cfg)
+        cache, pos_pages = _noisy_pool(cfg)
+        tokens = jax.random.randint(jax.random.PRNGKey(2), (1, _VALID + 1),
+                                    0, cfg.vocab_size)
+        cache, pos_pages = self._chunk(cfg, params, cache, pos_pages,
+                                       tokens[:, :_VALID])
+        # row 0 decodes position _VALID; row 1 is an inactive slot, whose
+        # all-null table points it at the null page, which it leaves alone
+        tables = jnp.asarray(np.stack([_TABLE, np.zeros_like(_TABLE)]))
+        kv_len = jnp.asarray([_VALID, 0], jnp.int32)
+        toks = jnp.stack([tokens[0, _VALID:], jnp.zeros((1,), jnp.int32)])
+        out = paged_decode_step(cfg, params, cache, pos_pages, tables,
+                                kv_len, kv_len, toks, backend=backend)
+        cache2 = out[1]
+        pg, sl = _slot(_VALID)
+        _assert_only_written(cache, cache2, [(pg, sl)])
+        k_ref, v_ref = _reference_kv(cfg, params, tokens)
+        np.testing.assert_allclose(np.asarray(cache2[0].k_pages[:, :, pg, sl]),
+                                   k_ref[:, :, _VALID], atol=1e-5, rtol=1e-5)
+        np.testing.assert_allclose(np.asarray(cache2[0].v_pages[:, :, pg, sl]),
+                                   v_ref[:, :, _VALID], atol=1e-5, rtol=1e-5)
+
+    @pytest.mark.parametrize("step", ["decode", "chunk"])
+    def test_pool_is_scan_carry_not_scanned_data(self, step):
+        """The layer scan carries the pool: no pool-shaped array is among
+        its scanned inputs or stacked outputs, so no layer of the pool is
+        sliced out or written back whole."""
+        from repro.serving.paged_model import (paged_decode_step,
+                                               paged_prefill_chunk)
+        cfg = _cfg(n_layers=3)
+        params = _params(cfg)
+        cache, pos_pages = _noisy_pool(cfg)
+        if step == "decode":
+            args = (jnp.asarray(np.stack([_TABLE, _TABLE])),
+                    jnp.asarray([3, 5], jnp.int32),
+                    jnp.asarray([3, 5], jnp.int32),
+                    jnp.zeros((2, 1), jnp.int32))
+            fn = paged_decode_step
+        else:
+            args = (jnp.asarray(_TABLE), jnp.int32(0),
+                    jnp.zeros((1, _CHUNK), jnp.int32), jnp.int32(_VALID))
+            fn = paged_prefill_chunk
+        jaxpr = jax.make_jaxpr(lambda c: fn(cfg, params, c, pos_pages,
+                                            *args))(cache)
+        pool = {tuple(a.shape) for kc in cache for a in kc}
+
+        def scans(jx):
+            for eqn in jx.eqns:
+                if eqn.primitive.name == "scan":
+                    yield eqn
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from scans(sub)
+
+        found = list(scans(jaxpr.jaxpr))
+        assert found
+        carried = False
+        for eqn in found:
+            nc, ncarry = eqn.params["num_consts"], eqn.params["num_carry"]
+            shapes = [tuple(v.aval.shape) for v in eqn.invars]
+            carry, xs = shapes[nc:nc + ncarry], shapes[nc + ncarry:]
+            ys = [tuple(v.aval.shape) for v in eqn.outvars[ncarry:]]
+            assert not pool & set(xs), "the pool is scanned data"
+            assert not pool & set(ys), "the pool is a stacked scan output"
+            carried |= pool <= set(carry)
+        assert carried, "no scan carries the pool"
 
 
 # ---------------------------------------------------------------------------

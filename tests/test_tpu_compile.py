@@ -148,6 +148,24 @@ CASES = {
             ((OL_KV, OL_PAGES, PAGE, DH), BF16),
             ((OL_PAGES, PAGE), I32), ((OL_SLOTS, OL_MAX // PAGE), I32),
             ((OL_SLOTS,), I32), ((OL_SLOTS,), I32)]),
+    # the serving steps' read: layer l of the stacked (L, KV, N, ps, Dh)
+    # pool, l traced -- qwen's 28 layers at 20 slots, olmoe's 16 at 8
+    "paged_flash_decode_stacked": (
+        lambda q, kp, vp, pp, tb, kl, pos, l: paged_flash_decode(
+            q, kp, vp, pp, tb, kl, pos, layer=l, interpret=False), [
+            ((20, KV, H // KV, DH), BF16),
+            ((28, KV, 20 * 128 + 1, PAGE, DH), BF16),
+            ((28, KV, 20 * 128 + 1, PAGE, DH), BF16),
+            ((20 * 128 + 1, PAGE), I32), ((20, 128), I32),
+            ((20,), I32), ((20,), I32), ((), I32)]),
+    "paged_flash_decode_stacked_olmoe": (
+        lambda q, kp, vp, pp, tb, kl, pos, l: paged_flash_decode(
+            q, kp, vp, pp, tb, kl, pos, layer=l, interpret=False), [
+            ((OL_SLOTS, OL_KV, 1, DH), BF16),
+            ((16, OL_KV, OL_PAGES, PAGE, DH), BF16),
+            ((16, OL_KV, OL_PAGES, PAGE, DH), BF16),
+            ((OL_PAGES, PAGE), I32), ((OL_SLOTS, OL_MAX // PAGE), I32),
+            ((OL_SLOTS,), I32), ((OL_SLOTS,), I32), ((), I32)]),
     # the held experts' grouped FFN of a 1024-token chunk and of a decode
     # step over 8 slots, the grid sized for every pair held
     "moe_gmm_chunk": _moe_gmm_case(OL_CHUNK),
