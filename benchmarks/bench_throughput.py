@@ -1,10 +1,10 @@
 """Fig. 20 + Table IV: cycle-model throughput decomposition and the
 attention-level energy-efficiency comparison vs SpAtten / Sanger -- plus a
-*measured* serving comparison: tokens/sec and pages-in-use for the dense
-fixed-slot engine vs the block-pool paged engine vs paged+SPLS page
-pruning on the BERT-Base (smoke-scale) config.  The derived
-``req_per_mb`` column is the acceptance metric: concurrent requests per
-MB of KV pool actually needed (paged+SPLS > paged > dense)."""
+*measured* serving comparison: tokens/sec and pages-in-use for the
+block-pool paged engine vs paged+SPLS page pruning on the BERT-Base
+(smoke-scale) config.  The derived ``req_per_mb`` column is the acceptance
+metric: concurrent requests per MB of KV pool actually needed (paged+SPLS
+> paged)."""
 
 from __future__ import annotations
 
@@ -39,7 +39,7 @@ def _tree_bytes(tree) -> int:
 
 
 def _measure_engine(mode: str, telemetry: bool = True):
-    """mode: dense | paged | paged_spls | paged_chunked |
+    """mode: paged | paged_spls | paged_chunked |
     paged_spls_chunked.  The ``*_chunked`` variants prefill long prompts
     in 16-token chunks (interleaved with decode); ``paged_spls_chunked``
     is the progressive-SPLS serving path -- the plan streams per chunk and
@@ -47,8 +47,7 @@ def _measure_engine(mode: str, telemetry: bool = True):
     engine, outputs)``; ``telemetry=False`` measures the no-op-sink
     engine for the overhead row."""
     from repro.models import init_params
-    from repro.serving import (PagedServingEngine, Request, ServeConfig,
-                               ServingEngine)
+    from repro.serving import PagedServingEngine, Request, ServeConfig
 
     chunked = mode.endswith("_chunked")
     spls = mode.startswith("paged_spls")
@@ -56,13 +55,11 @@ def _measure_engine(mode: str, telemetry: bool = True):
     params = init_params(cfg, jax.random.PRNGKey(0))
     max_len = _PROMPT + _MAX_NEW + _PS
     scfg = ServeConfig(n_slots=_SLOTS, max_len=max_len, page_size=_PS,
-                       attn_backend=None if mode == "dense"
-                       else "xla_paged_decode",
+                       attn_backend="xla_paged_decode",
                        prefill_chunk=16 if chunked else 64,
                        spls_page_prune=spls, spls_prune_vote=1.0,
                        telemetry=telemetry)
-    eng = (ServingEngine if mode == "dense"
-           else PagedServingEngine)(cfg, params, scfg)
+    eng = PagedServingEngine(cfg, params, scfg)
     reqs = []
     for i in range(_N_REQ):
         prompt = jax.random.randint(jax.random.PRNGKey(200 + i), (_PROMPT,),
@@ -76,21 +73,15 @@ def _measure_engine(mode: str, telemetry: bool = True):
     tokens = sum(len(r.output) for r in reqs)
     assert all(r.done for r in reqs)
 
-    if mode == "dense":
-        kv_bytes = _tree_bytes(eng.cache)           # n_slots x max_len slab
-        pages = None
-        saved = {"qkv": 0.0, "attn": 0.0, "ffn": 0.0, "kv": 0.0}
-    else:
-        # the SPLS predictor cache is page-parallel pool memory: charge it
-        # (int8 codes + per-token scale since the planner unification --
-        # _tree_bytes naturally reports the reduced footprint)
-        pool_bytes = _tree_bytes(eng.cache)
-        if eng.pred_cache is not None:
-            pool_bytes += _tree_bytes(eng.pred_cache)
-        page_bytes = pool_bytes / eng.pool.n_pages
-        kv_bytes = int(eng.stats["peak_pages"] * page_bytes)
-        pages = eng.stats["peak_pages"]
-        saved = eng.stats["flops_saved_pct"]
+    # the SPLS predictor cache is page-parallel pool memory: charge it
+    # (int8 codes + per-token scale since the planner unification --
+    # _tree_bytes naturally reports the reduced footprint)
+    pool_bytes = _tree_bytes(eng.cache)
+    if eng.pred_cache is not None:
+        pool_bytes += _tree_bytes(eng.pred_cache)
+    page_bytes = pool_bytes / eng.pool.n_pages
+    kv_bytes = int(eng.stats["peak_pages"] * page_bytes)
+    saved = eng.stats["flops_saved_pct"]
     out = {"tok_s": round(tokens / dt, 1),
            "kv_mb": round(kv_bytes / 1e6, 4),
            "concurrent": _SLOTS,
@@ -101,9 +92,8 @@ def _measure_engine(mode: str, telemetry: bool = True):
            "flops_saved_qkv_pct": round(saved["qkv"], 1),
            "flops_saved_attn_pct": round(saved["attn"], 1),
            "flops_saved_ffn_pct": round(saved["ffn"], 1),
-           "flops_saved_kv_pct": round(saved.get("kv", 0.0), 1)}
-    if pages is not None:
-        out["pages_in_use_peak"] = pages
+           "flops_saved_kv_pct": round(saved.get("kv", 0.0), 1),
+           "pages_in_use_peak": eng.stats["peak_pages"]}
     return dt * 1e6, out, eng, [list(r.output) for r in reqs]
 
 
@@ -193,12 +183,12 @@ def run():
     rows.append(("energy/attention_paper_reference", 0.0, {
         "energy_eff_gops_w": 6677, "vs_spatten": 2.95, "vs_sanger": 2.26}))
 
-    # measured serving: dense slab vs paged pool vs paged+SPLS pruning,
+    # measured serving: paged pool vs paged+SPLS pruning,
     # plus the long-prompt chunked-prefill pair (dense chunked vs the
     # progressive chunked+SPLS path -- the acceptance comparison)
     derived = {}
     outputs = {}
-    for mode in ("dense", "paged", "paged_spls", "paged_chunked",
+    for mode in ("paged", "paged_spls", "paged_chunked",
                  "paged_spls_chunked"):
         us, d, _eng, outs = _measure_engine(mode)
         derived[mode] = d
@@ -234,12 +224,11 @@ def run():
                               2),
         "outputs_bitwise_equal": True}))
     gain = (derived["paged_spls"]["req_per_mb"]
-            / max(derived["dense"]["req_per_mb"], 1e-9))
+            / max(derived["paged"]["req_per_mb"], 1e-9))
     rows.append(("serving/summary", 0.0, {
-        "req_per_mb_dense": derived["dense"]["req_per_mb"],
         "req_per_mb_paged": derived["paged"]["req_per_mb"],
         "req_per_mb_paged_spls": derived["paged_spls"]["req_per_mb"],
-        "paged_spls_vs_dense_x": round(gain, 2)}))
+        "paged_spls_vs_paged_x": round(gain, 2)}))
     ck, cs = derived["paged_chunked"], derived["paged_spls_chunked"]
     rows.append(("serving/summary_chunked", 0.0, {
         "peak_pages_dense_chunked": ck["pages_in_use_peak"],

@@ -18,9 +18,8 @@ exits non-zero and prints no result line:
    launcher's SPLS settings and ``auto`` backends, which must resolve to
    ``packed_pallas`` and ``pallas_paged_decode``; the same traffic again
    on the XLA twin (``packed_xla`` + ``xla_paged_decode``).
-4. serve, dense -- SPLS off, whole-prompt prefills through
-   ``pallas_flash`` and ``pallas_paged_decode``; the twin runs
-   ``xla_dense`` + ``xla_paged_decode``.
+4. serve, dense -- SPLS off, the dense chunk step and
+   ``pallas_paged_decode``; the twin runs ``xla_paged_decode``.
 
 In phases 3 and 4 every request must retire in both runs, and each
 request's first-token logits must agree with its twin's within
@@ -353,25 +352,13 @@ def phase_spls(params, prompts, clock, device, cfg):
 
 
 def phase_dense(params, prompts, clock, device, cfg):
-    import dataclasses
-
-    from repro.models import resolve_backend
-
-    # a chunk as long as the longest prompt: every prefill is whole-prompt
-    serve = dict(SERVE, prefill_chunk=max(len(p) for p in prompts))
-    prefill = sorted({resolve_backend(cfg.attn_backend, cfg, L=len(p))
-                      for p in prompts})
-    log(f"[dense] whole-prompt prefill backend(s): {prefill}")
+    serve = dict(SERVE, prefill_chunk=SPLS_CHUNK)
     run = serve_run("dense", cfg, params, prompts, clock, device,
                     attn_backend="auto", **serve)
-    if run["stats"]["prefill_chunks"]:
-        raise AssertionError("dense: a prompt took the chunked path")
-    if (prefill != ["pallas_flash"]
-            or run["stats"]["decode_backend"] != "pallas_paged_decode"):
-        raise AssertionError(f"dense: resolved {prefill} / "
+    if run["stats"]["decode_backend"] != "pallas_paged_decode":
+        raise AssertionError(f"dense: auto resolved to "
                              f"{run['stats']['decode_backend']}")
-    twin_cfg = dataclasses.replace(cfg, attn_backend="xla_dense")
-    twin = serve_run("dense-twin", twin_cfg, params, prompts, clock, device,
+    twin = serve_run("dense-twin", cfg, params, prompts, clock, device,
                      warm=False, attn_backend="xla_paged_decode", **serve)
     compare("dense", run, twin, LOGIT_TOL["dense"])
 

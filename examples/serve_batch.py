@@ -1,8 +1,8 @@
 """Serve a small model with batched requests through the continuous-
-batching engines: the dense fixed-slot baseline or the block-pool paged
-engine (chunked prefill, admission on free pages, SPLS page pruning).
+batching paged engine (chunked prefill, admission on free pages, SPLS page
+pruning).
 
-  PYTHONPATH=src python examples/serve_batch.py [--paged] [--spls]
+  PYTHONPATH=src python examples/serve_batch.py [--spls]
 """
 
 import argparse
@@ -14,8 +14,7 @@ import jax
 from repro.configs.base import ArchConfig, BlockCfg
 from repro.core.spls import SPLSConfig
 from repro.models import init_params
-from repro.serving import (PagedServingEngine, Request, ServeConfig,
-                           ServingEngine)
+from repro.serving import PagedServingEngine, Request, ServeConfig
 
 
 def main():
@@ -25,8 +24,6 @@ def main():
     ap.add_argument("--prompt-len", type=int, default=24)
     ap.add_argument("--max-new", type=int, default=12)
     ap.add_argument("--spls", action="store_true")
-    ap.add_argument("--paged", action="store_true",
-                    help="block-pool paged KV cache engine")
     ap.add_argument("--page-size", type=int, default=8)
     ap.add_argument("--prefill-chunk", type=int, default=16)
     ap.add_argument("--compute-backend", default=None,
@@ -88,8 +85,7 @@ def main():
                        spls_prune_vote=args.prune_vote,
                        capacity_margin=args.capacity_margin,
                        telemetry=not args.no_telemetry)
-    eng = (PagedServingEngine if args.paged else ServingEngine)(
-        cfg, params, scfg)
+    eng = PagedServingEngine(cfg, params, scfg)
 
     reqs = []
     for i in range(args.requests):
@@ -114,19 +110,18 @@ def main():
     dt = time.perf_counter() - t0
     total_tokens = sum(len(r.output) for r in reqs)
     dev = jax.devices()[0]
-    print(f"requests={len(reqs)} slots={args.slots} paged={args.paged} "
-          f"spls={args.spls} retired={len(done)}")
+    print(f"requests={len(reqs)} slots={args.slots} spls={args.spls} "
+          f"retired={len(done)}")
     print(f"decoded {total_tokens} tokens in {dt:.2f}s "
           f"({total_tokens/dt:.1f} tok/s on {dev.platform}:"
           f"{dev.device_kind}, compile included)")
-    if args.paged:
-        print(f"pool: peak_pages={eng.stats['peak_pages']} "
-              f"preemptions={eng.stats['preemptions']} "
-              f"prefill_chunks={eng.stats['prefill_chunks']}")
-        fs = eng.stats["flops_saved_pct"]
-        print(f"compute: backend={eng.stats['compute_backend']} "
-              f"flops_saved qkv={fs['qkv']:.1f}% attn={fs['attn']:.1f}% "
-              f"ffn={fs['ffn']:.1f}% kv={fs.get('kv', 0.0):.1f}%")
+    print(f"pool: peak_pages={eng.stats['peak_pages']} "
+          f"preemptions={eng.stats['preemptions']} "
+          f"prefill_chunks={eng.stats['prefill_chunks']}")
+    fs = eng.stats["flops_saved_pct"]
+    print(f"compute: backend={eng.stats['compute_backend']} "
+          f"flops_saved qkv={fs['qkv']:.1f}% attn={fs['attn']:.1f}% "
+          f"ffn={fs['ffn']:.1f}% kv={fs.get('kv', 0.0):.1f}%")
     assert all(r.done for r in reqs), "queue did not drain"
     assert len(done) == len(reqs)
     if args.bench_json:

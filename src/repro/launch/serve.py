@@ -1,14 +1,13 @@
 """Serving launcher: ``python -m repro.launch.serve --arch <id>``.
 
-Builds a continuous-batching engine at the architecture's published
-widths, with random weights drawn from ``--seed``, and drives a synthetic
-request stream through it.  ``--paged`` selects the block-pool paged
-engine (chunked prefill, admission keyed on free pages, SPLS page
-pruning); the default is the dense fixed-slot engine.  ``--spls`` turns
-on the paper's sparsity with :data:`SPLS_SETTINGS`.  ``--smoke`` swaps in
-the architecture's CPU-sized variant (``ArchConfig.smoke()``) for runs
-without an accelerator.  Paged serving requires attention-only periods
-(SSM state is O(1) per slot and is not paged).
+Builds the paged serving engine (chunked prefill, admission keyed on free
+pages, SPLS page pruning) at the architecture's published widths, with
+random weights drawn from ``--seed``, and drives a synthetic request
+stream through it.  ``--spls`` turns on the paper's sparsity with
+:data:`SPLS_SETTINGS`.  ``--smoke`` swaps in the architecture's CPU-sized
+variant (``ArchConfig.smoke()``) for runs without an accelerator.  The
+engine serves causal token models with attention-only periods (SSM state
+is O(1) per slot and is not paged).
 
 ``serving_config`` and ``build_engine`` are the construction path every
 serving entry point shares (``chip_smoke.py`` included).
@@ -57,13 +56,16 @@ def init_serving_params(cfg, seed: int = 0):
         jax.random.PRNGKey(seed))
 
 
-def build_engine(cfg, params, *, paged: bool, **serve_kw):
-    """A ``PagedServingEngine`` (``paged``) or the dense ``ServingEngine``
-    over ``cfg``/``params``; ``serve_kw`` are ``ServeConfig`` fields."""
-    from repro.serving import PagedServingEngine, ServeConfig, ServingEngine
+def build_engine(cfg, params, *, paged: bool = True, **serve_kw):
+    """A ``PagedServingEngine`` over ``cfg``/``params``; ``serve_kw`` are
+    ``ServeConfig`` fields.  ``paged`` must be True: there is no other
+    engine."""
+    from repro.serving import PagedServingEngine, ServeConfig
 
-    return (PagedServingEngine if paged else ServingEngine)(
-        cfg, params, ServeConfig(**serve_kw))
+    if not paged:
+        raise ValueError("build_engine(paged=False): the dense serving "
+                         "engine is gone; every engine is paged")
+    return PagedServingEngine(cfg, params, ServeConfig(**serve_kw))
 
 
 def main(argv=None):
@@ -77,7 +79,6 @@ def main(argv=None):
     ap.add_argument("--prompt-len", type=int, default=128)
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--spls", action="store_true")
-    ap.add_argument("--paged", action="store_true")
     ap.add_argument("--page-size", type=int, default=16)
     ap.add_argument("--prefill-chunk", type=int, default=64)
     args = ap.parse_args(argv)
@@ -87,17 +88,16 @@ def main(argv=None):
 
     configure_compile_cache()
     cfg = serving_config(args.arch, smoke=args.smoke, spls=args.spls)
-    if cfg.input_mode != "tokens" or (args.paged and cfg.has_mamba):
+    if cfg.input_mode != "tokens" or cfg.has_mamba or not cfg.causal:
         print(f"{cfg.name}: not servable by this engine -- skipping")
         return 0
     params = init_serving_params(cfg, args.seed)
-    # the paged engine packs SPLS prefill rows where the platform has a
-    # packed kernel ("auto"); the dense engine has no packed path
-    eng = build_engine(cfg, params, paged=args.paged, n_slots=args.slots,
+    # SPLS prefill packs rows where the platform has a packed kernel
+    eng = build_engine(cfg, params, n_slots=args.slots,
                        max_len=args.prompt_len + args.max_new + 8,
                        page_size=args.page_size,
                        prefill_chunk=args.prefill_chunk,
-                       compute_backend="auto" if args.paged else None)
+                       compute_backend="auto")
     reqs = []
     for i in range(args.requests):
         prompt = jax.random.randint(jax.random.PRNGKey(args.seed + i),
@@ -116,11 +116,10 @@ def main(argv=None):
                       "count": len(jax.devices())},
            # includes compilation: a first run's rate is mostly set-up
            "tok_per_s_incl_compile": tokens / wall,
-           "outputs": {r.rid: r.output[:8] for r in reqs[:4]}}
-    if args.paged:
-        out["pool"] = {k: eng.stats[k] for k in
-                       ("peak_pages", "preemptions", "prefill_chunks",
-                        "compute_backend", "decode_backend")}
+           "outputs": {r.rid: r.output[:8] for r in reqs[:4]},
+           "pool": {k: eng.stats[k] for k in
+                    ("peak_pages", "preemptions", "prefill_chunks",
+                     "compute_backend", "decode_backend")}}
     print(json.dumps(out, indent=1))
     return 0 if out["all_done"] else 1
 

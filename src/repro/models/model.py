@@ -191,8 +191,8 @@ def prefill(cfg: ArchConfig, params: Params, inputs: jax.Array,
     processed sparsely; the KV cache still holds every position (pruned
     columns would be an additional paper-faithful saving -- see DESIGN.md).
     ``plan_mode="progressive"`` selects the streaming-reproducible plan
-    builder (see :func:`repro.models.blocks.block_forward`); the serving
-    engines use it so chunked and whole-prompt prefills agree exactly.
+    builder (see :func:`repro.models.blocks.block_forward`): the serving
+    engine's chunked prefill reproduces it exactly.
     """
     L = inputs.shape[1]
     S = max_len or L

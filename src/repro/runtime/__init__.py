@@ -1,4 +1,4 @@
-"""Runtime: trainer loop, fault tolerance, elastic re-meshing, serving."""
+"""Runtime: trainer loop, fault tolerance, elastic re-meshing."""
 
 from .fault_tolerance import (FailureSimulator, Heartbeat, StragglerDetector,
                               retry_with_backoff)

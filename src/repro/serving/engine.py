@@ -1,22 +1,17 @@
-"""Serving engines: dense fixed-slot and block-pool paged.
+"""The serving engine: continuous batching over a block-pool paged KV cache.
 
-:class:`ServingEngine` is the original continuous-batching engine -- a
-dense ``n_slots x max_len`` KV cache, whole-prompt prefill into a free
-slot, one batched decode per tick.  It remains the baseline (and the
-parity oracle) for the paged engine.
+:class:`PagedServingEngine` keeps KV in a shared
+:class:`~repro.serving.pager.PagePool`; requests hold block tables instead
+of cache rows.  Every prompt prefills through the chunk step, one
+``prefill_chunk``-token chunk a tick *between* decode ticks (no
+head-of-line blocking); admission is keyed on free pages, and a dry pool
+preempts the youngest sequence by page eviction.  With SPLS enabled,
+prefill prunes dead KV columns out of the pool entirely
+(``spls_token_keep``), so the paper's sparsity buys admission capacity,
+not just skipped math.
 
-:class:`PagedServingEngine` is the production-shaped path: KV lives in a
-shared :class:`~repro.serving.pager.PagePool`, requests hold block tables
-instead of cache rows, prompts longer than a chunk prefill incrementally
-*between* decode ticks (no head-of-line blocking), admission is keyed on
-free pages, and a dry pool preempts the youngest sequence by page
-eviction.  With SPLS enabled, prefill prunes dead KV columns out of the
-pool entirely (``spls_token_keep``), so the paper's sparsity buys
-admission capacity, not just skipped math.
-
-Both engines share :class:`Request`/:class:`ServeConfig` and the sampling
-path: ``greedy=True`` (default) takes the argmax; ``greedy=False`` samples
-with ``temperature`` through a PRNG key threaded from ``seed``.
+Sampling: ``greedy=True`` (default) takes the argmax; ``greedy=False``
+samples with ``temperature`` through a PRNG key threaded from ``seed``.
 """
 
 from __future__ import annotations
@@ -25,7 +20,6 @@ import dataclasses
 import functools
 import math
 import warnings
-from collections import deque
 from typing import List, Optional, Tuple
 
 import jax
@@ -34,7 +28,6 @@ import numpy as np
 
 from repro.configs.base import ArchConfig
 from repro.kernels.paged_decode import pages_visited
-from repro.models import decode_step, init_cache, prefill
 from repro.models.attn_backend import AUTO, resolve_backend
 from repro.models.moe import MOE_STATS
 from repro.observability import Telemetry, tree_bytes
@@ -42,13 +35,12 @@ from repro.sparse_compute import (CapacityController, chunk_flops, is_packed,
                                   resolve_compute_backend)
 
 from .pager import (NULL_PAGE, PagePool, init_paged_cache, init_pos_pages,
-                    init_pred_cache, keep_from_votes, spls_token_votes)
+                    init_pred_cache, keep_from_votes)
 from .paged_model import (compact_slots, paged_decode_step,
-                          paged_prefill_chunk, paged_prefill_chunk_spls,
-                          scatter_prefill)
+                          paged_prefill_chunk, paged_prefill_chunk_spls)
 from .scheduler import Scheduler, SchedulerConfig, SeqState
 
-__all__ = ["Request", "ServeConfig", "ServingEngine", "PagedServingEngine"]
+__all__ = ["Request", "ServeConfig", "PagedServingEngine"]
 
 
 @dataclasses.dataclass
@@ -75,12 +67,10 @@ class ServeConfig:
     greedy: bool = True
     temperature: float = 1.0
     seed: int = 0
-    # attention backend override for this engine (None = cfg/auto); see
-    # repro.models.attn_backend -- prefill resolves the forward side
-    # (e.g. "pallas_flash"), ticks resolve the decode side (the paged
-    # engine resolves the *paged* decode side).
+    # paged decode backend (repro.models.attn_backend): "auto",
+    # "pallas_paged_decode" or "xla_paged_decode"; None, or a backend of
+    # another kind, keeps the model config's own
     attn_backend: Optional[str] = None
-    # paged-engine knobs (ignored by the dense engine)
     page_size: int = 16
     n_pages: Optional[int] = None   # None -> n_slots * pages(max_len) + 1
     prefill_chunk: int = 64
@@ -122,26 +112,26 @@ class ServeConfig:
     telemetry: bool = True
 
 
-def _backend_for_site(name: Optional[str], cfg_name: Optional[str], *,
-                      decode: bool, paged: bool = False) -> Optional[str]:
-    """Route a ServeConfig.attn_backend name to one engine site.
+def _paged_decode_name(name: Optional[str],
+                       cfg_name: Optional[str]) -> Optional[str]:
+    """The backend name the paged decode site resolves from
+    ServeConfig.attn_backend ``name``.
 
-    The single config field intentionally drives every site an engine
-    has; a site of a different kind keeps the model config's own backend
-    (``cfg_name``, ``"auto"`` by default), so an engine can pin its
-    decode backend through ServeConfig and its forward backend through
-    the ArchConfig.  Doing the kind split *here* keeps the registry's
-    kind-mismatch warning reserved for genuine configuration errors
-    instead of firing on the engines' own documented fall-through (and
-    keeps ``STRICT_BACKEND_KIND`` usable with the engines).  A name no
-    site knows is passed through, so the registry rejects it."""
-    if name is None or name == AUTO:
+    A registered backend of another kind (a forward or dense-decode one)
+    keeps the model config's own (``cfg_name``, ``"auto"`` by default), so
+    the registry's kind-mismatch warning stays reserved for genuine
+    configuration errors (and ``STRICT_BACKEND_KIND`` stays usable with
+    the engine).  A name no registry knows is passed through, so the
+    registry rejects it."""
+    if name is None:
+        return cfg_name
+    if name == AUTO:
         return name
     from repro.models import available_backends
 
     if name not in available_backends():
         return name
-    return (name if name in available_backends(decode=decode, paged=paged)
+    return (name if name in available_backends(decode=True, paged=True)
             else cfg_name)
 
 
@@ -170,179 +160,21 @@ def sample_tokens(key: Optional[jax.Array], logits: jax.Array,
     ).astype(jnp.int32)
 
 
-class _SamplerMixin:
-    def _init_sampler(self, scfg: ServeConfig) -> None:
-        self.scfg = scfg
-        self._key = jax.random.PRNGKey(scfg.seed)
-
-    def _pick(self, logits: jax.Array) -> jax.Array:
-        key = None
-        if not self.scfg.greedy:
-            self._key, key = jax.random.split(self._key)
-        return sample_tokens(key, logits, greedy=self.scfg.greedy,
-                             temperature=self.scfg.temperature)
-
-
-# ---------------------------------------------------------------------------
-# dense fixed-slot engine (the baseline / parity oracle)
-# ---------------------------------------------------------------------------
-
-class ServingEngine(_SamplerMixin):
-    def __init__(self, cfg: ArchConfig, params, scfg: ServeConfig):
-        assert cfg.input_mode == "tokens", "engine serves token models"
-        # the dense engine has no packed-compute path (it is the
-        # simulation-mode parity oracle); surface a requested packed
-        # backend loudly instead of silently measuring dense compute
-        if is_packed(resolve_compute_backend(
-                scfg.compute_backend if scfg.compute_backend is not None
-                else cfg.compute_backend, sparse=cfg.spls.enabled)):
-            warnings.warn(
-                "ServingEngine (dense fixed-slot) executes dense compute "
-                "only; the configured packed compute_backend applies to "
-                "PagedServingEngine's chunked SPLS prefill and is ignored "
-                "here", RuntimeWarning, stacklevel=2)
-        if scfg.vote_horizon is not None:
-            warnings.warn(
-                "ServingEngine prefills whole prompts with the "
-                "end-of-prefill prune vote; vote_horizon applies to "
-                "PagedServingEngine's chunked SPLS prefill and is ignored "
-                "here", RuntimeWarning, stacklevel=2)
-        cfg_fwd, cfg_dec = cfg, cfg
-        if scfg.attn_backend is not None:
-            cfg_fwd = dataclasses.replace(cfg, attn_backend=_backend_for_site(
-                scfg.attn_backend, cfg.attn_backend, decode=False))
-            cfg_dec = dataclasses.replace(cfg, attn_backend=_backend_for_site(
-                scfg.attn_backend, cfg.attn_backend, decode=True))
-        self.cfg, self.params = cfg, params
-        self._init_sampler(scfg)
-        self.telemetry = Telemetry(enabled=scfg.telemetry)
-        self.queue: deque = deque()
-        self.slots: List[Optional[Request]] = [None] * scfg.n_slots
-        self.pos = jnp.zeros((scfg.n_slots,), jnp.int32)
-        self.tokens = jnp.zeros((scfg.n_slots, 1), jnp.int32)
-        self.cache = init_cache(cfg, scfg.n_slots, scfg.max_len)
-        self._retired: List[Request] = []
-        self._decode = jax.jit(_program(decode_step, cfg_dec))
-        # SPLS configs prefill with the progressive (streaming-
-        # reproducible) plan builder so this engine stays the exact parity
-        # oracle for the paged engine's chunked SPLS prefill
-        plan_mode = "progressive" if cfg.spls.enabled else "auto"
-        self._prefill = jax.jit(_program(prefill, cfg_fwd,
-                                         max_len=scfg.max_len,
-                                         plan_mode=plan_mode))
-
-    # ------------------------------------------------------------------
-    @property
-    def stats(self) -> dict:
-        """Minimal stats view (the paged engine carries the full set);
-        dense compute executes everything, so savings are all zero."""
-        return {"retired": len(self._retired),
-                "compute_backend": "dense",
-                "flops_saved_pct": {}}
-
-    def submit(self, req: Request) -> None:
-        self.telemetry.request_submitted(req.rid,
-                                         int(req.prompt.shape[0]))
-        self.queue.append(req)
-
-    def _admit(self) -> None:
-        """Move queued requests into free slots (prefill their prompt)."""
-        for s in range(self.scfg.n_slots):
-            if self.slots[s] is not None or not self.queue:
-                continue
-            req = self.queue.popleft()
-            self.telemetry.request_admitted(req.rid)
-            lp = int(req.prompt.shape[0])
-            self.telemetry.span_begin("full_prefill", rid=req.rid)
-            logits, cache1 = self._prefill(self.params,
-                                           req.prompt[None, :])
-            # splice this row's prefilled cache into slot s
-            self.cache = jax.tree.map(
-                lambda full, one: full.at[:, s:s + 1].set(one),
-                self.cache, cache1)
-            if req.return_logits:
-                req.first_logits = np.asarray(logits[0, -1], np.float32)
-            nxt = int(self._pick(logits[0, -1]))
-            req.output.append(nxt)
-            self.telemetry.span_end("full_prefill", rid=req.rid)
-            self.telemetry.first_token(req.rid)
-            self.slots[s] = req
-            self.pos = self.pos.at[s].set(lp)
-            self.tokens = self.tokens.at[s, 0].set(nxt)
-
-    def _retire(self) -> None:
-        for s, req in enumerate(self.slots):
-            if req is None:
-                continue
-            hit_eos = req.eos_id is not None and req.eos_id in req.output
-            if len(req.output) >= req.max_new_tokens or hit_eos or \
-                    int(self.pos[s]) >= self.scfg.max_len - 1:
-                req.done = True
-                self.slots[s] = None
-                self._retired.append(req)
-                self.telemetry.request_retired(req.rid)
-
-    def tick(self) -> int:
-        """One engine iteration; returns number of active slots decoded."""
-        self._admit()
-        self._retire()  # a prefill-emitted token may already hit eos/budget
-        active = [s for s, r in enumerate(self.slots) if r is not None]
-        if not active:
-            return 0
-        self.telemetry.span_begin("decode_tick",
-                                  args={"n_active": len(active)})
-        logits, self.cache = self._decode(self.params, self.cache,
-                                          self.tokens, self.pos)
-        nxt = self._pick(logits[:, 0])
-        for s in active:
-            tok = int(nxt[s])
-            self.slots[s].output.append(tok)
-        self.telemetry.span_end("decode_tick")
-        self.telemetry.tokens_decoded(
-            [self.slots[s].rid for s in active])
-        self.pos = self.pos + jnp.asarray(
-            [1 if self.slots[s] is not None else 0
-             for s in range(self.scfg.n_slots)], jnp.int32)
-        self.tokens = nxt[:, None]
-        self._retire()
-        return len(active)
-
-    def run_until_drained(self, max_ticks: int = 10000) -> List[Request]:
-        """Tick until queue and slots are empty; returns the requests that
-        retired during this call, in retirement order."""
-        start = len(self._retired)
-        for _ in range(max_ticks):
-            self.tick()
-            if not self.queue and all(s is None for s in self.slots):
-                break
-        return self._retired[start:]
-
-
-# ---------------------------------------------------------------------------
-# paged engine
-# ---------------------------------------------------------------------------
-
-class PagedServingEngine(_SamplerMixin):
+class PagedServingEngine:
     """Continuous batching over the block-pool paged KV cache."""
 
     def __init__(self, cfg: ArchConfig, params, scfg: ServeConfig):
         assert cfg.input_mode == "tokens", "engine serves token models"
         assert all(b.mixer == "attn" for b in cfg.period), \
             "paged engine is attention-only (SSM state is O(1) per slot)"
-        cfg_fwd, cfg_pgd = cfg, cfg
-        if scfg.attn_backend is not None:
-            cfg_fwd = dataclasses.replace(cfg, attn_backend=_backend_for_site(
-                scfg.attn_backend, cfg.attn_backend, decode=False))
-            cfg_pgd = dataclasses.replace(cfg, attn_backend=_backend_for_site(
-                scfg.attn_backend, cfg.attn_backend, decode=True,
-                paged=True))
-        # chunked prefill needs causal cross-chunk attention.  SPLS no
-        # longer disables it: the plan streams one window-aligned chunk at
-        # a time (the paper's progressive generation scheme) and the
-        # page-prune vote accumulates across chunks.
-        chunkable = cfg.causal
-        if cfg.spls.enabled and chunkable \
-                and scfg.prefill_chunk % cfg.spls.window:
+        # every request decodes, and every prompt prefills in chunks that
+        # attend causally across chunks: a non-causal model needs a
+        # request kind that yields no token
+        assert cfg.causal, "the engine serves causal models"
+        # SPLS streams its plan one window-aligned chunk at a time (the
+        # paper's progressive generation scheme); the page-prune vote
+        # accumulates across chunks
+        if cfg.spls.enabled and scfg.prefill_chunk % cfg.spls.window:
             if scfg.auto_align_chunk:
                 aligned = -(-scfg.prefill_chunk // cfg.spls.window) \
                     * cfg.spls.window
@@ -358,10 +190,10 @@ class PagedServingEngine(_SamplerMixin):
                     f"multiple of the SPLS similarity window "
                     f"({cfg.spls.window}): chunk boundaries must align "
                     f"with similarity windows for chunked prefill to "
-                    f"reproduce the full-prefill plan (set "
+                    f"reproduce the whole-prompt plan (set "
                     f"ServeConfig.auto_align_chunk=True to round up)")
-        self.cfg, self.params = cfg, params
-        self._init_sampler(scfg)
+        self.cfg, self.params, self.scfg = cfg, params, scfg
+        self._key = jax.random.PRNGKey(scfg.seed)
 
         ps = scfg.page_size
         self.page_size = ps
@@ -378,8 +210,7 @@ class PagedServingEngine(_SamplerMixin):
             scfg.compute_backend if scfg.compute_backend is not None
             else cfg.compute_backend, sparse=cfg.spls.enabled)
         # horizon-finalized column votes (core.planner): a finite horizon
-        # needs the streaming chunked path AND page pruning (the horizon
-        # decision *is* a prune decision)
+        # needs page pruning (the horizon decision *is* a prune decision)
         self._horizon = scfg.vote_horizon
         # the horizon's early finalization applies the same cross-head
         # agreement bar as the end-of-prefill vote (keep_from_votes)
@@ -390,12 +221,11 @@ class PagedServingEngine(_SamplerMixin):
                 raise ValueError(
                     f"vote_horizon must be >= 1 chunks (or None for the "
                     f"end-of-prefill vote), got {self._horizon}")
-            if not (cfg.spls.enabled and self._prune and chunkable):
+            if not (cfg.spls.enabled and self._prune):
                 raise ValueError(
-                    "vote_horizon requires SPLS (cfg.spls.enabled), page "
-                    "pruning (ServeConfig.spls_page_prune) and a causal "
-                    "model (chunked prefill): the horizon finalizes the "
-                    "streaming prune vote early")
+                    "vote_horizon requires SPLS (cfg.spls.enabled) and page "
+                    "pruning (ServeConfig.spls_page_prune): the horizon "
+                    "finalizes the streaming prune vote early")
         cs = scfg.prefill_chunk
         if is_packed(self._compute):
             self._cap_q = CapacityController(
@@ -418,28 +248,20 @@ class PagedServingEngine(_SamplerMixin):
                             prefill_chunk=scfg.prefill_chunk,
                             max_prefills_per_tick=scfg.max_prefills_per_tick,
                             watermark=scfg.watermark),
-            self.pool, scfg.max_len, chunkable=chunkable,
-            prune_aware=self._prune,
-            # packed compute: route whole prompts (<= one chunk) through
-            # the chunk path too, so short prompts get token compaction
-            # instead of silently running the dense full-prefill path
-            chunk_all=is_packed(self._compute),
+            self.pool, scfg.max_len, prune_aware=self._prune,
             telemetry=self.telemetry)
 
         self.cache = init_paged_cache(cfg, n_pages, ps)
         self.pos_pages = init_pos_pages(n_pages, ps)
-        # the paged SPLS predictor cache is allocated lazily on the first
-        # chunked SPLS prefill: full-prefill-only workloads (every prompt
-        # <= prefill_chunk) never pay its pool memory
-        self.pred_cache = None
-        self._n_pages = n_pages
+        self.pred_cache = (init_pred_cache(cfg, n_pages, ps)
+                           if cfg.spls.enabled else None)
         self._retired: List[Request] = []
         # resolved once (the same call paged_decode_step makes), so a
         # wrong-kind name fails at construction under STRICT_BACKEND_KIND
         # and stats name the kernel every decode tick dispatches to
         self.decode_backend = dec_be = resolve_backend(
-            cfg_pgd.attn_backend, cfg, L=n_pages * ps, decode=True,
-            paged=True)
+            _paged_decode_name(scfg.attn_backend, cfg.attn_backend), cfg,
+            L=n_pages * ps, decode=True, paged=True)
         # the old cache / pos_pages references die on reassignment every
         # tick, so donate them: decode scatters one token in place instead
         # of copying the whole page pool (donation is a no-op on CPU).
@@ -448,10 +270,6 @@ class PagedServingEngine(_SamplerMixin):
         self._decode = jax.jit(
             _program(paged_decode_step, cfg, backend=dec_be),
             donate_argnums=(1, 2))
-        plan_mode = "progressive" if cfg.spls.enabled else "auto"
-        self._prefill = jax.jit(_program(prefill, cfg_fwd,
-                                         plan_mode=plan_mode))
-        self._votes = jax.jit(_program(spls_token_votes, cfg))
         self._chunk = jax.jit(_program(paged_prefill_chunk, cfg),
                               donate_argnums=(1, 2))
         # SPLS chunk step: one jit covers *all* prompt lengths (top-k
@@ -460,9 +278,9 @@ class PagedServingEngine(_SamplerMixin):
         # the pair set small)
         self._chunk_spls_jits: dict = {}
         self._compact = jax.jit(compact_slots, donate_argnums=(0, 1))
-        # pool byte gauges (metadata only, no device sync); the predictor
-        # cache gauge updates when its lazy allocation lands
-        self.telemetry.sparsity.note_pool_bytes(tree_bytes(self.cache))
+        # pool byte gauges (metadata only, no device sync)
+        self.telemetry.sparsity.note_pool_bytes(tree_bytes(self.cache),
+                                                tree_bytes(self.pred_cache))
 
     def _get_chunk_spls(self, cq: Optional[int], cf: Optional[int],
                         ckv: Optional[int] = None, horizon: bool = False):
@@ -517,59 +335,10 @@ class PagedServingEngine(_SamplerMixin):
         self.telemetry.request_submitted(req.rid, lp)
 
     # ------------------------------------------------------------------
-    def _dest_slots(self, st: SeqState, n: int) -> np.ndarray:
-        """(n,) flat page-slot destinations for logical slots [0, n)."""
-        pages = np.asarray(st.pages, np.int64)
-        sl = np.arange(n)
-        return pages[sl // self.page_size] * self.page_size \
-            + sl % self.page_size
-
     def _table_row(self, st: SeqState) -> np.ndarray:
         row = np.full((self.pages_per_seq,), NULL_PAGE, np.int32)
         row[:len(st.pages)] = st.pages
         return row
-
-    def _full_prefill(self, st: SeqState) -> None:
-        """Whole-prompt prefill.  ``engine/full_prefill`` covers the
-        dispatch and, with page pruning, the vote readback."""
-        tel = self.telemetry
-        with tel.span("engine/full_prefill", rid=st.req.rid,
-                      prompt_len=st.prompt_len):
-            tel.span_begin("full_prefill", rid=st.req.rid,
-                           args={"prompt_len": st.prompt_len})
-            toks = jnp.asarray(st.tokens, jnp.int32)[None, :]
-            logits, dense_cache = self._prefill(self.params, toks)
-            if self._prune:
-                keep = keep_from_votes(self._votes(self.params, toks[0]),
-                                       self.cfg.n_heads,
-                                       self.scfg.spls_prune_vote)
-            else:
-                keep = np.ones((st.prompt_len,), bool)
-            keep_idx = np.nonzero(keep)[0]
-            n_kept = len(keep_idx)
-            if not self.sched.grow_to(st, n_kept):
-                # st itself was preempted (span unwound by the
-                # preempt/abort telemetry); prefill recomputes later
-                return
-            dest = self._dest_slots(st, n_kept)
-            self.cache, self.pos_pages = scatter_prefill(
-                self.cache, self.pos_pages, dense_cache,
-                jnp.asarray(keep_idx, jnp.int32),
-                jnp.asarray(dest, jnp.int32))
-            st.kv_len = n_kept
-            st.cur_pos = st.prompt_len
-            st.prefilled = st.prompt_len
-            # whole-prompt prefill runs dense/simulation compute (packed
-            # capacities apply on the chunked path); charged dense ==
-            # executed
-            self.sched.note_flops(chunk_flops(self.cfg, st.prompt_len,
-                                              st.prompt_len))
-            if self._prune:
-                self.sched.note_prune(st.prompt_len, n_kept)
-                tel.sparsity.note_prune(st.prompt_len, n_kept)
-            tel.span_end("full_prefill", rid=st.req.rid,
-                         args={"kept": n_kept})
-        self._emit_first(st, logits[0, -1])
 
     def _chunk_prefill(self, st: SeqState) -> None:
         """One prompt chunk.  ``engine/prefill_chunk`` covers the page
@@ -607,11 +376,6 @@ class PagedServingEngine(_SamplerMixin):
         if self.cfg.spls.enabled:
             from repro.core.planner import horizon_update_live
             from repro.core.topk import topk_count
-            if self.pred_cache is None:
-                self.pred_cache = init_pred_cache(self.cfg, self._n_pages,
-                                                  self.page_size)
-                tel.sparsity.note_pool_bytes(tree_bytes(self.cache),
-                                             tree_bytes(self.pred_cache))
             k = topk_count(st.prompt_len, self.cfg.spls.k_ratio)
             packed = self._cap_q is not None
             cq = self._cap_q.capacity() if packed else None
@@ -694,10 +458,10 @@ class PagedServingEngine(_SamplerMixin):
     def _finish_chunk_prune(self, st: SeqState) -> None:
         """The page-prune vote is final once every prompt row has voted
         (votes are monotone in rows, so pruning any earlier would diverge
-        from the full-prefill decision): threshold the accumulated head
-        votes, compact kept columns -- in original order, the same layout
-        ``scatter_prefill`` produces -- into the front of the sequence's
-        own pages, and free the tail."""
+        from the whole-prompt decision, ``spls_token_keep``): threshold
+        the accumulated head votes, compact kept columns, in original
+        order, into the front of the sequence's own pages, and free the
+        tail."""
         tel = self.telemetry
         tel.span_begin("prune_compact", rid=st.req.rid)
         Lp = st.prompt_len
@@ -729,6 +493,13 @@ class PagedServingEngine(_SamplerMixin):
         tel.span_end("prune_compact", rid=st.req.rid,
                      args={"kept": n_kept, "prompt_len": Lp})
 
+    def _pick(self, logits: jax.Array) -> jax.Array:
+        key = None
+        if not self.scfg.greedy:
+            self._key, key = jax.random.split(self._key)
+        return sample_tokens(key, logits, greedy=self.scfg.greedy,
+                             temperature=self.scfg.temperature)
+
     def _emit_first(self, st: SeqState, logits_row: jax.Array) -> None:
         """The first token; its readback waits for the prefill step."""
         with self.telemetry.span("engine/emit_first", rid=st.req.rid):
@@ -745,12 +516,11 @@ class PagedServingEngine(_SamplerMixin):
 
         With telemetry on, ``engine/tick`` holds one span per host phase
         (:meth:`Telemetry.span`): ``engine/admit``, then per planned
-        prefill ``engine/prefill_chunk`` (or ``engine/full_prefill``),
-        ``engine/prune_compact`` and ``engine/emit_first``, then
-        ``engine/retire``, ``engine/decode_prepare``,
-        ``engine/decode_dispatch``, ``engine/decode_readback``,
-        ``engine/retire`` and ``engine/pool_observe``.  None of them
-        waits for the device."""
+        prefill ``engine/prefill_chunk``, ``engine/prune_compact`` and
+        ``engine/emit_first``, then ``engine/retire``,
+        ``engine/decode_prepare``, ``engine/decode_dispatch``,
+        ``engine/decode_readback``, ``engine/retire`` and
+        ``engine/pool_observe``.  None of them waits for the device."""
         tel = self.telemetry
         with tel.span("engine/tick"):
             with tel.span("engine/admit"):
@@ -759,10 +529,7 @@ class PagedServingEngine(_SamplerMixin):
             for st in plans:
                 if self.sched.slots[st.slot] is not st:
                     continue  # preempted by an earlier prefill this tick
-                if self.sched.use_chunks(st.prompt_len):
-                    self._chunk_prefill(st)
-                else:
-                    self._full_prefill(st)
+                self._chunk_prefill(st)
             with tel.span("engine/retire"):
                 # a prefill-emitted token may hit eos/budget
                 self._retire_finished()

@@ -13,10 +13,10 @@ a shared block table, and original-position ids instead of a dense
   to a static chunk size) is projected at its original positions, written
   into freshly allocated slots, and attends over *all* slots written so far
   -- cross-chunk causal attention, which is what lets the scheduler
-  interleave long prefills with decode ticks.
-* :func:`scatter_prefill` -- full-prefill ingestion: takes the dense cache
-  :func:`repro.models.model.prefill` produced, gathers the kept columns
-  (SPLS page pruning), and scatters them into pages.
+  interleave long prefills with decode ticks.  Every prompt, however
+  short, prefills this way (:func:`paged_prefill_chunk_spls` under SPLS).
+* :func:`compact_slots` -- the end-of-prefill SPLS page pruning: the kept
+  columns move to the front of the sequence's own pages.
 
 All functions are functional: caches/pos_pages go in, updated ones come
 out; the engine owns jit boundaries and the host-side pool bookkeeping.
@@ -65,7 +65,7 @@ from repro.models.moe import ffn_forward, moe_held_forward
 from .pager import POS_SENTINEL, PagedKVCache
 
 __all__ = ["paged_decode_step", "paged_prefill_chunk",
-           "paged_prefill_chunk_spls", "scatter_prefill", "compact_slots",
+           "paged_prefill_chunk_spls", "compact_slots",
            "STAGES"]
 
 # the stages of a model step, as its ops' named scopes; ``plan`` is the
@@ -701,8 +701,7 @@ def compact_slots(cache, pos_pages: jax.Array, table: jax.Array,
 
     keep: (S,) bool over the sequence's logical slots (slot == original
     position during prefill; slots past the prompt are False).  Kept
-    slots move -- in original order, matching :func:`scatter_prefill`'s
-    compacted layout exactly -- to the first ``n_kept`` slots of the
+    slots move, in original order, to the first ``n_kept`` slots of the
     sequence's *own* pages; the freed tail is sentinel-filled so window
     masks never admit a stale id.  No transient page allocation: the
     engine frees the pages past ``ceil(n_kept / ps)`` afterwards.
@@ -731,38 +730,4 @@ def compact_slots(cache, pos_pages: jax.Array, table: jax.Array,
             vf = vf.at[:, :, flat].set(vf[:, :, src])
             new_blocks.append(PagedKVCache(kf.reshape(nP, KV, N_, ps_, Dh),
                                            vf.reshape(nP, KV, N_, ps_, Dh)))
-    return tuple(new_blocks), pos_pages
-
-
-# ---------------------------------------------------------------------------
-# full-prefill ingestion (with SPLS page pruning)
-# ---------------------------------------------------------------------------
-
-def scatter_prefill(cache, pos_pages: jax.Array, dense_cache,
-                    keep_idx: jax.Array, flat: jax.Array
-                    ) -> Tuple[tuple, jax.Array]:
-    """Move a full prefill's kept KV columns into pages.
-
-    dense_cache: the per-layer dense cache from
-    :func:`repro.models.model.prefill` on a batch of one (arrays
-    ``(n_periods, 1, KV, S, Dh)`` per period block); keep_idx: (n_kept,)
-    original positions that survive SPLS pruning (all positions when
-    pruning is off); flat: (n_kept,) destination flat page slots.  The
-    kept columns land compacted; ``pos_pages`` records their original ids.
-    """
-    N, ps = pos_pages.shape
-    with jax.named_scope("kv_pool"):
-        pos_pages = pos_pages.reshape(N * ps).at[flat] \
-            .set(keep_idx.astype(jnp.int32)).reshape(N, ps)
-
-        new_blocks = []
-        for pc, dc in zip(cache, dense_cache):
-            nP, KV, N_, ps_, Dh = pc.k_pages.shape
-            rows_k = dc.k[:, 0][:, :, keep_idx]        # (nP, KV, n_kept, Dh)
-            rows_v = dc.v[:, 0][:, :, keep_idx]
-            kf = pc.k_pages.reshape(nP, KV, N_ * ps_, Dh).at[:, :, flat] \
-                .set(rows_k).reshape(nP, KV, N_, ps_, Dh)
-            vf = pc.v_pages.reshape(nP, KV, N_ * ps_, Dh).at[:, :, flat] \
-                .set(rows_v).reshape(nP, KV, N_, ps_, Dh)
-            new_blocks.append(PagedKVCache(kf, vf))
     return tuple(new_blocks), pos_pages

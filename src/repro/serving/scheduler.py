@@ -89,8 +89,7 @@ class SeqState:
 
 class Scheduler:
     def __init__(self, cfg: SchedulerConfig, pool: PagePool,
-                 max_len: int, chunkable: bool = True,
-                 prune_aware: bool = False, chunk_all: bool = False,
+                 max_len: int, prune_aware: bool = False,
                  telemetry: Optional[Telemetry] = None):
         self.cfg = cfg
         self.pool = pool
@@ -100,17 +99,6 @@ class Scheduler:
         self.tel = telemetry if telemetry is not None \
             else Telemetry(enabled=False)
         self.max_len = max_len
-        # chunked prefill needs causal cross-chunk attention; the engine
-        # disables it for non-causal models (SPLS configs now stream their
-        # plan chunk by chunk instead of bypassing chunking)
-        self.chunkable = chunkable
-        # route *every* prefill through the chunk path, including whole
-        # prompts (<= one chunk): the packed-compute engine sets this so
-        # short prompts get the same token-compacted QKV/FFN execution as
-        # long ones instead of silently running the dense full-prefill
-        # path (outputs are identical either way -- chunked-vs-full parity
-        # is test-pinned -- only the executed FLOPs differ)
-        self.chunk_all = chunk_all and chunkable
         # SPLS page pruning: track observed kept/prompt ratios (EMA) so
         # page-need accounting can use a post-prune estimate instead of
         # assuming dense footprints; conservative (dense) fallback until
@@ -166,7 +154,7 @@ class Scheduler:
         after prefill the sequence holds ``~ratio * lp`` kept slots plus
         its decode growth, while the prefill-time peak is the dense prompt
         (chunked prefill materializes every column until the vote
-        finalizes) or the kept count (full prefill allocates post-prune).
+        finalizes).
         Underestimates are survivable: a request that turns out not to fit
         is aborted by the solo-preemption guard instead of livelocking.
         """
@@ -174,15 +162,12 @@ class Scheduler:
         if not self.prune_aware or self.prune_ratio is None:
             return dense
         kept = math.ceil(self.prune_ratio * lp)
-        prefill_peak = self.pool.pages_for(
-            lp if self.use_chunks(lp) else kept)
         post = self.pool.pages_for(min(kept + budget, self.max_len))
-        return min(dense, max(prefill_peak, post))
+        return min(dense, max(self.pool.pages_for(lp), post))
 
     def submit(self, req, prompt_tokens: List[int], budget: int) -> None:
         lp = len(prompt_tokens)
-        first = (min(lp, self.cfg.prefill_chunk) if self.use_chunks(lp)
-                 else lp)
+        first = min(lp, self.cfg.prefill_chunk)
         # both the lifetime footprint and the admission need (first unit of
         # work + watermark) must fit, else the request could never run
         worst = max(self.lifetime_pages(lp, budget),
@@ -211,9 +196,9 @@ class Scheduler:
             if self.slots[slot] is not None or not self.waiting:
                 continue
             req, base, tokens, budget = self.waiting[0]
-            first = (min(len(tokens), self.cfg.prefill_chunk)
-                     if self.use_chunks(len(tokens)) else len(tokens))
-            need = self.pool.pages_for(first) + self.cfg.watermark
+            need = (self.pool.pages_for(min(len(tokens),
+                                            self.cfg.prefill_chunk))
+                    + self.cfg.watermark)
             if need > self.pool.free_pages:
                 break  # FIFO: don't let later requests starve the head
             self.waiting.popleft()
@@ -226,10 +211,6 @@ class Scheduler:
             self.tel.request_admitted(req.rid)
             admitted.append(st)
         return admitted
-
-    def use_chunks(self, prompt_len: int) -> bool:
-        return self.chunkable and (prompt_len > self.cfg.prefill_chunk
-                                   or self.chunk_all)
 
     def plan_prefills(self) -> List[SeqState]:
         """Prefill-phase sequences to advance this tick, oldest first."""

@@ -358,25 +358,3 @@ class TestDecodeParity:
         got = get_backend("pallas_flash_decode")(cfg, q, k, v, pos=pos)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=ATOL)
-
-    def test_serving_engine_backend_override(self):
-        """ServeConfig.attn_backend pins the engine's attention path."""
-        from repro.runtime.serve import Request, ServeConfig, ServingEngine
-        cfg = _cfg(n_layers=2, d_model=32, n_heads=2, n_kv_heads=2,
-                   head_dim=16, d_ff=64)
-        params = init_params(cfg, jax.random.PRNGKey(0))
-        prompt = jax.random.randint(jax.random.PRNGKey(1), (8,), 0,
-                                    cfg.vocab_size)
-        outs = {}
-        for b in (None, "pallas_flash"):
-            eng = ServingEngine(cfg, params,
-                                ServeConfig(n_slots=1, max_len=32,
-                                            attn_backend=b))
-            req = Request(rid=0, prompt=prompt, max_new_tokens=4)
-            eng.submit(req)
-            ticks = 0
-            while not req.done and ticks < 50:
-                eng.tick()
-                ticks += 1
-            outs[b] = req.output
-        assert outs[None] == outs["pallas_flash"]

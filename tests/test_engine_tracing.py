@@ -19,7 +19,6 @@ from repro.models import init_params
 from repro.observability import PHASE_PID, Telemetry
 from repro.serving import PagedServingEngine, Request, ServeConfig
 from repro.serving.engine import sample_tokens
-from repro.serving.pager import init_pred_cache
 from repro.serving.paged_model import STAGES
 
 jax.config.update("jax_platform_name", "cpu")
@@ -98,8 +97,8 @@ class TestPhaseSpans:
         ticks = [sp for sp in spans if sp[0] == "engine/tick"]
         assert len(ticks) == 4
         phases = [sp for sp in spans if sp[0] != "engine/tick"]
-        # the 7-token prompt fits one chunk: a whole-prompt prefill
-        assert {sp[0] for sp in phases} == PHASES | {"engine/full_prefill"}
+        # the 7-token prompt fits one chunk: one chunk step, like any other
+        assert {sp[0] for sp in phases} == PHASES
         for name, s, e, _ in phases:
             assert any(ts <= s and e <= te for _, ts, te, _ in ticks), name
 
@@ -124,7 +123,7 @@ class TestPhaseSpans:
         phase = [e for e in tr.events if e["name"].startswith("engine/")]
         assert phase and all(e["pid"] == PHASE_PID for e in phase)
         assert {e["name"] for e in phase} >= {"engine/tick",
-                                               "engine/full_prefill"}
+                                               "engine/prefill_chunk"}
         assert all(e["pid"] != PHASE_PID for e in tr.events
                    if e["name"] == "decode_tick")
 
@@ -229,27 +228,21 @@ def _lowered(eng, which):
         return eng._compact.lower(eng.cache, eng.pos_pages,
                                   jnp.zeros((P,), jnp.int32),
                                   jnp.zeros((P * eng.page_size,), bool))
-    if which == "prefill":
-        return eng._prefill.lower(p, jnp.zeros((1, 5), jnp.int32))
-    if which == "votes":
-        return eng._votes.lower(p, jnp.zeros((8,), jnp.int32))
     if which == "sampler":
         return sample_tokens.lower(None, jnp.zeros((n, cfg.vocab_size)),
                                    greedy=True, temperature=1.0)
     assert which == "chunk_spls"
     cq = cs if eng._cap_q is not None else None
-    pred = init_pred_cache(cfg, eng._n_pages, eng.page_size)
     return eng._get_chunk_spls(cq, cq, None, False).lower(
-        p, eng.cache, pred, eng.pos_pages, jnp.zeros((P,), jnp.int32),
-        i32(0), jnp.zeros((1, cs), jnp.int32), i32(cs), i32(4))
+        p, eng.cache, eng.pred_cache, eng.pos_pages,
+        jnp.zeros((P,), jnp.int32), i32(0), jnp.zeros((1, cs), jnp.int32),
+        i32(cs), i32(4))
 
 
 @pytest.mark.parametrize("which,name,spls", [
     ("decode", "jit_paged_decode_step", False),
     ("chunk", "jit_paged_prefill_chunk", False),
     ("compact", "jit_compact_slots", True),
-    ("prefill", "jit_prefill", False),
-    ("votes", "jit_spls_token_votes", True),
     ("chunk_spls", "jit_paged_prefill_chunk_spls", True),
     ("sampler", "jit_sample_tokens", False),
 ])

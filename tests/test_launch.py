@@ -58,7 +58,7 @@ def test_serving_config_published_widths():
 def test_launcher_serves_smoke_paged_spls(monkeypatch, tmp_path, capsys):
     # keep the launcher's compile cache out of the checkout
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
-    rc = serve.main(["--arch", "qwen3-0.6b", "--smoke", "--paged", "--spls",
+    rc = serve.main(["--arch", "qwen3-0.6b", "--smoke", "--spls",
                      "--requests", "2", "--slots", "2", "--prompt-len", "16",
                      "--max-new", "2", "--page-size", "4",
                      "--prefill-chunk", "8"])

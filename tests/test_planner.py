@@ -19,7 +19,7 @@ from repro.core.spls_chunked import chunked_plan_scan
 from repro.core.topk import topk_count
 from repro.models import init_params
 from repro.serving import (PagedServingEngine, Request, ServeConfig,
-                           ServingEngine, init_pred_cache, spls_token_votes)
+                           init_pred_cache, spls_token_votes)
 from repro.serving.pager import keep_from_votes
 
 jax.config.update("jax_platform_name", "cpu")
@@ -452,9 +452,8 @@ class TestPackedKV:
 
     def test_whole_prompt_packed_routing(self):
         """Short prompts (<= one chunk) under a packed compute backend
-        route through the chunk path: packed savings accrue where the
-        dense full-prefill path used to report zero, and greedy outputs
-        still match the dense-compute engine."""
+        take one chunk step each: packed savings accrue, and greedy
+        outputs still match the dense-compute engine."""
         cfg = _spls_cfg(spls_kw=dict(s_threshold=0.95, window=8),
                         name="tiny-planner-wp")
         params = _params(cfg)
@@ -466,13 +465,12 @@ class TestPackedKV:
         d_out = _drain(dense, _reqs(cfg, lens))
         packed = PagedServingEngine(cfg, params, ServeConfig(
             compute_backend="packed_xla", capacity_buckets=(8,), **scfg))
-        assert packed.sched.use_chunks(6)
         p_out = _drain(packed, _reqs(cfg, lens))
+        assert packed.stats["prefill_chunks"] == len(lens)
         assert p_out == d_out
-        # adaptive buckets: short prompts now accrue packed savings where
-        # the dense full-prefill path used to report zero (run a warmup
-        # batch so the controllers' EMAs leave the conservative first
-        # pick, then measure)
+        # adaptive buckets: short prompts accrue packed savings (run a
+        # warmup batch so the controllers' EMAs leave the conservative
+        # first pick, then measure)
         adaptive = PagedServingEngine(cfg, params, ServeConfig(
             compute_backend="packed_xla", capacity_buckets=(2, 4, 6, 8),
             capacity_margin=1.0, **scfg))

@@ -1,5 +1,5 @@
 """Progressive SPLS for serving: streaming per-chunk plan construction,
-chunked+SPLS prefill parity with the full-prefill pruned engine, page-prune
+chunked+SPLS prefill parity between one chunk and many, page-prune
 vote accumulation, O(chunk * L) plan memory, the PagePool double-free guard,
 the padded-chunk null-page sentinel, and backend-kind mismatch warnings."""
 
@@ -19,7 +19,9 @@ from repro.models import init_params, resolve_backend
 from repro.models import attn_backend as ab
 from repro.serving import (PagePool, PagedServingEngine, Request,
                            Scheduler, SchedulerConfig, ServeConfig,
-                           ServingEngine, SeqState, spls_token_votes)
+                           SeqState, spls_token_votes)
+
+from _reference import greedy_references
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -209,9 +211,9 @@ class TestChunkedSplsServing:
 
     @pytest.mark.parametrize("chunk", [8, 16])
     def test_parity_with_full_prefill_pruned(self, chunk):
-        """Greedy outputs of chunked+SPLS prefill (pruning on) match the
-        full-prefill pruned engine bit-for-bit in the no-preemption
-        regime, for multiple chunkings."""
+        """Greedy outputs of chunked+SPLS prefill (pruning on) in many
+        chunks match those of one whole-prompt chunk (64) bit-for-bit in
+        the no-preemption regime, for multiple chunkings."""
         cfg = _spls_cfg()
         params = _params(cfg)
         lens = [30, 18, 25, 41]
@@ -234,13 +236,12 @@ class TestChunkedSplsServing:
 
     def test_no_prune_matches_dense_engine(self):
         """Chunked SPLS prefill with pruning *off* still executes the
-        sparse (simulation-mode) compute -- outputs must equal the dense
-        fixed-slot engine's, which prefills whole prompts."""
+        sparse (simulation-mode) compute -- outputs must equal the
+        reference's, which prefills whole prompts."""
         cfg = _spls_cfg()
         params = _params(cfg)
-        dense = _drain(
-            ServingEngine(cfg, params, ServeConfig(n_slots=2, max_len=64)),
-            _reqs(cfg, [27, 14, 33], max_new=4))
+        dense = greedy_references(
+            cfg, params, _reqs(cfg, [27, 14, 33], max_new=4), max_len=64)
         chunked, _ = self._run(cfg, params, prefill_chunk=8,
                                lens=[27, 14, 33], prune=False, n_slots=2,
                                max_new=4)
@@ -248,7 +249,8 @@ class TestChunkedSplsServing:
 
     def test_sliding_window_chunked_spls(self):
         """SWA + chunked + SPLS: window masks evaluate original ids after
-        padding and compaction; parity with full prefill holds."""
+        padding and compaction; many chunks match one whole-prompt
+        chunk."""
         cfg = _spls_cfg(period=(BlockCfg(window=6),))
         cfg = dataclasses.replace(cfg, name="tiny-spls-swa")
         params = _params(cfg)
@@ -426,14 +428,12 @@ class TestPaddedChunkSentinel:
 
     def test_window_decode_ignores_null_page_after_padded_chunks(self):
         """Engine-level: sliding-window attention through ragged chunked
-        prefill (every chunk but the first is padded) matches the dense
-        engine -- null-page slots never win window mass."""
+        prefill (every chunk but the first is padded) matches the
+        reference -- null-page slots never win window mass."""
         cfg = _cfg(name="tiny-swa2", period=(BlockCfg(window=5),))
         params = _params(cfg)
         lens = [21, 9]                            # 21 -> chunks 8, 8, 5
-        dense = _drain(
-            ServingEngine(cfg, params, ServeConfig(n_slots=2, max_len=40)),
-            _reqs(cfg, lens))
+        dense = greedy_references(cfg, params, _reqs(cfg, lens), max_len=40)
         eng = PagedServingEngine(cfg, params, ServeConfig(
             n_slots=2, max_len=40, page_size=4, prefill_chunk=8,
             attn_backend="xla_paged_decode"))
@@ -484,9 +484,9 @@ class TestBackendKindMismatch:
 
     def test_engine_config_does_not_warn(self):
         """ServeConfig.attn_backend naming a paged decode backend is the
-        paged engine's documented usage: the engine routes the name to its
-        decode site and the prefill forward site resolves auto silently --
-        no kind-mismatch warning, and STRICT_BACKEND_KIND stays usable."""
+        engine's documented usage: the engine routes the name to its
+        decode site -- no kind-mismatch warning, and STRICT_BACKEND_KIND
+        stays usable."""
         import warnings as w
         cfg = _spls_cfg()
         params = _params(cfg)

@@ -325,21 +325,27 @@ class TestAdamW:
 # ---------------------------------------------------------------------------
 
 class TestServing:
+    @staticmethod
+    def _forward_greedy(cfg, params, prompt, n):
+        """Greedy tokens by repeated whole-sequence ``forward``."""
+        from repro.models import forward
+        seq = list(np.asarray(prompt))
+        for _ in range(n):
+            lg = forward(cfg, params, jnp.asarray(seq)[None, :])
+            seq.append(int(jnp.argmax(lg[0, -1])))
+        return seq[len(prompt):]
+
     def test_engine_matches_sequential_decode(self):
-        from repro.models import forward, init_params
-        from repro.runtime.serve import Request, ServeConfig, ServingEngine
+        from repro.models import init_params
+        from repro.serving import PagedServingEngine, Request, ServeConfig
         cfg = _tiny_cfg()
         params = init_params(cfg, jax.random.PRNGKey(0))
         prompt = jax.random.randint(jax.random.PRNGKey(1), (12,), 0,
                                     cfg.vocab_size)
-        # reference: greedy via repeated dense forward
-        seq = list(np.asarray(prompt))
-        for _ in range(6):
-            lg = forward(cfg, params, jnp.asarray(seq)[None, :])
-            seq.append(int(jnp.argmax(lg[0, -1])))
-        want = seq[12:]
+        want = self._forward_greedy(cfg, params, prompt, 6)
 
-        eng = ServingEngine(cfg, params, ServeConfig(n_slots=2, max_len=32))
+        eng = PagedServingEngine(cfg, params, ServeConfig(
+            n_slots=2, max_len=32, page_size=4))
         req = Request(rid=0, prompt=prompt, max_new_tokens=6)
         eng.submit(req)
         ticks = 0
@@ -348,12 +354,25 @@ class TestServing:
             ticks += 1
         assert req.output == want
 
-    def test_continuous_batching_drains_queue(self):
+    def test_reference_matches_sequential_decode(self):
+        """The serving tests' reference (whole-prompt prefill, then cached
+        decode) gives repeated ``forward``'s greedy tokens."""
+        from _reference import greedy_reference
         from repro.models import init_params
-        from repro.runtime.serve import Request, ServeConfig, ServingEngine
         cfg = _tiny_cfg()
         params = init_params(cfg, jax.random.PRNGKey(0))
-        eng = ServingEngine(cfg, params, ServeConfig(n_slots=2, max_len=48))
+        prompt = jax.random.randint(jax.random.PRNGKey(1), (12,), 0,
+                                    cfg.vocab_size)
+        assert greedy_reference(cfg, params, prompt, 6, max_len=32) == \
+            self._forward_greedy(cfg, params, prompt, 6)
+
+    def test_continuous_batching_drains_queue(self):
+        from repro.models import init_params
+        from repro.serving import PagedServingEngine, Request, ServeConfig
+        cfg = _tiny_cfg()
+        params = init_params(cfg, jax.random.PRNGKey(0))
+        eng = PagedServingEngine(cfg, params, ServeConfig(
+            n_slots=2, max_len=48, page_size=4))
         reqs = []
         for i in range(5):  # more requests than slots
             prompt = jax.random.randint(jax.random.PRNGKey(i), (8,), 0,
@@ -362,8 +381,7 @@ class TestServing:
             reqs.append(r)
             eng.submit(r)
         ticks = 0
-        while (eng.queue or any(s is not None for s in eng.slots)) \
-                and ticks < 200:
+        while not eng.sched.idle() and ticks < 200:
             eng.tick()
             ticks += 1
         assert all(r.done for r in reqs)

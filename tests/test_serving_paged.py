@@ -1,6 +1,7 @@
-"""Paged serving subsystem: engine parity vs the dense fixed-slot engine,
-scheduler policy (chunked-prefill fairness, pool exhaustion -> queueing /
-preemption, block-table reuse), SPLS page pruning, and sampling."""
+"""Paged serving subsystem: engine parity vs the one-request-at-a-time
+greedy reference (``tests/_reference.py``), scheduler policy
+(chunked-prefill fairness, pool exhaustion -> queueing / preemption,
+block-table reuse), SPLS page pruning, and sampling."""
 
 import dataclasses
 
@@ -13,7 +14,9 @@ from repro.configs.base import ArchConfig, BlockCfg
 from repro.core.spls import SPLSConfig
 from repro.models import init_params
 from repro.serving import (PagePool, PagedServingEngine, Request, ServeConfig,
-                           ServingEngine, spls_token_keep)
+                           spls_token_keep)
+
+from _reference import greedy_reference, greedy_references
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -50,7 +53,7 @@ def _drain_outputs(engine, reqs):
 
 
 # ---------------------------------------------------------------------------
-# paged vs dense parity
+# paged engine vs the greedy reference
 # ---------------------------------------------------------------------------
 
 class TestPagedDenseParity:
@@ -61,9 +64,8 @@ class TestPagedDenseParity:
         lengths and GQA (n_heads=4, kv=2), both paged backends."""
         cfg = _cfg()
         params = _params(cfg)
-        dense = _drain_outputs(
-            ServingEngine(cfg, params, ServeConfig(n_slots=2, max_len=32)),
-            _reqs(cfg, [12, 7, 19, 3, 14]))
+        dense = greedy_references(cfg, params, _reqs(cfg, [12, 7, 19, 3, 14]),
+                                  max_len=32)
         paged = _drain_outputs(
             PagedServingEngine(cfg, params, ServeConfig(
                 n_slots=2, max_len=32, page_size=4, attn_backend=backend)),
@@ -73,9 +75,8 @@ class TestPagedDenseParity:
     def test_sliding_window(self):
         cfg = _cfg(name="tiny-swa", period=(BlockCfg(window=6),))
         params = _params(cfg)
-        dense = _drain_outputs(
-            ServingEngine(cfg, params, ServeConfig(n_slots=2, max_len=32)),
-            _reqs(cfg, [15, 9, 21]))
+        dense = greedy_references(cfg, params, _reqs(cfg, [15, 9, 21]),
+                                  max_len=32)
         for backend in ("xla_paged_decode", "pallas_paged_decode"):
             paged = _drain_outputs(
                 PagedServingEngine(cfg, params, ServeConfig(
@@ -86,14 +87,13 @@ class TestPagedDenseParity:
 
     def test_spls_prefill_no_prune(self):
         """SPLS-enabled prefill (sparse compute) with page pruning off:
-        paged engines must reproduce the dense engine exactly."""
+        paged engines must reproduce the reference exactly."""
         cfg = _cfg(name="tiny-spls", spls=SPLSConfig(
             enabled=True, k_ratio=0.25, s_threshold=0.6, f_threshold=2,
             window=4, causal=True))
         params = _params(cfg)
-        dense = _drain_outputs(
-            ServingEngine(cfg, params, ServeConfig(n_slots=2, max_len=32)),
-            _reqs(cfg, [16, 11, 14], max_new=4))
+        dense = greedy_references(
+            cfg, params, _reqs(cfg, [16, 11, 14], max_new=4), max_len=32)
         for backend in ("xla_paged_decode", "pallas_paged_decode"):
             paged = _drain_outputs(
                 PagedServingEngine(cfg, params, ServeConfig(
@@ -101,6 +101,31 @@ class TestPagedDenseParity:
                     spls_page_prune=False)),
                 _reqs(cfg, [16, 11, 14], max_new=4))
             assert dense == paged, backend
+
+    @pytest.mark.parametrize("backend", ["xla_paged_decode",
+                                         "pallas_paged_decode"])
+    @pytest.mark.parametrize("lp", [1, 7, 8, 9])
+    def test_every_prompt_takes_chunk_steps(self, lp, backend):
+        """A prompt of any length, one shorter than a chunk included,
+        prefills in ceil(lp / chunk) chunk steps and decodes the
+        reference's tokens."""
+        cfg = _cfg()
+        params = _params(cfg)
+        eng = PagedServingEngine(cfg, params, ServeConfig(
+            n_slots=1, max_len=24, page_size=4, prefill_chunk=8,
+            attn_backend=backend))
+        req = _reqs(cfg, [lp], max_new=4)[0]
+        assert _drain_outputs(eng, [req]) == [
+            greedy_reference(cfg, params, req.prompt, 4, max_len=24)]
+        assert eng.stats["prefill_chunks"] == -(-lp // 8)
+
+    def test_rejects_non_causal_model(self):
+        """Every request decodes and every prompt prefills in causal
+        chunks, so an encoder config is refused at construction."""
+        cfg = _cfg(name="tiny-enc", causal=False)
+        with pytest.raises(AssertionError, match="causal"):
+            PagedServingEngine(cfg, _params(cfg), ServeConfig(
+                n_slots=1, max_len=16, page_size=4))
 
     def test_spls_pruned_backends_agree_and_save_pages(self):
         """With SPLS page pruning on, both paged backends agree bit-for-bit
@@ -126,12 +151,11 @@ class TestPagedDenseParity:
 
     def test_chunked_prefill_parity(self):
         """Prompts longer than the chunk prefill incrementally; outputs
-        stay identical to the dense whole-prompt engine."""
+        stay identical to the whole-prompt reference."""
         cfg = _cfg()
         params = _params(cfg)
-        dense = _drain_outputs(
-            ServingEngine(cfg, params, ServeConfig(n_slots=2, max_len=48)),
-            _reqs(cfg, [30, 7, 25]))
+        dense = greedy_references(cfg, params, _reqs(cfg, [30, 7, 25]),
+                                  max_len=48)
         eng = PagedServingEngine(cfg, params, ServeConfig(
             n_slots=2, max_len=48, page_size=4, prefill_chunk=8,
             attn_backend="xla_paged_decode"))
@@ -359,14 +383,13 @@ class TestSchedulerPolicy:
         assert eng.stats["admitted"] >= 3
         # never more than one sequence's pages in flight
         assert eng.stats["peak_pages"] <= 6
-        dense = _drain_outputs(
-            ServingEngine(cfg, params, ServeConfig(n_slots=4, max_len=24)),
-            _reqs(cfg, [16, 16, 16], max_new=4))
+        dense = greedy_references(
+            cfg, params, _reqs(cfg, [16, 16, 16], max_new=4), max_len=24)
         assert outs == dense
 
     def test_preemption_by_page_eviction(self):
         """A dry pool evicts the youngest sequence's pages; recompute-style
-        resume keeps greedy outputs identical to the dense engine."""
+        resume keeps greedy outputs identical to the reference."""
         cfg = _cfg()
         params = _params(cfg)
         eng = PagedServingEngine(cfg, params, ServeConfig(
@@ -375,9 +398,8 @@ class TestSchedulerPolicy:
         reqs = _reqs(cfg, [12, 12, 12], max_new=6)
         outs = _drain_outputs(eng, reqs)
         assert eng.stats["preemptions"] > 0
-        dense = _drain_outputs(
-            ServingEngine(cfg, params, ServeConfig(n_slots=3, max_len=32)),
-            _reqs(cfg, [12, 12, 12], max_new=6))
+        dense = greedy_references(
+            cfg, params, _reqs(cfg, [12, 12, 12], max_new=6), max_len=32)
         assert outs == dense
 
     def test_block_table_reuse_after_retirement(self):
@@ -433,22 +455,20 @@ class TestEngineApi:
     def test_run_until_drained_returns_retired(self):
         cfg = _cfg()
         params = _params(cfg)
-        for eng in (ServingEngine(cfg, params,
-                                  ServeConfig(n_slots=2, max_len=32)),
-                    PagedServingEngine(cfg, params, ServeConfig(
-                        n_slots=2, max_len=32, page_size=4))):
-            reqs = _reqs(cfg, [8, 5, 11], max_new=3)
-            for r in reqs:
-                eng.submit(r)
-            done = eng.run_until_drained()
-            assert sorted(r.rid for r in done) == [0, 1, 2]
-            assert all(r.done for r in done)
-            # a second call returns only newly retired requests
-            assert eng.run_until_drained() == []
+        eng = PagedServingEngine(cfg, params, ServeConfig(
+            n_slots=2, max_len=32, page_size=4))
+        reqs = _reqs(cfg, [8, 5, 11], max_new=3)
+        for r in reqs:
+            eng.submit(r)
+        done = eng.run_until_drained()
+        assert sorted(r.rid for r in done) == [0, 1, 2]
+        assert all(r.done for r in done)
+        assert [r.output for r in reqs] == greedy_references(
+            cfg, params, reqs, max_len=32)
+        # a second call returns only newly retired requests
+        assert eng.run_until_drained() == []
 
-    @pytest.mark.parametrize("engine_cls", [ServingEngine,
-                                            PagedServingEngine])
-    def test_temperature_sampling(self, engine_cls):
+    def test_temperature_sampling(self):
         """greedy=False samples through the threaded PRNG key:
         deterministic per seed, different across seeds, and (at high
         temperature) different from greedy argmax."""
@@ -456,7 +476,7 @@ class TestEngineApi:
         params = _params(cfg)
 
         def run(greedy, temperature, seed):
-            eng = engine_cls(cfg, params, ServeConfig(
+            eng = PagedServingEngine(cfg, params, ServeConfig(
                 n_slots=2, max_len=48, page_size=4, greedy=greedy,
                 temperature=temperature, seed=seed))
             return _drain_outputs(eng, _reqs(cfg, [10, 10], max_new=12))

@@ -391,18 +391,6 @@ class TestPackedServingEngine:
                 n_slots=2, max_len=64, page_size=4,
                 compute_backend="packed_xla"))
 
-    def test_dense_engine_warns_on_packed_backend(self):
-        """The dense fixed-slot engine has no packed path: a requested
-        packed backend warns loudly instead of silently measuring dense."""
-        from repro.serving import ServingEngine
-
-        cfg = _spls_cfg()
-        with warnings.catch_warnings(record=True) as w:
-            warnings.simplefilter("always")
-            ServingEngine(cfg, _params(cfg), ServeConfig(
-                n_slots=2, max_len=64, compute_backend="packed_xla"))
-        assert any("dense compute" in str(x.message) for x in w)
-
     def test_misaligned_chunk_raises_naming_both(self):
         cfg = _spls_cfg()
         with pytest.raises(ValueError) as ei:
@@ -433,17 +421,3 @@ class TestPackedServingEngine:
                     cfg, None, None, None, None, None,
                     jnp.int32(0), t, jnp.int32(6), jnp.int32(2)),
                 jax.ShapeDtypeStruct((1, 6), jnp.int32))
-
-
-class TestDeprecatedShim:
-    def test_runtime_serve_warns_and_forwards(self):
-        import importlib
-        import repro.runtime.serve as shim
-
-        with warnings.catch_warnings(record=True) as w:
-            warnings.simplefilter("always")
-            importlib.reload(shim)
-            cls = shim.PagedServingEngine
-        from repro.serving import PagedServingEngine as real
-        assert cls is real
-        assert any(issubclass(x.category, DeprecationWarning) for x in w)
