@@ -7,8 +7,9 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-__all__ = ["dtype_of", "cast_compute", "rms_norm", "layer_norm", "rope_freqs", "apply_rope",
-           "dense_init", "softcap", "Activations"]
+__all__ = ["dtype_of", "cast_compute", "off_compute_width", "rms_norm",
+           "layer_norm", "rope_freqs", "apply_rope", "dense_init", "softcap",
+           "Activations"]
 
 
 def dtype_of(name: str):
@@ -16,16 +17,31 @@ def dtype_of(name: str):
             "float16": jnp.float16}[name]
 
 
+def _keeps_dtype(path, a) -> bool:
+    """Whether :func:`cast_compute` leaves leaf ``a`` at ``path`` as it
+    is: not floating, or an MoE router's weights (key ``router``)."""
+    return not jnp.issubdtype(a.dtype, jnp.floating) or any(
+        getattr(k, "key", None) == "router" for k in path)
+
+
 def cast_compute(params, dtype):
     """Floating parameters cast to the compute dtype, except an MoE
     router's weights (key ``router``), which route in float32."""
     def cast(path, a):
-        if not jnp.issubdtype(a.dtype, jnp.floating) or any(
-                getattr(k, "key", None) == "router" for k in path):
-            return a
-        return a.astype(dtype)
+        return a if _keeps_dtype(path, a) else a.astype(dtype)
 
     return jax.tree_util.tree_map_with_path(cast, params)
+
+
+def off_compute_width(params, dtype) -> list:
+    """The paths of the leaves :func:`cast_compute` would change: the
+    floating parameters not at ``dtype``, an MoE router's excepted.
+    Reads only dtypes, so it works on arrays, tracers and
+    ``ShapeDtypeStruct`` leaves alike."""
+    dtype = jnp.dtype(dtype)
+    return [jax.tree_util.keystr(path) for path, a
+            in jax.tree_util.tree_flatten_with_path(params)[0]
+            if not _keeps_dtype(path, a) and a.dtype != dtype]
 
 
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:

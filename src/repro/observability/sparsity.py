@@ -5,8 +5,8 @@ runtime (cf. AccelTran's per-component realized-vs-predicted sparsity
 counters): realized kept-column ratios vs the scheduler's EMA estimate,
 vote-horizon finalization counts, capacity-bucket occupancy and
 overflow-fallback rates per :class:`~repro.sparse_compute.capacity.
-CapacityController`, and the byte/occupancy gauges of the page pool and
-the int8 predictor cache.
+CapacityController`, the byte/occupancy gauges of the page pool and
+the int8 predictor cache, and the resident weights' bytes.
 
 Everything here is a thin naming layer over the
 :class:`~repro.observability.metrics.MetricsRegistry` -- one place owns
@@ -104,3 +104,14 @@ class SparsityInstruments:
         r = self.registry
         r.gauge("pool/kv_bytes").set(kv_bytes)
         r.gauge("pool/pred_cache_bytes").set(pred_bytes)
+
+    def note_weight_bytes(self, params) -> None:
+        """The resident weights' bytes, and those of them held in float32
+        (at a bfloat16 compute width, an MoE router's alone)."""
+        import jax
+        import jax.numpy as jnp
+
+        r = self.registry
+        r.gauge("weights/bytes").set(tree_bytes(params))
+        r.gauge("weights/f32_bytes").set(tree_bytes(
+            [a for a in jax.tree.leaves(params) if a.dtype == jnp.float32]))

@@ -29,6 +29,7 @@ import numpy as np
 from repro.configs.base import ArchConfig
 from repro.kernels.paged_decode import pages_visited
 from repro.models.attn_backend import AUTO, resolve_backend
+from repro.models.common import cast_compute, dtype_of, off_compute_width
 from repro.models.moe import MOE_STATS
 from repro.observability import Telemetry, tree_bytes
 from repro.sparse_compute import (CapacityController, chunk_flops, is_packed,
@@ -192,6 +193,12 @@ class PagedServingEngine:
                     f"with similarity windows for chunked prefill to "
                     f"reproduce the whole-prompt plan (set "
                     f"ServeConfig.auto_align_chunk=True to round up)")
+        # the weights at compute width, cast once here: the steps compute
+        # with them as given (an MoE router's stay float32).  The caller's
+        # tree is neither donated nor deleted: it may still use it.
+        dtype = dtype_of(cfg.compute_dtype)
+        if off_compute_width(params, dtype):
+            params = jax.jit(cast_compute, static_argnums=1)(params, dtype)
         self.cfg, self.params, self.scfg = cfg, params, scfg
         self._key = jax.random.PRNGKey(scfg.seed)
 
@@ -278,9 +285,10 @@ class PagedServingEngine:
         # the pair set small)
         self._chunk_spls_jits: dict = {}
         self._compact = jax.jit(compact_slots, donate_argnums=(0, 1))
-        # pool byte gauges (metadata only, no device sync)
+        # pool and weight byte gauges (metadata only, no device sync)
         self.telemetry.sparsity.note_pool_bytes(tree_bytes(self.cache),
                                                 tree_bytes(self.pred_cache))
+        self.telemetry.sparsity.note_weight_bytes(self.params)
 
     def _get_chunk_spls(self, cq: Optional[int], cf: Optional[int],
                         ckv: Optional[int] = None, horizon: bool = False):

@@ -21,6 +21,12 @@ a shared block table, and original-position ids instead of a dense
 All functions are functional: caches/pos_pages go in, updated ones come
 out; the engine owns jit boundaries and the host-side pool bookkeeping.
 
+The steps cast no weight.  Their floating parameters arrive at
+``cfg.compute_dtype``, an MoE router's as stored (float32): the engine
+casts them once, when it is built (``cast_compute``).  Each step checks
+this when it is traced and raises ``ValueError`` on parameters at
+another width.
+
 The decode and dense chunk steps keep the stacked pool (``(n_periods, KV,
 N, ps, Dh)`` per period block) in the layer scan's *carry*; the scan's
 inputs are the period parameters and the period index ``l``.  A layer
@@ -57,7 +63,7 @@ import jax.numpy as jnp
 from repro.configs.base import ArchConfig
 from repro.models import resolve_backend, get_backend
 from repro.models.attention import output_proj, project_kv, project_qkv
-from repro.models.common import (cast_compute, dtype_of, rms_norm,
+from repro.models.common import (dtype_of, off_compute_width, rms_norm,
                                  softcap as _softcap)
 from repro.models.model import embed_inputs, head_logits
 from repro.models.moe import ffn_forward, moe_held_forward
@@ -72,8 +78,24 @@ __all__ = ["paged_decode_step", "paged_prefill_chunk",
 # SPLS prediction and plan, in the SPLS chunk step only; ``moe`` is the
 # held-expert layer (router, pair layout, the ``moe_gmm`` kernel and the
 # combine), inside ``ffn``
-STAGES = ("weights_cast", "embed", "qkv", "attention", "kv_pool", "ffn",
-          "lm_head", "plan", "moe")
+STAGES = ("embed", "qkv", "attention", "kv_pool", "ffn", "lm_head", "plan",
+          "moe")
+
+
+def _check_compute_width(cfg: ArchConfig, params):
+    """``cfg``'s compute dtype, once the floating parameters are checked to
+    arrive at it (an MoE router's in float32): the steps compute with the
+    weights as given and cast none of them.  A trace-time check, no op."""
+    dtype = dtype_of(cfg.compute_dtype)
+    off = off_compute_width(params, dtype)
+    if off:
+        raise ValueError(
+            f"the paged steps take their floating parameters at the "
+            f"compute dtype {cfg.compute_dtype} (an MoE router's as "
+            f"stored); {len(off)} are not, e.g. {', '.join(off[:3])}: "
+            f"cast them once with cast_compute, as PagedServingEngine "
+            f"does")
+    return dtype
 
 
 def _with_moe(out: tuple, stats: Optional[jax.Array]) -> tuple:
@@ -253,6 +275,7 @@ def paged_decode_step(cfg: ArchConfig, params, cache, pos_pages: jax.Array,
     over every layer (rows with ``kv_len == 0``, the inactive slots,
     route to nothing).
     """
+    _check_compute_width(cfg, params)
     ps = pos_pages.shape[1]
     N = pos_pages.shape[0]
     with jax.named_scope("kv_pool"):
@@ -263,7 +286,6 @@ def paged_decode_step(cfg: ArchConfig, params, cache, pos_pages: jax.Array,
     name = resolve_backend(backend or cfg.attn_backend, cfg, L=N * ps,
                            decode=True, paged=True)
     fn = get_backend(name)
-    dtype = dtype_of(cfg.compute_dtype)
     with jax.named_scope("embed"):
         x = embed_inputs(cfg, params, tokens)
 
@@ -272,8 +294,6 @@ def paged_decode_step(cfg: ArchConfig, params, cache, pos_pages: jax.Array,
     def scan_body(carry, inp):
         x, pcache = carry
         pparams, l = inp
-        with jax.named_scope("weights_cast"):
-            pparams = cast_compute(pparams, dtype)
         new_caches, stats = [], []
         for blk, bp, kc in zip(cfg.period, pparams, pcache):
             with jax.named_scope("qkv"):
@@ -332,7 +352,7 @@ def paged_prefill_chunk(cfg: ArchConfig, params, cache,
     _, CS = tokens.shape
     N, ps = pos_pages.shape
     S = table.shape[0] * ps
-    dtype = dtype_of(cfg.compute_dtype)
+    _check_compute_width(cfg, params)
 
     sl, _, pos_pages = _chunk_slots(table, pos_pages, start, valid, CS)
     positions = sl[None, :]                            # original ids
@@ -368,8 +388,6 @@ def paged_prefill_chunk(cfg: ArchConfig, params, cache,
     def scan_body(carry, inp):
         x, pcache = carry
         pparams, l = inp
-        with jax.named_scope("weights_cast"):
-            pparams = cast_compute(pparams, dtype)
         new_caches, stats = [], []
         for blk, bp, kc in zip(cfg.period, pparams, pcache):
             with jax.named_scope("qkv"):
@@ -499,7 +517,7 @@ def paged_prefill_chunk_spls(cfg: ArchConfig, params, cache, pred_cache,
     S = table.shape[0] * ps
     KV, Dh = cfg.n_kv_heads, cfg.resolved_head_dim
     scfg = cfg.spls
-    dtype = dtype_of(cfg.compute_dtype)
+    dtype = _check_compute_width(cfg, params)
     ctx = PlanContext.for_config(cfg, mode="structured")
 
     sl, flat, pos_pages = _chunk_slots(table, pos_pages, start, valid, CS)
@@ -517,8 +535,6 @@ def paged_prefill_chunk_spls(cfg: ArchConfig, params, cache, pred_cache,
             x = carry
             kv_written_c = live_all_c = n_kv_c = None
         pparams, pcache, ppred, p_idx = inp
-        with jax.named_scope("weights_cast"):
-            pparams = cast_compute(pparams, dtype)
         new_caches, new_preds = [], []
         kv_any0 = None
         counts = jnp.zeros((3,), jnp.int32)

@@ -16,6 +16,7 @@ from repro.configs.base import ArchConfig, BlockCfg
 from repro.core.spls import SPLSConfig
 from repro.kernels.paged_decode import pages_visited
 from repro.models import init_params
+from repro.models.common import cast_compute
 from repro.observability import PHASE_PID, Telemetry
 from repro.serving import PagedServingEngine, Request, ServeConfig
 from repro.serving.engine import sample_tokens
@@ -206,8 +207,9 @@ def test_pages_grid_is_what_the_kernel_copies():
 # program names and stages
 # ---------------------------------------------------------------------------
 
-def _lowered(eng, which):
-    cfg, p = eng.cfg, eng.params
+def _lowered(eng, which, params=None):
+    cfg = eng.cfg
+    p = eng.params if params is None else params
     n, P = eng.scfg.n_slots, eng.pages_per_seq
     cs = eng.scfg.prefill_chunk
 
@@ -254,7 +256,7 @@ def test_programs_are_named_by_their_function(which, name, spls):
 
 @pytest.mark.parametrize("which", ["decode", "chunk"])
 def test_steps_carry_every_stage_in_their_op_metadata(which):
-    # float32 weights computed in bfloat16, as served: the cast has ops
+    # float32 weights computed in bfloat16, as served
     cfg = _cfg(name="tiny-tr-bf16", compute_dtype="bfloat16")
     hlo = _lowered(_engine(cfg), which).compile().as_text()
     # plan: the SPLS step's; moe: a model with held experts' (below)
@@ -266,14 +268,103 @@ def test_steps_carry_every_stage_in_their_op_metadata(which):
     assert "/kv_pool/while/" not in hlo
 
 
+def _moe_cfg():
+    return _cfg(name="tiny-tr-moe", compute_dtype="bfloat16",
+                period=(BlockCfg(use_moe=True),), moe_experts=8, moe_topk=2,
+                moe_held=(0, 1, 2, 3))
+
+
 @pytest.mark.parametrize("which", ["decode", "chunk"])
 def test_moe_steps_carry_the_moe_stage_inside_ffn(which):
-    cfg = _cfg(name="tiny-tr-moe", compute_dtype="bfloat16",
-               period=(BlockCfg(use_moe=True),), moe_experts=8, moe_topk=2,
-               moe_held=(0, 1, 2, 3))
-    hlo = _lowered(_engine(cfg), which).compile().as_text()
+    hlo = _lowered(_engine(_moe_cfg()), which).compile().as_text()
     assert "/ffn/moe/" in hlo
     assert "moe_gmm" in hlo
+
+
+# ---------------------------------------------------------------------------
+# weights held at compute width
+# ---------------------------------------------------------------------------
+
+_SERVED = {"dense": lambda: _cfg(name="tiny-tr-bf16",
+                                 compute_dtype="bfloat16"),
+           "moe": _moe_cfg}
+
+
+def _is_router(path):
+    return any(getattr(k, "key", None) == "router" for k in path)
+
+
+def _float_leaves(params):
+    return [(path, a) for path, a
+            in jax.tree_util.tree_flatten_with_path(params)[0]
+            if jnp.issubdtype(a.dtype, jnp.floating)]
+
+
+@pytest.mark.parametrize("model", sorted(_SERVED))
+def test_engine_holds_its_weights_at_compute_width(model):
+    cfg = _SERVED[model]()
+    eng = _engine(cfg)
+    routers = 0
+    for path, a in _float_leaves(eng.params):
+        if _is_router(path):
+            routers += 1
+            assert a.dtype == jnp.float32, jax.tree_util.keystr(path)
+        else:
+            assert a.dtype == jnp.bfloat16, jax.tree_util.keystr(path)
+    assert routers == (model == "moe")
+    # the caller's float32 tree is left as it was
+    assert all(a.dtype == jnp.float32 for _, a in _float_leaves(_params(cfg)))
+
+
+def _weight_converts(text, params):
+    """The f32 -> bf16 converts in a lowered program whose operand has
+    the shape of a parameter, whole or as one layer's slice."""
+    shapes = set()
+    for path, a in _float_leaves(params):
+        shapes.add(a.shape)
+        if getattr(path[0], "key", None) == "periods":
+            shapes.add(a.shape[1:])
+    dims = {"x".join(map(str, s)) for s in shapes}
+    return [m.group(0) for m in re.finditer(
+        r"stablehlo\.convert %\S+ : \(tensor<((?:\d+x)*)f32>\) -> "
+        r"tensor<(?:\d+x)*bf16>", text)
+        if m.group(1).rstrip("x") in dims]
+
+
+@pytest.mark.parametrize("which", ["decode", "chunk"])
+@pytest.mark.parametrize("model", sorted(_SERVED))
+def test_steps_convert_no_weight(model, which):
+    cfg = _SERVED[model]()
+    # 3 slots: no activation shares a shape with the stacked (2, 32) gains
+    eng = _engine(cfg, n_slots=3)
+    text = _lowered(eng, which).as_text()
+    assert "stablehlo.convert" in text      # the activations' casts stay
+    assert _weight_converts(text, _params(cfg)) == []
+    # the same probe finds the casts the steps made before
+    cast = jax.jit(lambda p: cast_compute(p, jnp.bfloat16))
+    assert _weight_converts(cast.lower(_params(cfg)).as_text(),
+                            _params(cfg))
+
+
+@pytest.mark.parametrize("which", ["decode", "chunk"])
+@pytest.mark.parametrize("model", sorted(_SERVED))
+def test_steps_refuse_float32_weights(model, which):
+    cfg = _SERVED[model]()
+    eng = _engine(cfg)
+    with pytest.raises(ValueError, match="compute dtype bfloat16"):
+        _lowered(eng, which, params=_params(cfg))
+
+
+def test_engine_gauges_its_resident_weights():
+    eng = _engine(_moe_cfg())
+    m = eng.telemetry.metrics
+    routers = sum(a.size for path, a in _float_leaves(eng.params)
+                  if _is_router(path))
+    bf16 = sum(a.size for path, a in _float_leaves(eng.params)
+               if not _is_router(path))
+    assert routers and bf16
+    assert m.get("weights/f32_bytes").value == 4 * routers
+    assert m.get("weights/bytes").value == 4 * routers + 2 * bf16
 
 
 def test_packed_pallas_chunk_step_names_the_gather_schedule():

@@ -17,6 +17,7 @@ from repro.core.spls_chunked import plan_chunk, votes_from_kv_any
 from repro.core.topk import topk_count
 from repro.models import init_params, resolve_backend
 from repro.models import attn_backend as ab
+from repro.models.common import cast_compute, dtype_of
 from repro.serving import (PagePool, PagedServingEngine, Request,
                            Scheduler, SchedulerConfig, ServeConfig,
                            SeqState, spls_token_votes)
@@ -157,7 +158,7 @@ class TestPlanChunkStreaming:
         jaxpr = jax.make_jaxpr(
             lambda p, c, pc, pp, tb, s0, t, v, k: paged_prefill_chunk_spls(
                 cfg, p, c, pc, pp, tb, s0, t, v, k))(
-            params, cache, pred,
+            cast_compute(params, dtype_of(cfg.compute_dtype)), cache, pred,
             jax.ShapeDtypeStruct((n_pages, ps), jnp.int32),
             jax.ShapeDtypeStruct((P,), jnp.int32),
             jax.ShapeDtypeStruct((), jnp.int32),
@@ -419,7 +420,8 @@ class TestPaddedChunkSentinel:
         table = jnp.asarray([1, 2, NULL_PAGE, NULL_PAGE], jnp.int32)
         toks = jnp.zeros((1, 8), jnp.int32)       # 8-row chunk, 5 valid
         _, cache, pos_pages = paged_prefill_chunk(
-            cfg, params, cache, pos_pages, table,
+            cfg, cast_compute(params, dtype_of(cfg.compute_dtype)), cache,
+            pos_pages, table,
             jnp.asarray(0, jnp.int32), toks, jnp.asarray(5, jnp.int32))
         # padded rows 5..7 all scatter to null-page slot 0: it must hold
         # the sentinel, not a real position id

@@ -13,6 +13,7 @@ import pytest
 from repro.configs.base import ArchConfig, BlockCfg
 from repro.core.spls import SPLSConfig
 from repro.models import init_params
+from repro.models.common import cast_compute, dtype_of
 from repro.serving import (PagePool, PagedServingEngine, Request, ServeConfig,
                            spls_token_keep)
 
@@ -36,6 +37,11 @@ def _params(cfg):
     if key not in _PARAMS_CACHE:
         _PARAMS_CACHE[key] = init_params(cfg, jax.random.PRNGKey(0))
     return _PARAMS_CACHE[key]
+
+
+def _served(cfg, params):
+    """``params`` at the compute width the paged steps take them at."""
+    return cast_compute(params, dtype_of(cfg.compute_dtype))
 
 
 def _reqs(cfg, lens, max_new=5, seed0=0):
@@ -219,8 +225,8 @@ class TestStackedPoolSteps:
         valid = tokens.shape[1]
         padded = jnp.pad(tokens, ((0, 0), (0, _CHUNK - valid)))
         _, cache2, pos2 = paged_prefill_chunk(
-            cfg, params, cache, pos_pages, jnp.asarray(_TABLE),
-            jnp.int32(start), padded, jnp.int32(valid))
+            cfg, _served(cfg, params), cache, pos_pages,
+            jnp.asarray(_TABLE), jnp.int32(start), padded, jnp.int32(valid))
         return cache2, pos2
 
     def test_chunk_step_writes_only_its_rows(self):
@@ -281,8 +287,9 @@ class TestStackedPoolSteps:
         tables = jnp.asarray(np.stack([_TABLE, np.zeros_like(_TABLE)]))
         kv_len = jnp.asarray([_VALID, 0], jnp.int32)
         toks = jnp.stack([tokens[0, _VALID:], jnp.zeros((1,), jnp.int32)])
-        out = paged_decode_step(cfg, params, cache, pos_pages, tables,
-                                kv_len, kv_len, toks, backend=backend)
+        out = paged_decode_step(cfg, _served(cfg, params), cache, pos_pages,
+                                tables, kv_len, kv_len, toks,
+                                backend=backend)
         cache2 = out[1]
         pg, sl = _slot(_VALID)
         _assert_only_written(cache, cache2, [(pg, sl)])
@@ -312,6 +319,7 @@ class TestStackedPoolSteps:
             args = (jnp.asarray(_TABLE), jnp.int32(0),
                     jnp.zeros((1, _CHUNK), jnp.int32), jnp.int32(_VALID))
             fn = paged_prefill_chunk
+        params = _served(cfg, params)
         jaxpr = jax.make_jaxpr(lambda c: fn(cfg, params, c, pos_pages,
                                             *args))(cache)
         pool = {tuple(a.shape) for kc in cache for a in kc}
